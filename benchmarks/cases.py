@@ -102,8 +102,8 @@ def case_scale(smoke: bool = False, quiet=None):
     10k chains 100 deep (pairwise edges: ~990k composites) plus deep
     whole-chain *group* relationships whose canonical chunks exceed
     int64 — exactly the composites PR 6's guard used to reject with
-    ``OverflowError`` and the multi-limb registry now represents.  A
-    sampled sub-universe is then verified differentially: the limb
+    ``OverflowError`` and the wide registry now represents.  A
+    sampled sub-universe is then verified differentially: the digit
     divisibility scan, staged factorization, and pairwise gcd kernels
     against exact Python-int arithmetic, with zero false positives
     asserted by re-factorization (Theorem 1).
@@ -116,8 +116,7 @@ def case_scale(smoke: bool = False, quiet=None):
     from repro.core.composite import (CompositeRegistry,
                                       encode_relationship)
     from repro.core.primes import CacheLevel, HierarchicalPrimeAllocator
-    from repro.kernels import (divisibility_scan_limbs,
-                               factorize_batch_exact, gcd_batch_exact)
+    from repro.kernels import divisibility_scan, factorize_batch, gcd_batch
     from repro.obs import profile
     from repro.obs.telemetry import Progress
 
@@ -190,18 +189,18 @@ def case_scale(smoke: bool = False, quiet=None):
     profile.reset()
     profile.enable(True)        # launch ledger -> obs block (exempt)
     t0 = time.perf_counter()
-    idx = divisibility_scan_limbs(limbs, queries)
+    idx = divisibility_scan(limbs, queries)
     scan_wall = time.perf_counter() - t0
     scan_hits = 0
     for j, q in enumerate(queries):
         want = [i for i, c in enumerate(sample) if c % q == 0]
-        assert list(idx[j]) == want, f"limb scan diverged at prime {q}"
+        assert list(idx[j]) == want, f"digit scan diverged at prime {q}"
         scan_hits += len(want)
     assert all(not len(idx[len(queries) - 16 + k]) for k in range(16)), \
         "negative-control primes must hit nothing (Theorem 1)"
 
     t0 = time.perf_counter()
-    factors, residual = factorize_batch_exact(sample, pool)
+    factors, residual = factorize_batch(limbs, pool)
     factor_wall = time.perf_counter() - t0
     false_pos = 0
     for c, fs, r in zip(sample, factors, residual):
@@ -223,15 +222,15 @@ def case_scale(smoke: bool = False, quiet=None):
               prime_of[c * depth:(c + 1) * depth], max_bits)))]
     gb = gb[:len(ga)]
     import math as _math
-    gs = gcd_batch_exact(ga, gb, pool)
+    gs = gcd_batch(ga, gb, pool)
     assert gs == [_math.gcd(a, b) for a, b in zip(ga, gb)], \
-        "limb gcd diverged from exact host gcd"
+        "digit gcd diverged from exact host gcd"
     gcd_nontrivial = sum(1 for g in gs if g > 1)
     profile.enable(False)
     launches = profile.summary()
 
     print(f"\n== Case study: million-element wide registry "
-          f"(max_bits={max_bits}, {L} limbs) ==")
+          f"(max_bits={max_bits}, {L} digits) ==")
     print(f"  elements {len(prime_of):,}   chains {n_chains:,} x {depth} "
           f"deep   composites {len(comps):,} ({len(wide):,} beyond "
           f"int64, widest {max_comp_bits} bits)")

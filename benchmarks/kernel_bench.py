@@ -17,6 +17,13 @@ from repro.kernels.ops import divisibility_scan, factorize_batch, gcd_batch
 from .common import emit, save_bench, save_json, timed
 
 
+def squarefree_pairs(rng, primes, n):
+    """``n`` products of two distinct ``primes`` (the registry's
+    squarefree invariant, which the pool gcd needs)."""
+    return [int(p) * int(q) for p, q in
+            (rng.choice(primes, size=2, replace=False) for _ in range(n))]
+
+
 def run_smoke():
     """Tiny kernel pass under the launch-ledger profiler (DESIGN.md
     §13).  The checked-in ``BENCH_kernel_bench.json`` payload is ONLY
@@ -34,7 +41,8 @@ def run_smoke():
     with profile.profiling():
         factorize_batch(list(comps), list(pool))
         divisibility_scan(list(comps), list(pool[:64]))
-        gcd_batch(list(comps), list(comps[::-1]))
+        sq = squarefree_pairs(rng, primes[100:], 256)
+        gcd_batch(sq, sq[::-1], list(primes[100:]))
     launches = profile.summary()
     print("\n== kernels (smoke, launch ledger) ==")
     for name, rec in sorted(launches.items()):
@@ -74,10 +82,10 @@ def run():
     emit("kernel.divisibility_scan.us_per_query", per_q)
     out["scan_us_per_query"] = per_q
 
-    # gcd
-    a = rng.integers(1, 2**30, size=65536)
-    b = rng.integers(1, 2**30, size=65536)
-    _, dt = timed(gcd_batch, list(a), list(b), repeat=3)
+    # gcd of squarefree pairs, rebuilt from the prime pool
+    a = squarefree_pairs(rng, pool, 4096)
+    b = squarefree_pairs(rng, pool, 4096)
+    _, dt = timed(gcd_batch, a, b, list(pool), repeat=3)
     per_g = dt / len(a) * 1e6
     print(f"   gcd_batch: {len(a)} pairs in {dt*1e3:.1f} ms "
           f"({per_g:.3f} us/pair)")

@@ -15,7 +15,7 @@ import sys
 from repro.launch.serve import main as serve_main
 
 if __name__ == "__main__":
-    serve_main(["--arch", "qwen2.5-3b", "--requests", "12",
+    serve_main(["--arch", "qwen2.5-3b", "--smoke", "--requests", "12",
                 "--max-new", "16", "--max-batch", "4", "--max-seq", "192",
                 "--shared-prefix", "32"])
     serve_main(["--null-model", "--kv", "vec", "--max-batch", "128",

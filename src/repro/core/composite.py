@@ -11,28 +11,29 @@ The paper implicitly assumes composites fit machine words ("systems with
 10**12 elements require primes within 64-bit ranges", §7.1).  Products of
 many primes overflow regardless, so the registry *chunks* a k-ary
 relationship into composites that each fit ``max_bits`` (default 62, so
-int64 device kernels stay exact); all chunks share a relationship id.
-Pairwise relationships — the dominant case in the paper's workloads
-(FK pairs, feature pairs, instrument pairs) — always fit.
+every chunk also fits the host's int64 view); all chunks share a
+relationship id.  Pairwise relationships — the dominant case in the
+paper's workloads (FK pairs, feature pairs, instrument pairs) — always
+fit.
 
-Multi-limb wide mode (DESIGN.md §11)
-------------------------------------
-``max_bits > 63`` switches the registry to the :class:`LimbComposite`
-encoding: each chunk is stored exactly as ``ceil(max_bits / 32)``
-little-endian 32-bit limbs, so a single chunk can hold a 100+-deep chain
-composite without overflow and the former PR 6 "detect, never silent"
-overflow guard becomes "represent, never raise".  Member primes must fit
-``MAX_PRIME_BITS`` (31) bits so every limb x prime product in the Pallas
-kernels stays inside a signed int64 word — a bound no pool prime ever
-approaches (the 10**6-th prime is ~2**24).  Arithmetic stays exact
-integer everywhere; Theorem 1's zero-false-positive guarantee is
-untouched because chunk values are the same products of distinct primes,
-merely re-encoded.
+Device digits (DESIGN.md §3, §11)
+---------------------------------
+The device kernels see every composite, narrow or wide, as
+``ceil(max_bits / LIMB_BITS)`` little-endian ``LIMB_BITS``-bit digits,
+one digit per int32 lane.  Member primes must stay below
+``MAX_PRIME_LIMIT`` (2**25, enforced where the pools mint primes) so that
+every kernel step — ``remainder * 2**LIMB_BITS + digit`` in the
+divisibility ladder and the short division, ``digit * p + carry`` in the
+gcd rebuild — stays below 2**31: exact int32 arithmetic, no 64-bit lanes,
+no float paths.  ``max_bits > 63`` switches the registry to wide mode:
+chunks may exceed int64 on the host too, so a single chunk can hold a
+100+-deep chain composite without overflow ("represent, never raise").
+Theorem 1's zero-false-positive guarantee is untouched because chunk
+values are the same products of distinct primes, merely re-encoded.
 
-The registry also maintains the flat numpy array view of live composites
-that the TPU divisibility-scan kernel (``repro.kernels.divisibility``)
-consumes directly, and — in wide mode — the ``(N, L)`` int64 limb matrix
-the limb kernels consume (``limbs_array``).
+The registry also maintains the int64 view of live composites
+(narrow mode, host-side arithmetic) and the ``(N, n_limbs)`` int32 digit
+matrix the kernels consume (``limbs_array``), in both modes.
 """
 
 from __future__ import annotations
@@ -43,27 +44,23 @@ from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Set, Tup
 import numpy as np
 
 from .factorization import Factorizer
+from .primes import MAX_PRIME_BITS, MAX_PRIME_LIMIT
 
 __all__ = ["encode_relationship", "CompositeRegistry", "Relationship",
            "LimbComposite", "LIMB_BITS", "LIMB_BASE", "MAX_PRIME_BITS",
            "MAX_COMPOSITE_BITS", "n_limbs_for_bits", "int_to_limbs",
            "limbs_to_int", "pack_limbs", "unpack_limbs"]
 
-#: limb word width: 32-bit limbs held in int64 lanes keep every kernel
-#: intermediate (limb * prime + carry, Horner-mod partial remainders)
-#: provably inside a signed int64 — no float paths, no wraparound.
-LIMB_BITS = 32
+#: device digit width: one ``LIMB_BITS``-bit digit per int32 lane.
+#: ``LIMB_BITS + MAX_PRIME_BITS == 31`` keeps every kernel step below
+#: 2**31 (see the module docstring) — no wraparound, no 64-bit lanes.
+LIMB_BITS = 31 - MAX_PRIME_BITS
 LIMB_BASE = 1 << LIMB_BITS
 LIMB_MASK = LIMB_BASE - 1
 
-#: primes must fit 31 bits so ``limb * p`` < 2**63 (see DESIGN.md §11);
-#: the prime pools never mint anything close (10**6-th prime ~ 2**24).
-MAX_PRIME_BITS = 31
-MAX_PRIME_LIMIT = 1 << MAX_PRIME_BITS
-
-#: sanity cap on chunk width (128 limbs) — wide enough for 150+-deep
+#: sanity cap on chunk width (683 digits) — wide enough for 150+-deep
 #: chains of MEM-level primes in ONE chunk, small enough that a
-#: misconfigured budget cannot allocate absurd limb matrices.
+#: misconfigured budget cannot allocate absurd digit matrices.
 MAX_COMPOSITE_BITS = 4096
 
 
@@ -73,49 +70,53 @@ def n_limbs_for_bits(max_bits: int) -> int:
 
 
 def int_to_limbs(x: int, n_limbs: int) -> List[int]:
-    """Little-endian 32-bit limb decomposition of a non-negative int."""
-    x = int(x)
-    if x < 0:
-        raise ValueError(f"composites are positive, got {x}")
-    out = []
-    for _ in range(n_limbs):
-        out.append(x & LIMB_MASK)
-        x >>= LIMB_BITS
-    if x:
-        raise OverflowError(
-            f"value needs more than {n_limbs} limbs ({n_limbs * LIMB_BITS} bits)")
-    return out
+    """Little-endian ``LIMB_BITS``-bit digits of a non-negative int."""
+    return [int(d) for d in pack_limbs([x], n_limbs)[0]]
 
 
 def limbs_to_int(limbs: Sequence[int]) -> int:
     """Inverse of :func:`int_to_limbs` (exact Python int)."""
-    x = 0
-    for limb in reversed(list(limbs)):
-        x = (x << LIMB_BITS) | (int(limb) & LIMB_MASK)
-    return x
+    return unpack_limbs(np.asarray([list(limbs)]))[0]
 
 
 def pack_limbs(values: Sequence[int], n_limbs: int) -> np.ndarray:
-    """Pack Python-int composites into the ``(N, L)`` int64 kernel matrix."""
-    out = np.zeros((len(values), n_limbs), dtype=np.int64)
-    for i, v in enumerate(values):
-        out[i, :] = int_to_limbs(v, n_limbs)
-    return out
+    """Pack non-negative ints into the ``(N, n_limbs)`` int32 digit
+    matrix the kernels take (bytes -> bits -> digits, vectorized)."""
+    vals = [int(v) for v in values]
+    nbits = n_limbs * LIMB_BITS
+    for v in vals:
+        if v < 0:
+            raise ValueError(f"composites are positive, got {v}")
+        if v.bit_length() > nbits:
+            raise OverflowError(
+                f"value needs more than {n_limbs} limbs ({nbits} bits)")
+    nbytes = -(-nbits // 8)
+    raw = np.frombuffer(b"".join(v.to_bytes(nbytes, "little") for v in vals),
+                        dtype=np.uint8).reshape(len(vals), nbytes)
+    bits = np.unpackbits(raw, axis=1, bitorder="little")[:, :nbits]
+    weights = (1 << np.arange(LIMB_BITS, dtype=np.int32))
+    return (bits.reshape(len(vals), n_limbs, LIMB_BITS).astype(np.int32)
+            * weights).sum(axis=2, dtype=np.int32)
 
 
 def unpack_limbs(arr: np.ndarray) -> List[int]:
-    """Exact Python ints back out of an ``(N, L)`` limb matrix."""
-    return [limbs_to_int(row) for row in np.asarray(arr)]
+    """Exact Python ints back out of an ``(N, L)`` digit matrix."""
+    digits = np.asarray(arr, dtype=np.int64)
+    n, L = digits.shape
+    bits = ((digits[:, :, None] >> np.arange(LIMB_BITS)) & 1).astype(np.uint8)
+    raw = np.packbits(bits.reshape(n, L * LIMB_BITS), axis=1,
+                      bitorder="little")
+    return [int.from_bytes(row.tobytes(), "little") for row in raw]
 
 
 @dataclass(frozen=True)
 class LimbComposite:
-    """One composite as fixed-width little-endian 32-bit limbs.
+    """One composite as fixed-width little-endian ``LIMB_BITS``-bit digits.
 
     The scalar unit of the wide registry encoding: ``encode`` splits an
     exact Python-int chunk value into limbs, ``value`` reassembles it
     bit-exactly.  The registry's ``limbs_array()`` is the batched (N, L)
-    form of this for the Pallas limb kernels.
+    form of this for the Pallas kernels.
     """
 
     limbs: Tuple[int, ...]
@@ -150,12 +151,11 @@ def encode_relationship(primes: Sequence[int], max_bits: int = 62) -> List[int]:
     boundary is inclusive on the value side and exclusive on the budget:
     a chunk product of exactly ``2**max_bits - 1`` is accepted, a prime
     of exactly ``2**max_bits`` is rejected.  Raises if any single prime
-    alone exceeds the bound (cannot be represented), or — in wide
-    (``max_bits > 63``) mode — exceeds the 31-bit kernel limb word (no
-    pool prime ever does; see DESIGN.md §11).
+    alone exceeds the bound (cannot be represented), or reaches the
+    ``MAX_PRIME_BITS``-bit kernel prime bound (the pools refuse to mint
+    such primes; see DESIGN.md §3).
     """
     limit = 1 << max_bits
-    wide = max_bits > 63
     chunks: List[int] = []
     cur = 1
     for p in sorted(primes):
@@ -163,10 +163,10 @@ def encode_relationship(primes: Sequence[int], max_bits: int = 62) -> List[int]:
             raise ValueError(f"not a prime: {p}")
         if p >= limit:
             raise ValueError(f"prime {p} exceeds {max_bits}-bit composite budget")
-        if wide and p >= MAX_PRIME_LIMIT:
+        if p >= MAX_PRIME_LIMIT:
             raise ValueError(
-                f"prime {p} exceeds the {MAX_PRIME_BITS}-bit kernel limb "
-                f"word (limb arithmetic would overflow int64)")
+                f"prime {p} exceeds the {MAX_PRIME_BITS}-bit kernel prime "
+                f"bound (digit arithmetic would overflow int32)")
         if cur * p >= limit:
             chunks.append(cur)
             cur = p
@@ -196,28 +196,28 @@ class CompositeRegistry:
       * ``related_to(p)``          — §4.2 intelligent prefetch: all primes
                                      co-occurring with p in any composite,
                                      recovered *by factorization*.
-      * ``composites_array()``     — int64 view for the Pallas scan kernel.
+      * ``composites_array()``     — int64 host view (narrow mode).
+      * ``limbs_array()``          — int32 digit matrix for the kernels.
     """
 
     def __init__(self, factorizer: Optional[Factorizer] = None, max_bits: int = 62):
         if not 1 < max_bits <= MAX_COMPOSITE_BITS:
             # max_bits <= 63 keeps every chunk inside one signed int64
-            # kernel word (the flat composites_array() view); anything
-            # wider flips the registry into multi-limb mode, where chunks
-            # are exact (N, n_limbs) 32-bit-limb rows (limbs_array()) and
-            # the cap only guards against absurd limb matrices.
+            # host word (the flat composites_array() view); anything
+            # wider flips the registry into wide mode, where only exact
+            # Python ints and the digit matrix hold chunks, and the cap
+            # only guards against absurd digit matrices.
             raise ValueError(
                 f"max_bits must be in (1, {MAX_COMPOSITE_BITS}], "
                 f"got {max_bits}")
         self.factorizer = factorizer or Factorizer()
         self.max_bits = max_bits
         #: wide mode: chunks may exceed int64 — consumers must use the
-        #: limb matrix (limbs_array) or exact Python ints
+        #: digit matrix (limbs_array) or exact Python ints
         #: (composites_list / composites_view), never composites_array.
         self.wide = max_bits > 63
-        #: limb rows wide enough for any value < 2**max_bits (also
-        #: meaningful in narrow mode: the limb kernels are differential-
-        #: fuzzed against the int64 path at every width)
+        #: digits per kernel row: enough for any value < 2**max_bits
+        #: (the kernels take this matrix in both modes)
         self.n_limbs = n_limbs_for_bits(max_bits)
         self._next_id = 0
         self._by_id: Dict[int, Relationship] = {}
@@ -225,7 +225,7 @@ class CompositeRegistry:
         self._prime_degree: Dict[int, int] = {}  # prime -> #relationships
         self._dirty = True
         self._arr: np.ndarray = np.empty(0, dtype=np.int64)
-        self._limbs: np.ndarray = np.empty((0, self.n_limbs), dtype=np.int64)
+        self._limbs: np.ndarray = np.empty((0, self.n_limbs), dtype=np.int32)
         self._limbs_version = -1
         self.version = 0  # bumped on every mutation (memoization key)
 
@@ -286,8 +286,7 @@ class CompositeRegistry:
                     a, b = pset
                     if a > b:
                         a, b = b, a
-                    if a <= 1 or b >= limit or (wide
-                                                and b >= MAX_PRIME_LIMIT):
+                    if a <= 1 or b >= limit or b >= MAX_PRIME_LIMIT:
                         encode_relationship(pset, max_bits)  # raises
                         raise AssertionError("unreachable")
                     ab = a * b
@@ -353,15 +352,15 @@ class CompositeRegistry:
                            count=len(self._prime_degree))
 
     def composites_array(self) -> np.ndarray:
-        """Flat int64 array of all live composites (kernel input).
+        """Flat int64 array of all live composites (host arithmetic).
 
-        Narrow mode only — wide (multi-limb) chunks cannot fit int64;
+        Narrow mode only — wide chunks cannot fit int64;
         use :meth:`limbs_array` (kernels) or :meth:`composites_view` /
         :meth:`composites_list` (host) there.
         """
         if self.wide:
             raise OverflowError(
-                "composites exceed int64 in wide (multi-limb) mode; use "
+                "composites exceed int64 in wide mode; use "
                 "limbs_array() / composites_view() / composites_list()")
         if self._dirty:
             self._arr = np.fromiter(self._by_composite.keys(), dtype=np.int64,
@@ -375,7 +374,7 @@ class CompositeRegistry:
 
     def composites_view(self) -> np.ndarray:
         """Registry-order composite array at whatever dtype is exact:
-        the int64 kernel view in narrow mode, an object array of Python
+        the int64 host view in narrow mode, an object array of Python
         ints in wide mode.  Host-side consumers that only index / compare
         / take ``%`` (resharding, isolation audit) stay mode-agnostic."""
         if not self.wide:
@@ -386,9 +385,9 @@ class CompositeRegistry:
         return out
 
     def limbs_array(self) -> np.ndarray:
-        """``(N, n_limbs)`` int64 little-endian 32-bit-limb matrix of all
-        live composites, registry (row) order matching
-        :meth:`composites_view` — the wide-mode kernel input."""
+        """``(N, n_limbs)`` int32 little-endian digit matrix of all live
+        composites, registry (row) order matching
+        :meth:`composites_view` — the kernel input in both modes."""
         if self._limbs_version != self.version:
             self._limbs = pack_limbs(list(self._by_composite), self.n_limbs)
             self._limbs_version = self.version

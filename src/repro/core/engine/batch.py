@@ -7,9 +7,11 @@ whole scan — per-trace PFCS tables ride along as batched inputs, and
 shorter traces are padded with key ``-1`` (an exact no-op step), so
 ragged batches lose nothing.
 
-The drivers run under ``jax.enable_x64``: all state is explicitly int32
-(DESIGN.md §3) except ARC's float64 adaptive target, which must match
-the CPython float arithmetic of the oracle bit-for-bit.
+The compiled scans run under ``jax.enable_x64``: all state is explicitly
+int32 (DESIGN.md §3) except ARC's float64 adaptive target, which must
+match the CPython float arithmetic of the oracle bit-for-bit.  Discovery
+tables are built before that context is entered, so no Pallas kernel
+ever runs under x64.
 
 ``AccessStats`` assembly mirrors the scalar simulators field-for-field,
 so callers (benchmarks, Table 1 derivations) cannot tell which engine
@@ -175,8 +177,8 @@ def simulate_batch(traces: Sequence[Trace], system: str,
     key spaces (state sized to the largest).  Returns one
     ``AccessStats`` per trace, in order.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     system = system.lower()
     if system not in VECTORIZED_SYSTEMS:
@@ -201,56 +203,57 @@ def simulate_batch(traces: Sequence[Trace], system: str,
         acc[i, :tr.length] = np.asarray(tr.accesses, dtype=np.int32)
     batched = n > 1
 
-    with enable_x64(True):
-        if system == "pfcs":
-            budget_cols = max(1, int(prefetch_budget))
-            if tables is not None:
-                # caller-built tables define the key universe (targets may
-                # index keys the residency array must be able to hold)
-                sizes = {tb.targets.shape[0] for tb in tables}
-                if len(sizes) > 1:
-                    raise ValueError(f"tables disagree on key-space size: "
-                                     f"{sorted(sizes)}")
-                if max(sizes) < n_keys:
-                    raise ValueError(
-                        f"tables cover {max(sizes)} keys but the traces "
-                        f"reach key {n_keys - 1}; rebuild with n_keys>="
-                        f"{n_keys}")
-                n_keys = max(sizes)
-                if any(tb.targets.shape[1] != budget_cols for tb in tables):
-                    raise ValueError(
-                        f"tables built for budget "
-                        f"{tables[0].targets.shape[1]}, run requested "
-                        f"{budget_cols}; rebuild with matching "
-                        f"prefetch_budget")
-            if tables is None:
-                tables = [pfcs_tables(tr, caps, prefetch_budget,
-                                      victim_window, enable_prefetch,
-                                      prefetch_trigger, discover,
-                                      n_keys=n_keys)
-                          for tr in traces]
-            budget = max(1, int(prefetch_budget))
-            tgt = np.stack([tb.targets for tb in tables])
-            truth = np.stack([tb.truth for tb in tables])
-            deg = np.stack([tb.degree for tb in tables])
-            if not batched:
-                tgt, truth, deg = tgt[0], truth[0], deg[0]
+    if system == "pfcs":
+        budget = max(1, int(prefetch_budget))
+        if tables is not None:
+            # caller-built tables define the key universe (targets may
+            # index keys the residency array must be able to hold)
+            sizes = {tb.targets.shape[0] for tb in tables}
+            if len(sizes) > 1:
+                raise ValueError(f"tables disagree on key-space size: "
+                                 f"{sorted(sizes)}")
+            if max(sizes) < n_keys:
+                raise ValueError(
+                    f"tables cover {max(sizes)} keys but the traces "
+                    f"reach key {n_keys - 1}; rebuild with n_keys>="
+                    f"{n_keys}")
+            n_keys = max(sizes)
+            if any(tb.targets.shape[1] != budget for tb in tables):
+                raise ValueError(
+                    f"tables built for budget "
+                    f"{tables[0].targets.shape[1]}, run requested "
+                    f"{budget}; rebuild with matching prefetch_budget")
+        else:
+            # discovery (possibly through the 32-bit Pallas kernels)
+            # happens here, outside the x64 context below
+            tables = [pfcs_tables(tr, caps, prefetch_budget,
+                                  victim_window, enable_prefetch,
+                                  prefetch_trigger, discover,
+                                  n_keys=n_keys)
+                      for tr in traces]
+        tgt = np.stack([tb.targets for tb in tables])
+        truth = np.stack([tb.truth for tb in tables])
+        deg = np.stack([tb.degree for tb in tables])
+        if not batched:
+            tgt, truth, deg = tgt[0], truth[0], deg[0]
+        with jax.enable_x64(True):
             fn = _pfcs_core(caps, n_keys, budget, int(victim_window),
                             bool(enable_prefetch),
                             prefetch_trigger == "always", length, batched)
             out = fn(jnp.asarray(acc if batched else acc[0]),
                      jnp.asarray(tgt), jnp.asarray(truth), jnp.asarray(deg))
-            return [_pfcs_stats(caps, out, tables[i],
-                                i if batched else None) for i in range(n)]
+        return [_pfcs_stats(caps, out, tables[i],
+                            i if batched else None) for i in range(n)]
 
-        # only LIRS carries per-key state; every other policy's compiled
-        # core is key-space independent — normalize the cache key so one
-        # compile serves traces of any key universe
-        pol_keys = n_keys if system == "lirs" else 0
+    # only LIRS carries per-key state; every other policy's compiled
+    # core is key-space independent — normalize the cache key so one
+    # compile serves traces of any key universe
+    pol_keys = n_keys if system == "lirs" else 0
+    with jax.enable_x64(True):
         fn = _baseline_core(system, caps, pol_keys, length, batched)
         out = fn(jnp.asarray(acc if batched else acc[0]))
-        return [_baseline_stats(system, caps, out, i if batched else None)
-                for i in range(n)]
+    return [_baseline_stats(system, caps, out, i if batched else None)
+            for i in range(n)]
 
 
 def sweep(traces: Sequence[Trace], systems: Sequence[str],

@@ -31,9 +31,9 @@ the mesh with ``shard_map``: every shard scans its own registry slice
 against its own query primes.  Cross-shard relationships are resolved
 by a **collective batched-gcd exchange**: each shard contributes its
 slice of the cross-shard composites, ``lax.all_gather`` replicates them
-along the mesh, and each shard computes ``gcd_pallas`` of its *query
-chunk products* (its owned query primes packed into < 2**62 composites)
-against every gathered composite.  A gcd > 1 decodes — exactly, by
+along the mesh, and each shard computes ``gcd_squarefree_pallas`` of
+its *query chunk products* (its owned query primes packed into
+registry-width composites) against every gathered composite.  A gcd > 1 decodes — exactly, by
 unique factorization — to the member primes the shard owns, so no
 per-query modulo scan ever crosses shard boundaries.
 
@@ -147,7 +147,7 @@ def shard_mesh(n_shards: int):
 
 
 def _pad_rows(rows: Sequence[np.ndarray], mult: int, fill: int,
-              dtype=np.int64) -> np.ndarray:
+              dtype=np.int32) -> np.ndarray:
     """Stack ragged 1-D arrays into (S, W), W bucketed to ``mult * 2**k``
     — power-of-two buckets bound the number of distinct compiled shapes
     as tables grow across refreshes."""
@@ -158,6 +158,24 @@ def _pad_rows(rows: Sequence[np.ndarray], mult: int, fill: int,
     out = np.full((len(rows), width), fill, dtype=dtype)
     for i, r in enumerate(rows):
         out[i, :r.shape[0]] = r
+    return out
+
+
+def _pad_digit_stack(rows: Sequence[np.ndarray], mult: int, L: int,
+                     width: Optional[int] = None) -> np.ndarray:
+    """Stack ragged (n_i, L) digit matrices into (S, W, L); pad rows
+    encode the value 1 (match nothing) and W is bucketed to
+    ``mult * 2**k`` like :func:`_pad_rows`."""
+    need = max([r.shape[0] for r in rows] + [1])
+    if width is None:
+        width = mult
+        while width < need:
+            width *= 2
+    out = np.zeros((len(rows), width, L), dtype=np.int32)
+    out[:, :, 0] = 1
+    for i, r in enumerate(rows):
+        if r.shape[0]:
+            out[i, :r.shape[0], :] = r
     return out
 
 
@@ -173,184 +191,86 @@ class ShardScanReport:
     gcd_pairs: int = 0
 
 
-def _one_shard_scan(lc, qs, ck, gathered_cross, *, n_chunks: int,
+def _one_shard_scan(lc, qs, ck, pool, gathered_cross, *, n_chunks: int,
                     interpret: bool):
-    """One shard's kernel work: local divisibility mask + cross gcds."""
-    import jax.numpy as jnp
+    """One shard's kernel work: local divisibility mask + cross gcds.
 
-    from repro.kernels.factorize import divisibility_mask_pallas
-    from repro.kernels.gcd import gcd_pallas
-
-    gcd_block = 256
-    mask = divisibility_mask_pallas(lc, qs, interpret=interpret)
-    # batched-gcd exchange: every query chunk x every cross composite
-    x = gathered_cross.shape[0]
-    a = jnp.repeat(ck, x)
-    b = jnp.tile(gathered_cross, n_chunks)
-    pad = (-a.shape[0]) % gcd_block
-    a = jnp.concatenate([a, jnp.ones((pad,), a.dtype)])
-    b = jnp.concatenate([b, jnp.ones((pad,), b.dtype)])
-    g = gcd_pallas(a, b, block_n=gcd_block, interpret=interpret)
-    return mask, g[:n_chunks * x].reshape(n_chunks, x)
-
-
-@functools.lru_cache(maxsize=64)
-def _shard_map_scan(mesh, shapes: Tuple[int, ...], interpret: bool):
-    """Compiled shard_map scan, memoized per (mesh, bucketed shapes)."""
-    import jax
-    from jax.experimental.shard_map import shard_map
-
-    from repro.sharding.partition import shard_stack_spec
-
-    axes = tuple(mesh.axis_names)
-    spec = shard_stack_spec(mesh)       # leading shard axis over data x model
-    _, _, K, _ = shapes
-
-    def body(lc, qs, ck, xc):
-        gathered = jax.lax.all_gather(xc[0], axes, tiled=True)
-        mask, g = _one_shard_scan(lc[0], qs[0], ck[0], gathered,
-                                  n_chunks=K, interpret=interpret)
-        return mask[None], g[None]
-
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(spec, spec, spec, spec),
-                             out_specs=(spec, spec), check_rep=False))
-
-
-def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
-                  chunks: np.ndarray, cross_c: np.ndarray,
-                  mesh) -> Tuple[np.ndarray, np.ndarray]:
-    """The per-shard kernel work: local divisibility masks + cross gcds.
-
-    Inputs are (S, *) padded stacks; returns ``(local_mask (S, C, Q),
-    gcds (S, K, X))``.  With a mesh of exactly S devices the work runs
-    under ``shard_map`` (one shard per device, cross composites
-    replicated by ``lax.all_gather`` — the collective exchange);
-    otherwise a host loop runs the identical kernels per shard.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    interpret = jax.default_backend() != "tpu"
-    S, C = local_c.shape
-    Q = queries.shape[1]
-    K = chunks.shape[1]
-
-    with enable_x64(True), kernel_scope("sharded_gcd_exchange", items=S * C):
-        if mesh is not None and mesh.size == S:
-            fn = _shard_map_scan(mesh, (C, Q, K, cross_c.shape[1]),
-                                 interpret)
-            mask, g = fn(jnp.asarray(local_c), jnp.asarray(queries),
-                         jnp.asarray(chunks), jnp.asarray(cross_c))
-        else:                           # host loop, same kernels, same math
-            gathered = jnp.asarray(cross_c.reshape(-1))
-            masks, gs = [], []
-            for s in range(S):
-                m, g = _one_shard_scan(jnp.asarray(local_c[s]),
-                                       jnp.asarray(queries[s]),
-                                       jnp.asarray(chunks[s]), gathered,
-                                       n_chunks=K, interpret=interpret)
-                masks.append(m)
-                gs.append(g)
-            mask, g = jnp.stack(masks), jnp.stack(gs)
-        return np.asarray(mask), np.asarray(g)
-
-
-# --------------------------------------------------------------------------- #
-# multi-limb twin of the shard scan (wide registries, DESIGN.md §11)          #
-# --------------------------------------------------------------------------- #
-
-def _pad_limb_stack(rows: Sequence[np.ndarray], mult: int, L: int,
-                    width: Optional[int] = None) -> np.ndarray:
-    """Stack ragged (n_i, L) limb matrices into (S, W, L); pad rows encode
-    composite value 1 (match nothing) and W is bucketed to ``mult * 2**k``
-    like :func:`_pad_rows`."""
-    need = max([r.shape[0] for r in rows] + [1])
-    if width is None:
-        width = mult
-        while width < need:
-            width *= 2
-    out = np.zeros((len(rows), width, L), dtype=np.int64)
-    out[:, :, 0] = 1
-    for i, r in enumerate(rows):
-        if r.shape[0]:
-            out[i, :r.shape[0], :] = r
-    return out
-
-
-def _one_shard_scan_limbs(lc, qs, ck, pool, gathered_cross, *, n_chunks: int,
-                          interpret: bool):
-    """One shard's limb-kernel work: local divisibility mask + cross gcds.
-
-    Same collective recipe as :func:`_one_shard_scan` with (.., L) limb
-    rows instead of int64 words; the gcd pool is the shard's own
+    ``lc`` (C, L) local digit rows, ``qs`` (Q,) owned query primes,
+    ``ck`` (K, L) query chunk products, ``gathered_cross`` (X, L) every
+    shard's cross composites.  The gcd pool is the shard's own
     (deduplicated, zero-padded) query primes — chunk products are
     products of exactly those primes, so the pool covers every possible
     common factor.
     """
     import jax.numpy as jnp
 
-    from repro.kernels.factorize import divisibility_mask_limbs_pallas
-    from repro.kernels.gcd import gcd_limbs_pallas
+    from repro.kernels.factorize import divisibility_mask_pallas
+    from repro.kernels.gcd import gcd_squarefree_pallas
 
     gcd_block = 256
-    mask = divisibility_mask_limbs_pallas(lc, qs, interpret=interpret)
+    mask = divisibility_mask_pallas(lc, qs, interpret=interpret)
+    # batched-gcd exchange: every query chunk x every cross composite
     x, L = gathered_cross.shape
     a = jnp.repeat(ck, x, axis=0)                       # (K*X, L)
     b = jnp.tile(gathered_cross, (n_chunks, 1))
     pad = (-a.shape[0]) % gcd_block
-    one = jnp.zeros((pad, L), a.dtype).at[:, 0].set(1)
+    one = jnp.broadcast_to((jnp.arange(L) == 0).astype(a.dtype), (pad, L))
     a = jnp.concatenate([a, one])
     b = jnp.concatenate([b, one])
-    g = gcd_limbs_pallas(a, b, pool, block_n=gcd_block, interpret=interpret)
+    g = gcd_squarefree_pallas(a, b, pool, block_n=gcd_block,
+                              interpret=interpret)
     return mask, g[:n_chunks * x].reshape(n_chunks, x, L)
 
 
 @functools.lru_cache(maxsize=64)
-def _shard_map_scan_limbs(mesh, shapes: Tuple[int, ...], interpret: bool):
-    """Compiled wide shard_map scan, memoized per (mesh, bucketed shapes)."""
+def _shard_map_scan(mesh, shapes: Tuple[int, ...], interpret: bool):
+    """Compiled shard_map scan, memoized per (mesh, bucketed shapes)."""
     import jax
-    from jax.experimental.shard_map import shard_map
 
     from repro.sharding.partition import shard_stack_spec
 
     axes = tuple(mesh.axis_names)
-    spec = shard_stack_spec(mesh)
+    spec = shard_stack_spec(mesh)       # leading shard axis over data x model
     _, _, K, _, _ = shapes
 
     def body(lc, qs, ck, pool, xc):
         gathered = jax.lax.all_gather(xc[0], axes, tiled=True)
-        mask, g = _one_shard_scan_limbs(lc[0], qs[0], ck[0], pool[0],
-                                        gathered, n_chunks=K,
-                                        interpret=interpret)
+        mask, g = _one_shard_scan(lc[0], qs[0], ck[0], pool[0], gathered,
+                                  n_chunks=K, interpret=interpret)
         return mask[None], g[None]
 
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(spec, spec, spec, spec, spec),
-                             out_specs=(spec, spec), check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(spec,) * 5,
+                                 out_specs=(spec, spec), check_vma=False))
 
 
-def _scan_sharded_limbs(local_c: np.ndarray, queries: np.ndarray,
-                        chunks: np.ndarray, pools: np.ndarray,
-                        cross_c: np.ndarray,
-                        mesh) -> Tuple[np.ndarray, np.ndarray]:
-    """Wide twin of :func:`_scan_sharded`: (S, C, L) local limb stacks,
-    (S, K, L) query-chunk limbs, (S, X, L) cross slices; returns
-    ``(local_mask (S, C, Q), gcd limbs (S, K, X, L))``."""
-    import jax
+def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
+                  chunks: np.ndarray, pools: np.ndarray,
+                  cross_c: np.ndarray,
+                  mesh) -> Tuple[np.ndarray, np.ndarray]:
+    """The per-shard kernel work: local divisibility masks + cross gcds.
+
+    Inputs are padded stacks: (S, C, L) local digit rows, (S, Q) query
+    primes, (S, K, L) query-chunk digits, (S, P) gcd pools and (S, X, L)
+    cross slices; returns ``(local_mask (S, C, Q), gcd digits (S, K, X,
+    L))``.  With a mesh of exactly S devices the work runs under
+    ``shard_map`` (one shard per device, cross composites replicated by
+    ``lax.all_gather`` — the collective exchange); otherwise a host loop
+    runs the identical kernels per shard.
+    """
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    interpret = jax.default_backend() != "tpu"
+    from repro.kernels.ops import check_32bit, interpret_mode
+
+    interpret = interpret_mode()
+    check_32bit()
     S, C, L = local_c.shape
     Q = queries.shape[1]
     K = chunks.shape[1]
 
-    with enable_x64(True), kernel_scope("sharded_gcd_exchange_limbs",
-                                        items=S * C):
+    with kernel_scope("sharded_gcd_exchange", items=S * C):
         if mesh is not None and mesh.size == S:
-            fn = _shard_map_scan_limbs(
+            fn = _shard_map_scan(
                 mesh, (C, Q, K, pools.shape[1], cross_c.shape[1]), interpret)
             mask, g = fn(jnp.asarray(local_c), jnp.asarray(queries),
                          jnp.asarray(chunks), jnp.asarray(pools),
@@ -359,7 +279,7 @@ def _scan_sharded_limbs(local_c: np.ndarray, queries: np.ndarray,
             gathered = jnp.asarray(cross_c.reshape(-1, L))
             masks, gs = [], []
             for s in range(S):
-                m, g = _one_shard_scan_limbs(
+                m, g = _one_shard_scan(
                     jnp.asarray(local_c[s]), jnp.asarray(queries[s]),
                     jnp.asarray(chunks[s]), jnp.asarray(pools[s]), gathered,
                     n_chunks=K, interpret=interpret)
@@ -393,12 +313,11 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     primes yields identical rows — a prime's hits can only come from the
     chunk containing it.
     """
-    from repro.kernels.ops import factorize_batch_exact
+    from repro.kernels.ops import factorize_batch, prime_lanes
 
-    from ..composite import limbs_to_int, pack_limbs
+    from ..composite import pack_limbs, unpack_limbs
 
     S = partition.n_shards
-    wide = getattr(registry, "wide", False)
     keyed = [(int(d), p) for d in data_ids
              if (p := assigner.prime_of(int(d))) is not None]
     arr = registry.composites_view()
@@ -414,16 +333,16 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     for d, p in keyed:
         by_shard[partition.owner(p)].append((d, p))
 
-    queries = _pad_rows([np.asarray([p for _, p in sh], dtype=np.int64)
-                         for sh in by_shard], 512, 0)
+    queries = _pad_rows([prime_lanes([p for _, p in sh]) for sh in by_shard],
+                        512, 0)
     # query chunk products: each shard's owned query primes packed into
-    # < 2**max_bits composites — the gcd exchange payload (one wide limb
+    # registry-width composites — the gcd exchange payload (one wide
     # chunk usually covers the whole shard's query set)
-    chunk_bits = registry.max_bits if wide else 62
     chunk_vals: List[List[int]] = []
     for sh in by_shard:
         ps = {p for _, p in sh}
-        chunk_vals.append(encode_relationship(ps, chunk_bits) if ps else [])
+        chunk_vals.append(encode_relationship(ps, registry.max_bits)
+                          if ps else [])
     # per-shard cross-slice width bucketed to powers of two, like every
     # other stack: an exact ceil(cross/S) width would change the compiled
     # shard_map shape on nearly every registry growth
@@ -432,44 +351,26 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     while per < need:
         per *= 2
 
-    if wide:
-        limbs = registry.limbs_array()
-        Lw = registry.n_limbs
-        local_c = _pad_limb_stack(
-            [limbs[np.asarray(pos, dtype=np.int64)]
-             if pos else np.empty((0, Lw), np.int64)
-             for pos in local_pos], 256, Lw)
-        chunks = _pad_limb_stack([pack_limbs(cv, Lw) for cv in chunk_vals],
-                                 1, Lw)
-        # the gcd-reconstruction pool: each shard's deduplicated query
-        # primes (zero-padded) — exactly the primes its chunks contain
-        pools = _pad_rows([np.asarray(sorted({p for _, p in sh}),
-                                      dtype=np.int64) for sh in by_shard],
-                          512, 0)
-        cross_limbs = (limbs[np.asarray(cross_pos, dtype=np.int64)]
-                       if cross_pos else np.empty((0, Lw), np.int64))
-        cross_sh = _pad_limb_stack(
-            [cross_limbs[s * per:(s + 1) * per] for s in range(S)],
-            1, Lw, width=per)
-        mask, gcds = _scan_sharded_limbs(local_c, queries, chunks, pools,
-                                         cross_sh, mesh)
-        n_gcd_pairs = int(chunks.shape[1] * S * per)
-    else:
-        local_c = _pad_rows([arr[np.asarray(pos, dtype=np.int64)]
-                             if pos else np.empty(0, np.int64)
-                             for pos in local_pos], 256, 1)
-        chunks = _pad_rows([np.asarray(cv, dtype=np.int64)
-                            for cv in chunk_vals], 1, 1)
-        cross_arr = (arr[np.asarray(cross_pos, dtype=np.int64)]
-                     if cross_pos else np.empty(0, np.int64))
-        cross_sh = np.ones((S, per), dtype=np.int64)
-        for s in range(S):
-            sl = cross_arr[s * per:(s + 1) * per]
-            cross_sh[s, :sl.shape[0]] = sl
+    digits = registry.limbs_array()
+    L = registry.n_limbs
+    local_c = _pad_digit_stack(
+        [digits[np.asarray(pos, dtype=np.int64)]
+         if pos else np.empty((0, L), np.int32)
+         for pos in local_pos], 256, L)
+    chunks = _pad_digit_stack([pack_limbs(cv, L) for cv in chunk_vals], 1, L)
+    # the gcd-reconstruction pool: each shard's deduplicated query primes
+    # (zero-padded) — exactly the primes its chunks contain
+    pools = _pad_rows([prime_lanes(sorted({p for _, p in sh}))
+                       for sh in by_shard], 512, 0)
+    cross_digits = (digits[np.asarray(cross_pos, dtype=np.int64)]
+                    if cross_pos else np.empty((0, L), np.int32))
+    cross_sh = _pad_digit_stack(
+        [cross_digits[s * per:(s + 1) * per] for s in range(S)],
+        1, L, width=per)
 
-        # ---- kernel work (shard_map when the mesh matches) -------------- #
-        mask, gcds = _scan_sharded(local_c, queries, chunks, cross_sh, mesh)
-        n_gcd_pairs = int(chunks.shape[1] * cross_sh.size)
+    # ---- kernel work (shard_map when the mesh matches) ------------------ #
+    mask, gcds = _scan_sharded(local_c, queries, chunks, pools, cross_sh,
+                               mesh)
 
     if report is not None:
         report.n_shards = S
@@ -477,7 +378,7 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
         report.local_composites = [len(p) for p in local_pos]
         report.cross_composites = len(cross_pos)
         report.queries_per_shard = [len(sh) for sh in by_shard]
-        report.gcd_pairs = n_gcd_pairs
+        report.gcd_pairs = int(chunks.shape[1] * S * per)
 
     # ---- decode the gcd exchange: which cross composites contain which
     # owned query primes (exact — unique factorization) ------------------- #
@@ -485,26 +386,19 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     for s in range(S):
         if not by_shard[s] or not cross_pos:
             continue
-        pool = np.asarray(sorted({p for _, p in by_shard[s]}), dtype=np.int64)
-        gs = gcds[s]                        # (K, X) or (K, X, L) limb rows
-        if wide:
-            # value > 1 iff limb0 > 1 or any higher limb nonzero
-            high = ((gs[..., 1:] != 0).any(axis=-1) if gs.shape[-1] > 1
-                    else np.zeros(gs.shape[:2], dtype=bool))
-            hit_k, hit_x = np.nonzero((gs[..., 0] > 1) | high)
-        else:
-            hit_k, hit_x = np.nonzero(gs > 1)
+        pool = sorted({p for _, p in by_shard[s]})
+        gs = gcds[s]                        # (K, X, L) digit rows
+        # value > 1 iff digit 0 > 1 or any higher digit nonzero
+        hit_k, hit_x = np.nonzero((gs[..., 0] > 1)
+                                  | (gs[..., 1:] != 0).any(axis=-1))
         valid = hit_x < len(cross_pos)      # drop padding columns
         hit_k, hit_x = hit_k[valid], hit_x[valid]
-        if wide:
-            hit_vals = [limbs_to_int(gs[k, x]) for k, x in zip(hit_k, hit_x)]
-        else:
-            hit_vals = [int(gs[k, x]) for k, x in zip(hit_k, hit_x)]
+        hit_vals = unpack_limbs(gs[hit_k, hit_x])
         uniq = sorted(set(hit_vals))
         if not uniq:
             continue
-        facs, residual = factorize_batch_exact(uniq, pool)
-        assert all(int(r) == 1 for r in residual), \
+        facs, residual = factorize_batch(uniq, pool)
+        assert all(r == 1 for r in residual), \
             "gcd escaped the shard's query pool"
         fac_of = {g: fs for g, fs in zip(uniq, facs)}
         for x, v in zip(hit_x, hit_vals):

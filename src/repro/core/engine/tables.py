@@ -85,7 +85,7 @@ def related_bulk(cache: PFCSCache, keys: Sequence[int],
 
     registry = cache.registry
     assigner = cache.assigner
-    arr = registry.composites_array()
+    arr = registry.composites_view()
     keyed = [(int(k), p) for k in keys
              if (p := assigner.prime_of(int(k))) is not None]
     if arr.size == 0 or not keyed:
@@ -93,9 +93,10 @@ def related_bulk(cache: PFCSCache, keys: Sequence[int],
 
     # kernel pass 1: registry divisibility scan, chunked over query primes
     primes = np.asarray([p for _, p in keyed], dtype=np.int64)
+    digits = registry.limbs_array()
     cand: List[np.ndarray] = []
     for lo in range(0, len(primes), chunk):
-        cand.extend(divisibility_scan(arr, primes[lo:lo + chunk]))
+        cand.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
 
     # kernel pass 2: decode every candidate composite once
     needed = sorted({int(i) for idxs in cand for i in idxs})
@@ -103,8 +104,9 @@ def related_bulk(cache: PFCSCache, keys: Sequence[int],
     if needed:
         comps = arr[np.asarray(needed)]
         pool = registry.primes_array()
-        facs, residual = factorize_batch(comps, pool)
-        assert np.all(residual == 1), "registry composite escaped its pool"
+        facs, residual = factorize_batch(digits[np.asarray(needed)], pool)
+        assert all(r == 1 for r in residual), \
+            "registry composite escaped its pool"
         stats = cache.factorizer.stats
         for c, fs in zip(comps, facs):
             factors_of[int(c)] = set(fs)
@@ -184,24 +186,19 @@ def successor_table(registry, assigner, data_ids: Sequence[int],
         raise ValueError(f"discover must be 'host' or 'kernel', "
                          f"got {discover!r}")
 
-    from repro.kernels.ops import (divisibility_scan,
-                                   divisibility_scan_limbs, factorize_batch,
-                                   factorize_batch_exact)
+    from repro.kernels.ops import divisibility_scan, factorize_batch
 
-    wide = getattr(registry, "wide", False)
-    arr = registry.composites_view() if wide else registry.composites_array()
+    arr = registry.composites_view()
     if arr.size == 0 or not keyed:
         return {d: [] for d, _ in keyed}
 
-    # kernel pass 1: registry divisibility scan, chunked over query primes
-    # (wide registries route through the multi-limb kernels — same mask
-    # semantics, DESIGN.md §11)
+    # kernel pass 1: registry divisibility scan over the digit matrix
+    # (every width, DESIGN.md §3), chunked over query primes
     primes = np.asarray([p for _, p in keyed], dtype=np.int64)
-    scan_input = registry.limbs_array() if wide else arr
-    scan = divisibility_scan_limbs if wide else divisibility_scan
+    digits = registry.limbs_array()
     cand: List[np.ndarray] = []
     for lo in range(0, len(primes), chunk):
-        cand.extend(scan(scan_input, primes[lo:lo + chunk]))
+        cand.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
 
     # kernel pass 2: decode every candidate composite once (Theorem 1
     # check: the decoded factors must contain the query prime)
@@ -209,8 +206,9 @@ def successor_table(registry, assigner, data_ids: Sequence[int],
     factors_of: Dict[int, set] = {}
     if needed:
         comps = arr[np.asarray(needed)]
-        facs, residual = factorize_batch_exact(comps, registry.primes_array())
-        assert all(int(r) == 1 for r in residual), \
+        facs, residual = factorize_batch(digits[np.asarray(needed)],
+                                         registry.primes_array())
+        assert all(r == 1 for r in residual), \
             "registry composite escaped its pool"
         for c, fs in zip(comps, facs):
             factors_of[int(c)] = set(fs)

@@ -35,7 +35,16 @@ __all__ = [
     "LEVEL_PRIME_RANGES",
     "PrimePool",
     "HierarchicalPrimeAllocator",
+    "MAX_PRIME_BITS",
+    "MAX_PRIME_LIMIT",
 ]
+
+#: widest prime the device kernels take (DESIGN.md §3): with 6-bit
+#: digits in int32 lanes, ``remainder * 2**6 + digit < p * 2**6 <= 2**31``.
+#: 2**25 is ~2.06M primes — twice the 1M-element ``case_scale`` registry.
+#: Pools refuse to mint anything wider.
+MAX_PRIME_BITS = 25
+MAX_PRIME_LIMIT = 1 << MAX_PRIME_BITS
 
 
 # --------------------------------------------------------------------------
@@ -223,7 +232,7 @@ class PrimePool:
                 self._extend(self.initial_capacity)
             else:
                 return None
-        p = self._primes[self._next_idx]
+        p = _mintable(self._primes[self._next_idx])
         self._next_idx += 1
         self._allocated.add(p)
         return p
@@ -253,6 +262,8 @@ class PrimePool:
             if self.hi is None and len(self._primes) - self._next_idx < want:
                 self._extend(want - (len(self._primes) - self._next_idx))
             fresh = self._primes[self._next_idx : self._next_idx + want]
+            if fresh:
+                _mintable(fresh[-1])            # ascending: the widest one
             self._next_idx += len(fresh)
             out.extend(fresh)
         self._allocated.update(out)
@@ -273,6 +284,16 @@ class PrimePool:
 
     def contains_range(self, p: int) -> bool:
         return p >= self.lo and (self.hi is None or p <= self.hi)
+
+
+def _mintable(p: int) -> int:
+    """``p`` if the device kernels can take it; raises otherwise (a
+    prime is never truncated or wrapped into range)."""
+    if p >= MAX_PRIME_LIMIT:
+        raise ValueError(
+            f"prime {p} exceeds the {MAX_PRIME_BITS}-bit kernel prime bound; "
+            f"the pool is out of device-representable primes")
+    return p
 
 
 class HierarchicalPrimeAllocator:

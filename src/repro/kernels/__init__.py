@@ -1,21 +1,18 @@
 """Pallas TPU kernels for the PFCS factorization hot paths.
 
-``factorize.py``  — batched squarefree trial-division factorization
-                    (VMEM-tiled composites x prime-pool grid)
-``gcd.py``        — batched Euclidean gcd (chain-composite intersection)
-``ops.py``        — host-facing jit'd wrappers (padding, int32/int64 path)
-``ref.py``        — pure-jnp oracles the kernels are tested against
+``factorize.py``  — §4.2 divisibility scan and batched squarefree
+                    trial-division factorization (digit tiles x prime
+                    pool grid)
+``gcd.py``        — batched gcd: int32 Euclid, and squarefree gcd of
+                    any width by prime-pool reconstruction
+``ops.py``        — host-facing wrappers (digit packing, range checks,
+                    padding, backend dispatch)
+``ref.py``        — exact host oracles the kernels are tested against
 
-Validated in interpret mode on CPU; compiled path targets TPU (see
-DESIGN.md §3 for the int-width adaptation notes).
+Every kernel runs in int32 lanes (DESIGN.md §3): compiled on TPU,
+interpreted on CPU for the tests.
 """
 
-from .ops import (INT32_SAFE_LIMIT, INT64_SAFE_LIMIT, divisibility_scan,
-                  divisibility_scan_limbs, factorize_batch,
-                  factorize_batch_exact, factorize_batch_limbs, gcd_batch,
-                  gcd_batch_exact, gcd_batch_limbs)
+from .ops import divisibility_scan, factorize_batch, gcd_batch
 
-__all__ = ["INT32_SAFE_LIMIT", "INT64_SAFE_LIMIT", "divisibility_scan",
-           "divisibility_scan_limbs", "factorize_batch",
-           "factorize_batch_exact", "factorize_batch_limbs", "gcd_batch",
-           "gcd_batch_exact", "gcd_batch_limbs"]
+__all__ = ["divisibility_scan", "factorize_batch", "gcd_batch"]

@@ -1,29 +1,37 @@
-"""Pallas TPU kernel: batched squarefree factorization by trial division.
+"""Pallas TPU kernels: §4.2 divisibility scan and batched squarefree
+factorization by trial division.
 
 PFCS Algorithm 2 stage 1 (trial division against a prime pool), adapted
 from the paper's per-access scalar loop to a TPU-native *batched* kernel:
 the registry refresh / bulk relationship-discovery path factorizes many
 composites against a whole pool at once.
 
-Layout (all VMEM):
-    composites tile  (BN, 1)  int32/int64  — one composite per sublane row
-    primes tile      (1, BP)  int32/int64  — prime pool along lanes
-    mask out tile    (BN, BP) bool         — mask[i,j] = p_j | c_i
-    residual out     (BN, 1)               — c_i / prod(dividing p_j)
+Digits in int32 lanes (DESIGN.md §3).  A composite of any width is its
+little-endian ``LIMB_BITS``-bit digits (``core.composite.pack_limbs``),
+and the kernels take them digit-major, ``(L, N)``: composites along
+lanes, digit ``k`` of a whole tile one sublane row.  Primes are below
+``MAX_PRIME_LIMIT`` (2**25), so with 6-bit digits every step is exact
+int32 arithmetic:
+
+    Horner remainder   r = (r * 2**6 + digit) % p      r < p
+                       => r * 2**6 + digit < p * 2**6 <= 2**31
+    short division     cur = carry * 2**6 + digit;  q = cur // p
+                       carry < p  => the same bound; q < 2**6
+
+Layout of one (BP primes) x (BN composites) grid cell:
+    digits tile      (L, BN)  int32  VMEM
+    primes tile      (BP, 1)  int32  VMEM — broadcast along lanes
+    primes scalars   (1, BP)  int32  SMEM — read one at a time
+    mask out tile    (BP, BN) bool   — mask[j, i] = p_j | c_i
+    residual out     (L, BN)  int32  — c_i // prod(dividing p_j)
 
 Grid: (N/BN, P/BP).  The prime axis (j) is the innermost, sequentially
-executed grid dimension on TPU, so the residual tile accumulates the
-running cofactor across prime tiles: initialized to the composite at
-j == 0, divided by every dividing prime as tiles stream through.  This is
-the standard TPU accumulator pattern (same shape as a matmul K-loop).
-
-Default tile sizes keep the working set well under VMEM (BN=256, BP=512
-int32 ≈ 0.5 MB including the bool tile) and lane-align BP to 128.
-
-TPU int width note (DESIGN.md §3): the int32 fast path covers L1xL1 and
-L1xL2 composites (the hot path by construction — hot data gets small
-primes).  The int64 variant is validated in interpret mode and is the
-reference semantics for hardware with emulated 64-bit integer ops.
+executed grid dimension, so the residual tile accumulates the running
+cofactor across prime tiles: the composite at j == 0, then divided by
+every dividing prime as tiles stream through (the matmul K-loop
+accumulator pattern).  Inside a tile the division walks the primes one
+at a time with a scalar read from SMEM and a dynamic sublane read of its
+mask row — no dynamic lane indexing, no cross-lane reduction.
 """
 
 from __future__ import annotations
@@ -34,252 +42,142 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["factorize_squarefree_pallas", "divisibility_mask_pallas",
-           "divisibility_mask_limbs_pallas", "factorize_limbs_pallas"]
+from repro.core.composite import LIMB_BITS
 
-# ----------------------------------------------------------------------- #
-# multi-limb variants (DESIGN.md §11)                                     #
-# ----------------------------------------------------------------------- #
-# Composites wider than 63 bits arrive as (N, L) little-endian 32-bit
-# limbs in int64 lanes.  All arithmetic is exact integer:
-#
-#   Horner mod      r = (r * 2**32 + limb) % p      r < p < 2**31
-#                   => r * 2**32 + limb < p * 2**32 <= 2**63        OK
-#   short division  cur = carry * 2**32 + limb; q, carry = divmod(cur, p)
-#                   carry < p < 2**31 => cur < 2**63                OK
-#
-# so every intermediate fits a signed int64 as long as primes fit 31 bits
-# (MAX_PRIME_BITS in core.composite — the pools never mint larger).  The
-# limb count L is static (baked into the traced program), tiles are
-# (BN, L) composites x (1, BP) primes exactly like the flat kernels.
+__all__ = ["divisibility_mask_pallas", "factorize_squarefree_pallas"]
 
-_LIMB_BITS = 32
-_LIMB_BASE = 1 << _LIMB_BITS
+_BASE = 1 << LIMB_BITS
 
 
-def _horner_mod(limbs, p):
-    """Remainder of an (BN, L)-limb composite modulo (1, BP) primes.
+def _divides(c_ref, p):
+    """``(BP, BN)`` bool: prime row ``j`` divides composite column ``i``.
 
-    Little-endian limbs evaluated most-significant-first (Horner);
-    returns (BN, BP) remainders.  ``p`` must be sanitized > 0.
+    ``c_ref`` holds ``(L, BN)`` digits, ``p`` the ``(BP, 1)`` primes.
+    Primes <= 1 (padding) divide nothing.  Horner's rule runs most
+    significant digit first, one dynamic sublane row per step.
     """
-    bn, L = limbs.shape
-    r = jnp.zeros((bn, p.shape[1]), dtype=jnp.int64)
-    for k in reversed(range(L)):
-        r = (r * _LIMB_BASE + limbs[:, k:k + 1]) % p
-    return r
+    L, bn = c_ref.shape
+    safe_p = jnp.maximum(p, 1)
 
+    def step(k, r):
+        return (r * _BASE + c_ref[pl.ds(L - 1 - k, 1), :]) % safe_p
 
-def _short_div(limbs, p):
-    """Exact division of (BN, L) limbs by a scalar prime p (int64).
-
-    Most-significant-first schoolbook short division; returns the
-    quotient limbs.  Caller guarantees divisibility (squarefree exact
-    path) — the final carry is the remainder and is discarded.
-    """
-    bn, L = limbs.shape
-    carry = jnp.zeros((bn,), dtype=jnp.int64)
-    out = [None] * L
-    for k in reversed(range(L)):
-        cur = carry * _LIMB_BASE + limbs[:, k]
-        out[k] = cur // p
-        carry = cur % p
-    return jnp.stack(out, axis=1)
-
-
-def _divmask_limbs_kernel(c_ref, p_ref, mask_ref):
-    limbs = c_ref[...]                       # (BN, L)
-    p = p_ref[...]                           # (1, BP)
-    safe_p = jnp.where(p <= 1, jnp.ones_like(p), p)
-    mask_ref[...] = jnp.logical_and(_horner_mod(limbs, safe_p) == 0, p > 1)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
-def divisibility_mask_limbs_pallas(
-    limbs: jnp.ndarray,        # (N, L) int64 32-bit limbs, N % block_n == 0
-    primes: jnp.ndarray,       # (P,)  int64, P % block_p == 0
-    *,
-    block_n: int = 256,
-    block_p: int = 512,
-    interpret: bool = True,
-):
-    """Wide §4.2 prefetch scan: mask[i, j] = primes[j] | composite(limbs[i]).
-
-    Limb rows of all-zero / value-1 composites (padding) match nothing;
-    zero-padded primes never divide (same pad contract as the flat
-    kernel).
-    """
-    n, L = limbs.shape
-    p = primes.shape[0]
-    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
-    grid = (n // block_n, p // block_p)
-    return pl.pallas_call(
-        _divmask_limbs_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_p), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_p), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, p), jnp.bool_),
-        interpret=interpret,
-    )(limbs, primes.reshape(1, p))
-
-
-def _factorize_limbs_kernel(c_ref, p_ref, mask_ref, res_ref, *, block_p: int):
-    j = pl.program_id(1)
-    limbs = c_ref[...]                       # (BN, L)
-    p = p_ref[...]                           # (1, BP)
-    safe_p = jnp.where(p <= 1, jnp.ones_like(p), p)
-    divides = jnp.logical_and(_horner_mod(limbs, safe_p) == 0, p > 1)
-    mask_ref[...] = divides
-
-    @pl.when(j == 0)
-    def _init():
-        res_ref[...] = limbs
-
-    # peel off every dividing prime of this tile sequentially: short
-    # division is inherently most-significant-first, so unlike the flat
-    # kernel there is no one-shot tile-product divide — but the body is
-    # traced ONCE (fori_loop) and each trip is L exact int64 ops/lane.
-    def body(jj, res):
-        pj = lax.dynamic_index_in_dim(safe_p[0], jj, keepdims=False)
-        div = lax.dynamic_index_in_dim(divides, jj, axis=1, keepdims=False)
-        return jnp.where(div[:, None], _short_div(res, pj), res)
-
-    res_ref[...] = lax.fori_loop(0, block_p, body, res_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
-def factorize_limbs_pallas(
-    limbs: jnp.ndarray,        # (N, L) int64 32-bit limbs, N % block_n == 0
-    primes: jnp.ndarray,       # (P,)  int64, P % block_p == 0
-    *,
-    block_n: int = 256,
-    block_p: int = 512,
-    interpret: bool = True,
-):
-    """Wide squarefree factorization: ``(mask (N, P) bool, residual
-    (N, L))`` where the residual limbs hold the cofactor after dividing
-    out every dividing pool prime (limb value 1 when fully factored).
-    Same grid/accumulator shape as :func:`factorize_squarefree_pallas`
-    with the residual tile carrying L limbs instead of one word.
-    """
-    n, L = limbs.shape
-    p = primes.shape[0]
-    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
-    grid = (n // block_n, p // block_p)
-    mask, residual = pl.pallas_call(
-        functools.partial(_factorize_limbs_kernel, block_p=block_p),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_p), lambda i, j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, block_p), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, p), jnp.bool_),
-            jax.ShapeDtypeStruct((n, L), jnp.int64),
-        ],
-        interpret=interpret,
-    )(limbs, primes.reshape(1, p))
-    return mask, residual
-
-
-def _factorize_kernel(c_ref, p_ref, mask_ref, res_ref):
-    """One (BN, BP) tile: divisibility mask + residual accumulation."""
-    j = pl.program_id(1)
-    c = c_ref[...]          # (BN, 1)
-    p = p_ref[...]          # (1, BP)
-    safe_p = jnp.where(p <= 1, jnp.ones_like(p), p)
-    divides = jnp.logical_and((c % safe_p) == 0, p > 1)   # (BN, BP)
-    mask_ref[...] = divides
-
-    # residual accumulator: init with the composite on the first prime tile
-    @pl.when(j == 0)
-    def _init():
-        res_ref[...] = c
-
-    # divide out every dividing prime in this tile (squarefree: each prime
-    # appears at most once, so a single exact division per prime is exact).
-    factor = jnp.where(divides, safe_p, jnp.ones_like(safe_p))
-    tile_prod = jnp.prod(factor, axis=1, keepdims=True)   # (BN, 1)
-    res_ref[...] = res_ref[...] // jnp.maximum(tile_prod, 1)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
-def factorize_squarefree_pallas(
-    composites: jnp.ndarray,   # (N,) int32/int64, N % block_n == 0
-    primes: jnp.ndarray,       # (P,) same dtype, P % block_p == 0
-    *,
-    block_n: int = 256,
-    block_p: int = 512,
-    interpret: bool = True,
-):
-    """Returns ``(mask (N, P) bool, residual (N,))`` — see ref.py oracle."""
-    n, p = composites.shape[0], primes.shape[0]
-    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
-    c2 = composites.reshape(n, 1)
-    p2 = primes.reshape(1, p)
-    grid = (n // block_n, p // block_p)
-
-    mask, residual = pl.pallas_call(
-        _factorize_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_p), lambda i, j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, block_p), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, p), jnp.bool_),
-            jax.ShapeDtypeStruct((n, 1), composites.dtype),
-        ],
-        interpret=interpret,
-    )(c2, p2)
-    return mask, residual.reshape(n)
+    r = lax.fori_loop(0, L, step, jnp.zeros((p.shape[0], bn), jnp.int32))
+    return jnp.logical_and(r == 0, p > 1)
 
 
 def _divmask_kernel(c_ref, p_ref, mask_ref):
-    c = c_ref[...]
-    p = p_ref[...]
-    safe_p = jnp.where(p <= 1, jnp.ones_like(p), p)
-    mask_ref[...] = jnp.logical_and((c % safe_p) == 0, p > 1)
+    mask_ref[...] = _divides(c_ref, p_ref[...])
+
+
+def _factorize_kernel(c_ref, p_ref, ps_ref, mask_ref, res_ref, take_ref):
+    j = pl.program_id(1)
+    L = c_ref.shape[0]
+    divides = _divides(c_ref, p_ref[...])
+    mask_ref[...] = divides
+    take_ref[...] = divides.astype(jnp.int32)
+
+    @pl.when(j == 0)
+    def _init():
+        res_ref[...] = c_ref[...]
+
+    # divide the residual by every dividing prime of this tile, one
+    # prime at a time: floor(floor(c / a) / b) == floor(c / (a * b)), so
+    # the result equals c // prod(dividing primes) exactly
+    def per_prime(jj, _):
+        p = jnp.maximum(ps_ref[0, jj], 1)
+        take = take_ref[pl.ds(jj, 1), :] != 0             # (1, BN)
+
+        def step(k, carry):
+            row = pl.ds(L - 1 - k, 1)
+            cur = carry * _BASE + res_ref[row, :]
+            q = cur // p
+            res_ref[row, :] = jnp.where(take, q, res_ref[row, :])
+            return cur - q * p
+
+        lax.fori_loop(0, L, step, jnp.zeros(take.shape, jnp.int32))
+        return 0
+
+    lax.fori_loop(0, ps_ref.shape[1], per_prime, 0)
+
+
+def _specs(L: int, block_n: int, block_p: int):
+    digits = pl.BlockSpec((L, block_n), lambda i, j: (0, i))
+    primes = pl.BlockSpec((block_p, 1), lambda i, j: (j, 0))
+    scalars = pl.BlockSpec((None, 1, block_p), lambda i, j: (j, 0, 0),
+                           memory_space=pltpu.SMEM)
+    mask = pl.BlockSpec((block_p, block_n), lambda i, j: (j, i))
+    return digits, primes, scalars, mask
+
+
+def _check(digits, primes, block_n: int, block_p: int):
+    n, _ = digits.shape
+    p = primes.shape[0]
+    assert digits.dtype == jnp.int32 and primes.dtype == jnp.int32, \
+        (digits.dtype, primes.dtype)
+    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
+    return n, p
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
 def divisibility_mask_pallas(
-    composites: jnp.ndarray,   # (N,) — the registry
-    primes: jnp.ndarray,       # (P,) — query primes (recently accessed)
+    digits: jnp.ndarray,       # (N, L) int32 digits, N % block_n == 0
+    primes: jnp.ndarray,       # (P,) int32 query primes, P % block_p == 0
     *,
     block_n: int = 256,
     block_p: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
-    """Prefetch candidate scan (§4.2): mask[i, j] = primes[j] | composites[i].
+    """Prefetch candidate scan (§4.2): ``mask[i, j] = primes[j] | c_i``.
 
-    Mask-only variant of the factorize kernel for the serving-path hot
-    loop: the host compacts per-query candidate lists from the mask and
-    hands pairwise cofactors to the O(1) primality fast path.
+    The host compacts per-query candidate lists from the ``(N, P)`` mask.
+    Padding rows (value 0 or 1) match nothing; zero-padded primes never
+    divide.
     """
-    n, p = composites.shape[0], primes.shape[0]
-    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
-    grid = (n // block_n, p // block_p)
-    return pl.pallas_call(
+    n, p = _check(digits, primes, block_n, block_p)
+    L = digits.shape[1]
+    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
+    mask = pl.pallas_call(
         _divmask_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_p), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_n, block_p), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, p), jnp.bool_),
+        grid=(n // block_n, p // block_p),
+        in_specs=[dspec, pspec],
+        out_specs=mspec,
+        out_shape=jax.ShapeDtypeStruct((p, n), jnp.bool_),
         interpret=interpret,
-    )(composites.reshape(n, 1), primes.reshape(1, p))
+        name="divisibility_mask",
+    )(digits.T, primes.reshape(p, 1))
+    return mask.T
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
+def factorize_squarefree_pallas(
+    digits: jnp.ndarray,       # (N, L) int32 digits, N % block_n == 0
+    primes: jnp.ndarray,       # (P,) int32 pool, P % block_p == 0
+    *,
+    block_n: int = 256,
+    block_p: int = 512,
+    interpret: bool = False,
+):
+    """Returns ``(mask (N, P) bool, residual (N, L) int32 digits)``:
+    the dividing pool primes and ``c // prod(dividing primes)`` (1 when
+    the pool fully factors a squarefree composite) — see the ref.py
+    oracle."""
+    n, p = _check(digits, primes, block_n, block_p)
+    L = digits.shape[1]
+    dspec, pspec, sspec, mspec = _specs(L, block_n, block_p)
+    mask, residual = pl.pallas_call(
+        _factorize_kernel,
+        grid=(n // block_n, p // block_p),
+        in_specs=[dspec, pspec, sspec],
+        out_specs=[mspec, dspec],
+        out_shape=[jax.ShapeDtypeStruct((p, n), jnp.bool_),
+                   jax.ShapeDtypeStruct((L, n), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_p, block_n), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="factorize_squarefree",
+    )(digits.T, primes.reshape(p, 1), primes.reshape(p // block_p, 1, block_p))
+    return mask.T, residual.T

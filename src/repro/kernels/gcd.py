@@ -1,15 +1,28 @@
-"""Pallas TPU kernel: batched elementwise GCD (Euclid, fixed trip count).
+"""Pallas TPU kernels: batched elementwise gcd.
 
-Used by the serving tier for deterministic shared-prefix discovery:
-``gcd(chain_composite_a, chain_composite_b)`` is the composite of the
-shared pages (PFCS relationship intersection — exact, zero false
-positives by unique factorization).
+Used by the serving tier for deterministic shared-prefix discovery and by
+the sharded exchange: ``gcd(chain_composite_a, chain_composite_b)`` is
+the composite of the shared pages (PFCS relationship intersection —
+exact, zero false positives by unique factorization).
 
-Vectorization note: binary GCD needs count-trailing-zeros, which does not
-vectorize cleanly on the VPU; the Euclidean form ``(a, b) -> (b, a mod b)``
-is branch-free with a ``b == 0`` guard and converges in <= 47 iterations
-for int32 (Fibonacci worst case), <= 92 for int64.  A fixed-trip
-``lax.fori_loop`` keeps the kernel shape static.
+One kernel, in int32 lanes at any width (DESIGN.md §3).  Multi-digit
+Euclid needs long division with normalization — hostile to the VPU.
+PFCS composites let us sidestep it: chunk values are SQUAREFREE products
+of pool primes, so
+
+    gcd(a, b) = prod { p in pool : p | a  and  p | b }
+
+exactly (unique factorization — Theorem 1).  The kernel computes both
+divisibility masks with the Horner ladder of ``factorize.py`` and
+rebuilds the gcd by masked schoolbook multiplication into a digit
+accumulator, least significant digit first:
+
+    t = digit * p + carry      digit < 2**6, carry < p < 2**25
+                               => t < p * 2**6 <= 2**31          OK
+
+The caller supplies a prime pool covering the common factors (any
+superset of either side's member primes works — common primes are a
+subset of both).
 """
 
 from __future__ import annotations
@@ -20,156 +33,75 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["gcd_pallas", "gcd_limbs_pallas"]
+from repro.core.composite import LIMB_BITS, LIMB_MASK
 
-_TRIPS = {jnp.dtype(jnp.int32): 48, jnp.dtype(jnp.int64): 96}
+from .factorize import _check, _divides, _specs
 
-
-def _gcd_kernel(a_ref, b_ref, o_ref, *, trips: int):
-    a = a_ref[...]
-    b = b_ref[...]
-
-    def body(_, ab):
-        a, b = ab
-        safe_b = jnp.where(b == 0, jnp.ones_like(b), b)
-        r = jnp.where(b == 0, jnp.zeros_like(a), a % safe_b)
-        new_a = jnp.where(b == 0, a, b)
-        return new_a, r
-
-    a, b = lax.fori_loop(0, trips, body, (a, b))
-    o_ref[...] = a
+__all__ = ["gcd_squarefree_pallas"]
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def gcd_pallas(
-    a: jnp.ndarray,   # (N,) int32/int64, N % block_n == 0
-    b: jnp.ndarray,   # (N,) same
-    *,
-    block_n: int = 1024,
-    interpret: bool = True,
-):
-    """Elementwise gcd(a, b) — matches ``jnp.gcd`` (incl. gcd(x, 0) = |x|;
-    PFCS composites are positive so the abs path never triggers)."""
-    n = a.shape[0]
-    assert n % block_n == 0, (n, block_n)
-    trips = _TRIPS[jnp.dtype(a.dtype)]
-    # lanes-last layout: (rows, 128)
-    lanes = 128
-    assert block_n % lanes == 0
-    rows = block_n // lanes
-    a2 = a.reshape(n // lanes, lanes)
-    b2 = b.reshape(n // lanes, lanes)
-    out = pl.pallas_call(
-        functools.partial(_gcd_kernel, trips=trips),
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec((rows, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((rows, lanes), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // lanes, lanes), a.dtype),
-        interpret=interpret,
-    )(a2, b2)
-    return out.reshape(n)
-
-
-# ----------------------------------------------------------------------- #
-# multi-limb variant (DESIGN.md §11)                                      #
-# ----------------------------------------------------------------------- #
-# Multi-limb Euclid needs long division with normalization — hostile to
-# the VPU.  PFCS composites let us sidestep it: chunk values are
-# SQUAREFREE products of pool primes, so
-#
-#     gcd(a, b) = prod { p in pool : p | a  and  p | b }
-#
-# exactly (unique factorization — Theorem 1).  The kernel computes both
-# divisibility masks with the Horner-mod ladder and rebuilds the gcd by
-# masked schoolbook scalar multiplication into a limb accumulator:
-#
-#     t = g_limb * p + carry     g_limb < 2**32, p < 2**31, carry < 2**31
-#                                => t < 2**63                         OK
-#
-# The caller supplies the prime pool covering the common factors (any
-# superset of either side's member primes works — common primes are a
-# subset of both).
-
-_LIMB_BITS = 32
-_LIMB_BASE = 1 << _LIMB_BITS
-_LIMB_MASK = _LIMB_BASE - 1
-
-
-def _horner_mod_g(limbs, p):
-    r = jnp.zeros((limbs.shape[0], p.shape[1]), dtype=jnp.int64)
-    for k in reversed(range(limbs.shape[1])):
-        r = (r * _LIMB_BASE + limbs[:, k:k + 1]) % p
-    return r
-
-
-def _gcd_limbs_kernel(a_ref, b_ref, p_ref, o_ref, *, block_p: int):
+def _gcd_squarefree_kernel(a_ref, b_ref, p_ref, ps_ref, g_ref, take_ref):
     j = pl.program_id(1)
-    a = a_ref[...]                           # (BN, L)
-    b = b_ref[...]                           # (BN, L)
-    p = p_ref[...]                           # (1, BP)
-    L = a.shape[1]
-    safe_p = jnp.where(p <= 1, jnp.ones_like(p), p)
-    common = jnp.logical_and(
-        jnp.logical_and(_horner_mod_g(a, safe_p) == 0,
-                        _horner_mod_g(b, safe_p) == 0),
-        p > 1)                               # (BN, BP)
+    L = a_ref.shape[0]
+    p = p_ref[...]
+    common = jnp.logical_and(_divides(a_ref, p), _divides(b_ref, p))
+    take_ref[...] = common.astype(jnp.int32)
 
-    # accumulator: limb value 1 on the first prime tile
+    # accumulator: the value 1 on the first prime tile
     @pl.when(j == 0)
     def _init():
-        one = jnp.zeros_like(a)
-        o_ref[...] = one.at[:, 0].set(1)
+        g_ref[...] = (lax.broadcasted_iota(jnp.int32, g_ref.shape, 0) == 0
+                      ).astype(jnp.int32)
 
-    def body(jj, g):
-        pj = lax.dynamic_index_in_dim(safe_p[0], jj, keepdims=False)
-        take = lax.dynamic_index_in_dim(common, jj, axis=1, keepdims=False)
-        carry = jnp.zeros((g.shape[0],), dtype=jnp.int64)
-        out = []
-        for k in range(L):
-            t = g[:, k] * pj + carry
-            out.append(t & _LIMB_MASK)
-            carry = t >> _LIMB_BITS
-        mul = jnp.stack(out, axis=1)
-        return jnp.where(take[:, None], mul, g)
+    def per_prime(jj, _):
+        pj = jnp.maximum(ps_ref[0, jj], 1)
+        take = take_ref[pl.ds(jj, 1), :] != 0             # (1, BN)
 
-    o_ref[...] = lax.fori_loop(0, block_p, body, o_ref[...])
+        def step(k, carry):
+            row = pl.ds(k, 1)
+            t = g_ref[row, :] * pj + carry
+            g_ref[row, :] = jnp.where(take, t & LIMB_MASK, g_ref[row, :])
+            return t >> LIMB_BITS
+
+        lax.fori_loop(0, L, step, jnp.zeros(take.shape, jnp.int32))
+        return 0
+
+    lax.fori_loop(0, ps_ref.shape[1], per_prime, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
-def gcd_limbs_pallas(
-    a: jnp.ndarray,            # (N, L) int64 32-bit limbs, N % block_n == 0
+def gcd_squarefree_pallas(
+    a: jnp.ndarray,            # (N, L) int32 digits, N % block_n == 0
     b: jnp.ndarray,            # (N, L) same
-    pool: jnp.ndarray,         # (P,)  int64 primes covering common factors
+    pool: jnp.ndarray,         # (P,) int32 primes covering common factors
     *,
     block_n: int = 256,
     block_p: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
-    """Elementwise gcd of squarefree multi-limb composite pairs.
+    """Elementwise gcd of squarefree composite pairs, as ``(N, L)`` digits.
 
-    Exact for chunk values that are products of distinct ``pool`` primes
-    (the registry invariant).  Pad rows (limb value 0 or 1) and
-    zero-padded pool primes yield gcd 1 — callers slice to the live
-    prefix, matching the flat kernel's contract.
+    Exact for values that are products of distinct ``pool`` primes (the
+    registry invariant).  Pad rows of value 1 and zero-padded pool
+    primes yield gcd 1 — callers slice to the live prefix.
     """
-    n, L = a.shape
     assert a.shape == b.shape, (a.shape, b.shape)
-    p = pool.shape[0]
-    assert n % block_n == 0 and p % block_p == 0, (n, p, block_n, block_p)
-    grid = (n // block_n, p // block_p)
-    return pl.pallas_call(
-        functools.partial(_gcd_limbs_kernel, block_p=block_p),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_p), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_n, L), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, L), jnp.int64),
+    n, p = _check(a, pool, block_n, block_p)
+    _check(b, pool, block_n, block_p)
+    L = a.shape[1]
+    dspec, pspec, sspec, _ = _specs(L, block_n, block_p)
+    g = pl.pallas_call(
+        _gcd_squarefree_kernel,
+        grid=(n // block_n, p // block_p),
+        in_specs=[dspec, dspec, pspec, sspec],
+        out_specs=dspec,
+        out_shape=jax.ShapeDtypeStruct((L, n), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((block_p, block_n), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b, pool.reshape(1, p))
+        name="gcd_squarefree",
+    )(a.T, b.T, pool.reshape(p, 1), pool.reshape(p // block_p, 1, block_p))
+    return g.T

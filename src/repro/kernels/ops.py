@@ -1,13 +1,15 @@
-"""Public jit'd wrappers around the PFCS Pallas kernels.
+"""Public host-facing wrappers around the PFCS Pallas kernels.
 
-Handles the ragged real world: pads inputs to tile multiples, picks the
-int32 fast path vs the int64 wide path per composite magnitude (DESIGN.md
-§3 — TPUs have no fast 64-bit integer multiply, and PFCS routes hot data
-to small primes precisely so the hot path stays narrow), and decides
-interpret mode from the backend (compiled on TPU, interpreted on CPU).
+Handles the ragged real world: converts exact Python ints (or an
+already-packed digit matrix) into int32 digit lanes on the host, checks
+every value's range there, pads to power-of-two tile buckets, and
+decides interpret mode from the backend (compiled on TPU, interpreted
+on CPU, refused anywhere else).  One representation serves every width (DESIGN.md §3):
+a 62-bit narrow chunk is 11 digits, a 4096-bit wide chunk 683.
 
-Numpy in, numpy out — these are host-callable building blocks used by the
-registry/prefetcher when batch sizes justify the device round trip.
+Python ints in, Python ints out — these are host-callable building
+blocks used by the registry/prefetcher when batch sizes justify the
+device round trip.
 """
 
 from __future__ import annotations
@@ -17,82 +19,98 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from repro.core.composite import (LIMB_BITS, limbs_to_int, n_limbs_for_bits,
-                                  pack_limbs, unpack_limbs)
+from repro.core.composite import (LIMB_BASE, MAX_PRIME_BITS, MAX_PRIME_LIMIT,
+                                  n_limbs_for_bits, pack_limbs, unpack_limbs)
 from repro.obs.profile import kernel_scope
 
-from .factorize import (divisibility_mask_limbs_pallas,
-                        divisibility_mask_pallas, factorize_limbs_pallas,
-                        factorize_squarefree_pallas)
-from .gcd import gcd_limbs_pallas, gcd_pallas
+from .factorize import divisibility_mask_pallas, factorize_squarefree_pallas
+from .gcd import gcd_squarefree_pallas
 
-__all__ = ["factorize_batch", "divisibility_scan", "gcd_batch",
-           "divisibility_scan_limbs", "factorize_batch_limbs",
-           "gcd_batch_limbs", "factorize_batch_exact", "gcd_batch_exact",
-           "INT32_SAFE_LIMIT", "INT64_SAFE_LIMIT"]
-
-# composites below this fit the int32 fast path
-INT32_SAFE_LIMIT = 2**31 - 1
-
-# composites below this fit the flat int64 kernels; anything larger takes
-# the multi-limb path (DESIGN.md §11)
-INT64_SAFE_LIMIT = 2**63 - 1
+__all__ = ["divisibility_scan", "factorize_batch", "gcd_batch",
+           "prime_lanes", "interpret_mode", "check_32bit"]
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Pallas interpret mode for the ``cpu`` backend only.
+
+    The kernels compile on ``tpu``; any other backend raises rather than
+    silently interpreting a kernel on a device it was not built for.
+    """
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
+
+
+def check_32bit() -> None:
+    """The kernels run in 32-bit lanes: refuse a call under x64, where a
+    stray int64 intermediate would reach Mosaic."""
+    if jax.config.jax_enable_x64:
+        raise RuntimeError("the discovery kernels run in 32-bit lanes; "
+                           "call them outside jax.enable_x64")
+
+
+def as_digits(values) -> np.ndarray:
+    """Values -> ``(N, L)`` int32 digit matrix.
+
+    A 2-D integer array is taken as an already-packed digit matrix and
+    range-checked; anything else is a sequence of non-negative ints
+    packed at the widest value's digit count.
+    """
+    if isinstance(values, np.ndarray) and values.ndim == 2 \
+            and values.dtype != object:
+        if values.size and (values.min() < 0 or values.max() >= LIMB_BASE):
+            raise ValueError("digit matrix entries must be in "
+                             f"[0, {LIMB_BASE})")
+        return values.astype(np.int32)
+    vals = [int(v) for v in values]
+    return pack_limbs(vals, max(1, n_limbs_for_bits(
+        max((v.bit_length() for v in vals), default=1))))
+
+
+def prime_lanes(primes: Sequence[int]) -> np.ndarray:
+    """Primes -> int32 lanes; every value must be in
+    ``[0, MAX_PRIME_LIMIT)`` (0 and 1 are padding and divide nothing)."""
+    vals = [int(p) for p in primes]
+    for p in vals:
+        if not 0 <= p < MAX_PRIME_LIMIT:
+            raise ValueError(f"prime {p} is outside the {MAX_PRIME_BITS}-"
+                             f"bit kernel prime bound")
+    return np.asarray(vals, dtype=np.int32)
+
+
+def _bucket(n: int, mult: int) -> int:
+    """``mult * 2**k >= n``: power-of-two buckets bound the number of
+    distinct compiled kernel shapes as registries grow."""
+    width = mult
+    while width < n:
+        width *= 2
+    return width
 
 
 def _pad_to(x: np.ndarray, mult: int, fill) -> np.ndarray:
-    n = x.shape[0]
-    pad = (-n) % mult
+    pad = _bucket(x.shape[0], mult) - x.shape[0]
     if pad == 0:
         return x
     return np.concatenate([x, np.full(pad, fill, dtype=x.dtype)])
 
 
-def _pick_dtype(*arrays: np.ndarray):
-    hi = max((int(a.max()) if a.size else 0) for a in arrays)
-    return np.int32 if hi <= INT32_SAFE_LIMIT else np.int64
-
-
-def factorize_batch(
-    composites: Sequence[int],
-    primes: Sequence[int],
-    block_n: int = 256,
-    block_p: int = 512,
-    interpret: bool | None = None,
-) -> Tuple[List[List[int]], np.ndarray]:
-    """Factor each composite against the pool.
-
-    Returns ``(factors, residuals)`` — per composite the dividing pool
-    primes and the remaining cofactor (1 when fully factored).
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    comp = np.asarray(list(composites))
-    pool = np.asarray(list(primes))
-    if comp.size == 0:
-        return [], np.empty(0, dtype=np.int64)
-    dt = _pick_dtype(comp, pool)
-    n, p = comp.shape[0], pool.shape[0]
-    comp_p = _pad_to(comp.astype(dt), block_n, 1)
-    pool_p = _pad_to(pool.astype(dt), block_p, 0)
-    with enable_x64(True) if dt == np.int64 else _nullcontext():
-        with kernel_scope("factorize_batch", items=n):
-            mask, residual = factorize_squarefree_pallas(
-                jnp.asarray(comp_p), jnp.asarray(pool_p),
-                block_n=block_n, block_p=block_p, interpret=interpret)
-            mask = np.asarray(mask)[:n, :p]
-            residual = np.asarray(residual)[:n]
-    factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]] for i in range(n)]
-    return factors, residual.astype(np.int64)
+def _pad_rows_one(digits: np.ndarray, mult: int) -> np.ndarray:
+    """Pad to a bucketed row count with rows encoding the value 1
+    (divides nothing, is divided by nothing)."""
+    pad = _bucket(digits.shape[0], mult) - digits.shape[0]
+    if pad == 0:
+        return digits
+    one = np.zeros((pad, digits.shape[1]), dtype=digits.dtype)
+    one[:, 0] = 1
+    return np.concatenate([digits, one])
 
 
 def divisibility_scan(
-    registry: Sequence[int],
+    registry,                       # ints, or an (N, L) digit matrix
     query_primes: Sequence[int],
     block_n: int = 256,
     block_p: int = 512,
@@ -104,217 +122,89 @@ def divisibility_scan(
     candidate index lists.
     """
     if interpret is None:
-        interpret = _interpret_default()
-    reg = np.asarray(list(registry))
-    qs = np.asarray(list(query_primes))
-    if reg.size == 0 or qs.size == 0:
-        return [np.empty(0, dtype=np.int64) for _ in range(qs.size)]
-    dt = _pick_dtype(reg, qs)
-    n, q = reg.shape[0], qs.shape[0]
-    reg_p = _pad_to(reg.astype(dt), block_n, 1)
-    qs_p = _pad_to(qs.astype(dt), block_p, 0)
-    with enable_x64(True) if dt == np.int64 else _nullcontext():
-        with kernel_scope("divisibility_scan", items=n):
-            mask = divisibility_mask_pallas(
-                jnp.asarray(reg_p), jnp.asarray(qs_p),
-                block_n=block_n, block_p=block_p, interpret=interpret)
-            mask = np.asarray(mask)[:n, :q]
-    return [np.nonzero(mask[:, j])[0] for j in range(q)]
-
-
-def gcd_batch(
-    a: Sequence[int],
-    b: Sequence[int],
-    block_n: int = 1024,
-    interpret: bool | None = None,
-) -> np.ndarray:
-    """Elementwise gcd over pairs (shared-prefix composite discovery)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    aa = np.asarray(list(a))
-    bb = np.asarray(list(b))
-    assert aa.shape == bb.shape
-    if aa.size == 0:
-        return np.empty(0, dtype=np.int64)
-    dt = _pick_dtype(aa, bb)
-    n = aa.shape[0]
-    ap = _pad_to(aa.astype(dt), block_n, 0)
-    bp = _pad_to(bb.astype(dt), block_n, 0)
-    with enable_x64(True) if dt == np.int64 else _nullcontext():
-        with kernel_scope("gcd_batch", items=n):
-            g = gcd_pallas(jnp.asarray(ap), jnp.asarray(bp),
-                           block_n=block_n, interpret=interpret)
-            g = np.asarray(g)[:n]
-    return g.astype(np.int64)
-
-
-class _nullcontext:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-# --------------------------------------------------------------------------- #
-# multi-limb wrappers + exact dispatchers (DESIGN.md §11)                      #
-# --------------------------------------------------------------------------- #
-# Python ints in, Python ints out: the wrappers pack arbitrary-precision
-# composites into (N, L) 32-bit-limb int64 matrices for the limb kernels
-# and unpack results exactly.  The ``*_exact`` dispatchers pick the flat
-# int64 kernels when every value fits a machine word (bit-identical to
-# the narrow path) and the limb kernels otherwise, so consumers stay
-# mode-agnostic.
-
-def _as_limbs(values, n_limbs: int | None) -> np.ndarray:
-    """Values -> (N, L) limb matrix; passes (N, L) arrays through."""
-    if isinstance(values, np.ndarray) and values.ndim == 2 \
-            and values.dtype != object:
-        assert n_limbs is None or values.shape[1] == n_limbs
-        return values.astype(np.int64)
-    vals = [int(v) for v in values]
-    if n_limbs is None:
-        n_limbs = max(1, n_limbs_for_bits(max(
-            (v.bit_length() for v in vals), default=1)))
-    return pack_limbs(vals, n_limbs)
-
-
-def divisibility_scan_limbs(
-    registry_limbs: np.ndarray,     # (N, L) limbs OR sequence of ints
-    query_primes: Sequence[int],
-    block_n: int = 256,
-    block_p: int = 512,
-    interpret: bool | None = None,
-    n_limbs: int | None = None,
-) -> List[np.ndarray]:
-    """Wide §4.2 scan: per query prime, indices of dividing composites."""
-    if interpret is None:
-        interpret = _interpret_default()
-    limbs = _as_limbs(registry_limbs, n_limbs)
-    qs = np.asarray(list(query_primes), dtype=np.int64)
-    n, q = limbs.shape[0], qs.shape[0]
+        interpret = interpret_mode()
+    check_32bit()
+    qs = prime_lanes(query_primes)
+    digits = as_digits(registry)
+    n, q = digits.shape[0], qs.shape[0]
     if n == 0 or q == 0:
         return [np.empty(0, dtype=np.int64) for _ in range(q)]
-    limbs_p = np.concatenate(
-        [limbs, _pad_rows_one(limbs.shape[1], (-n) % block_n)]) \
-        if n % block_n else limbs
-    qs_p = _pad_to(qs, block_p, 0)
-    with enable_x64(True):
-        with kernel_scope("divisibility_scan_limbs", items=n):
-            mask = divisibility_mask_limbs_pallas(
-                jnp.asarray(limbs_p), jnp.asarray(qs_p),
-                block_n=block_n, block_p=block_p, interpret=interpret)
-            mask = np.asarray(mask)[:n, :q]
+    with kernel_scope("divisibility_scan", items=n):
+        mask = divisibility_mask_pallas(
+            jnp.asarray(_pad_rows_one(digits, block_n)),
+            jnp.asarray(_pad_to(qs, block_p, 0)),
+            block_n=block_n, block_p=block_p, interpret=interpret)
+        mask = np.asarray(mask)[:n, :q]
     return [np.nonzero(mask[:, j])[0] for j in range(q)]
 
 
-def _pad_rows_one(L: int, rows: int) -> np.ndarray:
-    """Pad rows encoding composite value 1 (divides nothing)."""
-    out = np.zeros((rows, L), dtype=np.int64)
-    if rows:
-        out[:, 0] = 1
-    return out
-
-
-def factorize_batch_limbs(
-    composites,                     # sequence of ints OR (N, L) limbs
+def factorize_batch(
+    composites,                     # ints, or an (N, L) digit matrix
     primes: Sequence[int],
     block_n: int = 256,
     block_p: int = 512,
     interpret: bool | None = None,
-    n_limbs: int | None = None,
 ) -> Tuple[List[List[int]], List[int]]:
-    """Wide :func:`factorize_batch`: residuals come back as exact Python
-    ints (1 when the pool fully factors the composite)."""
+    """Factor each composite against the pool.
+
+    Returns ``(factors, residuals)`` — per composite the dividing pool
+    primes and the remaining cofactor as an exact Python int (1 when the
+    pool fully factors a squarefree composite).
+    """
     if interpret is None:
-        interpret = _interpret_default()
-    limbs = _as_limbs(composites, n_limbs)
-    pool = np.asarray(list(primes), dtype=np.int64)
-    n, p = limbs.shape[0], pool.shape[0]
+        interpret = interpret_mode()
+    check_32bit()
+    pool = prime_lanes(primes)
+    digits = as_digits(composites)
+    n, p = digits.shape[0], pool.shape[0]
     if n == 0:
         return [], []
-    limbs_p = np.concatenate(
-        [limbs, _pad_rows_one(limbs.shape[1], (-n) % block_n)]) \
-        if n % block_n else limbs
-    pool_p = _pad_to(pool, block_p, 0)
-    with enable_x64(True):
-        with kernel_scope("factorize_batch_limbs", items=n):
-            mask, residual = factorize_limbs_pallas(
-                jnp.asarray(limbs_p), jnp.asarray(pool_p),
-                block_n=block_n, block_p=block_p, interpret=interpret)
-            mask = np.asarray(mask)[:n, :p]
-            residual = np.asarray(residual)[:n]
+    with kernel_scope("factorize_batch", items=n):
+        mask, residual = factorize_squarefree_pallas(
+            jnp.asarray(_pad_rows_one(digits, block_n)),
+            jnp.asarray(_pad_to(pool, block_p, 0)),
+            block_n=block_n, block_p=block_p, interpret=interpret)
+        mask = np.asarray(mask)[:n, :p]
+        residual = np.asarray(residual)[:n]
     factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]]
                for i in range(n)]
     return factors, unpack_limbs(residual)
 
 
-def gcd_batch_limbs(
-    a, b,                           # sequences of ints OR (N, L) limbs
+def gcd_batch(
+    a,                              # ints, or an (N, L) digit matrix
+    b,
     pool: Sequence[int],
     block_n: int = 256,
     block_p: int = 512,
     interpret: bool | None = None,
-    n_limbs: int | None = None,
 ) -> List[int]:
-    """Wide elementwise gcd of squarefree composite pairs, exact Python
-    ints out.  ``pool`` must cover the common member primes (either
-    side's prime set suffices — see ``gcd_limbs_pallas``)."""
+    """Elementwise gcd over pairs (shared-prefix composite discovery),
+    exact Python ints out.
+
+    The values may have any width but must be squarefree products of
+    primes, and ``pool`` must cover the common member primes (either
+    side's prime set suffices — see
+    :func:`~repro.kernels.gcd.gcd_squarefree_pallas`).  The narrower side
+    is widened with zero high digits.
+    """
     if interpret is None:
-        interpret = _interpret_default()
-    if n_limbs is None and not (isinstance(a, np.ndarray) and a.ndim == 2):
-        hi = max((int(v).bit_length() for v in [*a, *b]), default=1)
-        n_limbs = max(1, n_limbs_for_bits(hi))
-    aa = _as_limbs(a, n_limbs)
-    bb = _as_limbs(b, n_limbs if n_limbs is not None else aa.shape[1])
-    assert aa.shape == bb.shape, (aa.shape, bb.shape)
-    pl_ = np.asarray(list(pool), dtype=np.int64)
+        interpret = interpret_mode()
+    check_32bit()
+    aa, bb = as_digits(a), as_digits(b)
+    if aa.shape[0] != bb.shape[0]:
+        raise ValueError(f"length mismatch {aa.shape[0]} vs {bb.shape[0]}")
+    width = max(aa.shape[1], bb.shape[1])
+    aa, bb = (np.pad(x, ((0, 0), (0, width - x.shape[1]))) for x in (aa, bb))
+    primes = prime_lanes(pool)
     n = aa.shape[0]
     if n == 0:
         return []
-    pad = (-n) % block_n
-    if pad:
-        aa = np.concatenate([aa, _pad_rows_one(aa.shape[1], pad)])
-        bb = np.concatenate([bb, _pad_rows_one(bb.shape[1], pad)])
-    pool_p = _pad_to(pl_, block_p, 0)
-    with enable_x64(True):
-        with kernel_scope("gcd_batch_limbs", items=n):
-            g = gcd_limbs_pallas(jnp.asarray(aa), jnp.asarray(bb),
-                                 jnp.asarray(pool_p), block_n=block_n,
-                                 block_p=block_p, interpret=interpret)
-            g = np.asarray(g)[:n]
+    with kernel_scope("gcd_batch", items=n):
+        g = gcd_squarefree_pallas(
+            jnp.asarray(_pad_rows_one(aa, block_n)),
+            jnp.asarray(_pad_rows_one(bb, block_n)),
+            jnp.asarray(_pad_to(primes, block_p, 0)),
+            block_n=block_n, block_p=block_p, interpret=interpret)
+        g = np.asarray(g)[:n]
     return unpack_limbs(g)
-
-
-def factorize_batch_exact(
-    composites: Sequence[int],
-    primes: Sequence[int],
-    **kw,
-) -> Tuple[List[List[int]], List[int]]:
-    """Width-agnostic factorize: flat int64 kernels when every composite
-    fits, limb kernels otherwise.  Residuals are Python ints either way."""
-    vals = [int(c) for c in composites]
-    if not vals:
-        return [], []
-    if max(vals) <= INT64_SAFE_LIMIT:
-        facs, residual = factorize_batch(vals, primes, **kw)
-        return facs, [int(r) for r in residual]
-    return factorize_batch_limbs(vals, primes, **kw)
-
-
-def gcd_batch_exact(
-    a: Sequence[int],
-    b: Sequence[int],
-    pool: Sequence[int],
-    **kw,
-) -> List[int]:
-    """Width-agnostic elementwise gcd (see :func:`gcd_batch_limbs` for
-    the squarefree/pool contract of the wide path)."""
-    va = [int(x) for x in a]
-    vb = [int(x) for x in b]
-    if not va:
-        return []
-    if max(max(va), max(vb)) <= INT64_SAFE_LIMIT:
-        return [int(g) for g in gcd_batch(va, vb, **{
-            k: v for k, v in kw.items() if k in ("block_n", "interpret")})]
-    return gcd_batch_limbs(va, vb, pool, **kw)

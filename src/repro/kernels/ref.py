@@ -1,107 +1,64 @@
-"""Pure-jnp oracles for the PFCS Pallas kernels.
+"""Exact oracles for the PFCS Pallas kernels.
 
 These are the semantic ground truth the kernels are validated against
-(tests sweep shapes/dtypes and assert exact equality — integer kernels,
-no tolerance needed).
+(tests sweep shapes and widths and assert exact equality — integer
+kernels, no tolerance needed).  The digit-matrix oracles are
+arbitrary-precision Python-int arithmetic: unpack digits -> exact int
+ops -> repack.  Deliberately NOT the kernels' digit ladder — there is
+nothing to get subtly wrong here, which is the point of an oracle.
 """
 
 from __future__ import annotations
 
 import math
 
-import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["divisibility_mask_ref", "factorize_squarefree_ref", "gcd_ref",
-           "divisibility_mask_limbs_ref", "factorize_limbs_ref",
-           "gcd_limbs_ref"]
+from repro.core.composite import pack_limbs, unpack_limbs
+
+__all__ = ["divisibility_mask_ref", "factorize_squarefree_ref",
+           "gcd_squarefree_ref"]
 
 
-def divisibility_mask_ref(composites: jnp.ndarray, primes: jnp.ndarray) -> jnp.ndarray:
-    """mask[i, j] = primes[j] divides composites[i].
+def divisibility_mask_ref(digits: np.ndarray, primes) -> np.ndarray:
+    """mask[i, j] = primes[j] divides the composite encoded by digits[i].
 
-    composites: (N,) int32/int64, primes: (P,) same dtype -> (N, P) bool.
-    Zero-padded primes never divide (pad-safe); composite 0/1 rows are all
-    False for primes > 1.
+    digits: (N, L) digit matrix -> (N, P) bool; primes <= 1 (padding)
+    never divide.
     """
-    c = composites[:, None]
-    p = primes[None, :]
-    safe_p = jnp.where(p <= 0, 1, p)
-    mask = (c % safe_p) == 0
-    return jnp.logical_and(mask, p > 1)
-
-
-def factorize_squarefree_ref(composites: jnp.ndarray, primes: jnp.ndarray):
-    """Squarefree factorization against a prime pool.
-
-    PFCS composites are products of *distinct* primes (one per data
-    element), so the divisibility mask IS the factorization.  Returns
-    ``(mask, residual)`` where ``residual[i] = composites[i] / prod of
-    dividing pool primes`` — 1 when the pool fully factors the composite,
-    else the cofactor for the next (colder) pool / Pollard stage.
-    """
-    mask = divisibility_mask_ref(composites, primes)
-    p = primes[None, :].astype(composites.dtype)
-    factors = jnp.where(mask, p, jnp.ones_like(p))
-    prod = jnp.prod(factors, axis=1)
-    residual = jnp.where(prod > 0, composites // jnp.maximum(prod, 1), composites)
-    return mask, residual
-
-
-def gcd_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Elementwise gcd (Euclid), same shape/dtype in and out."""
-    return jnp.gcd(a, b)
-
-
-# ----------------------------------------------------------------------- #
-# multi-limb oracles (DESIGN.md §11)                                      #
-# ----------------------------------------------------------------------- #
-# Ground truth for the limb kernels is arbitrary-precision Python-int
-# arithmetic: unpack limbs -> exact int ops -> repack.  Deliberately NOT
-# jnp — there is nothing to get subtly wrong here, which is the point of
-# an oracle.
-
-def _unpack(limbs: np.ndarray):
-    from repro.core.composite import unpack_limbs
-    return unpack_limbs(np.asarray(limbs))
-
-
-def divisibility_mask_limbs_ref(limbs: np.ndarray, primes) -> np.ndarray:
-    """mask[i, j] = primes[j] divides the composite encoded by limbs[i].
-
-    limbs: (N, L) int64 little-endian 32-bit limbs -> (N, P) bool; pad
-    primes <= 1 never divide (same contract as the flat kernel).
-    """
-    vals = _unpack(limbs)
+    vals = unpack_limbs(digits)
     ps = [int(p) for p in np.asarray(primes)]
     return np.array([[p > 1 and v % p == 0 for p in ps] for v in vals],
                     dtype=bool).reshape(len(vals), len(ps))
 
 
-def factorize_limbs_ref(limbs: np.ndarray, primes):
-    """Wide squarefree factorization oracle: ``(mask, residual_limbs)``
-    with the residual repacked at the input limb width."""
-    from repro.core.composite import pack_limbs
-    vals = _unpack(limbs)
+def factorize_squarefree_ref(digits: np.ndarray, primes):
+    """Squarefree factorization against a prime pool.
+
+    PFCS composites are products of *distinct* primes (one per data
+    element), so the divisibility mask IS the factorization.  Returns
+    ``(mask, residual_digits)`` where the residual is ``c // prod of
+    dividing pool primes`` — 1 when the pool fully factors the
+    composite, else the cofactor for the next (colder) pool / Pollard
+    stage — repacked at the input digit width.
+    """
+    vals = unpack_limbs(digits)
+    mask = divisibility_mask_ref(digits, primes)
     ps = [int(p) for p in np.asarray(primes)]
-    mask = divisibility_mask_limbs_ref(limbs, primes)
     residuals = []
     for i, v in enumerate(vals):
-        for j, p in enumerate(ps):
-            if mask[i, j]:
-                v //= p
-        residuals.append(v)
-    return mask, pack_limbs(residuals, np.asarray(limbs).shape[1])
+        prod = math.prod(p for j, p in enumerate(ps) if mask[i, j])
+        residuals.append(v // prod)
+    return mask, pack_limbs(residuals, np.asarray(digits).shape[1])
 
 
-def gcd_limbs_ref(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Elementwise exact gcd of limb-encoded pairs, repacked limbs.
+def gcd_squarefree_ref(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Elementwise exact gcd of digit-encoded pairs, repacked digits.
 
     This is FULL math.gcd — it equals the kernel's pool-reconstruction
     gcd exactly when both sides are squarefree products of pool primes
     (the registry invariant the differential fuzz pins).
     """
-    from repro.core.composite import pack_limbs
-    va, vb = _unpack(a), _unpack(b)
+    va, vb = unpack_limbs(a), unpack_limbs(b)
     return pack_limbs([math.gcd(x, y) for x, y in zip(va, vb)],
                       np.asarray(a).shape[1])

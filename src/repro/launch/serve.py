@@ -14,6 +14,11 @@ array-state tables by default, ``scalar`` for the oracle, ``sharded`` /
 in ``serving/engine.py``; ``--max-bits > 63`` runs the chain registry
 in multi-limb wide mode (DESIGN.md §11).
 
+A real model is built at its published widths (``get_config``) unless
+``--smoke`` asks for the reduced smoke config; parameters are random,
+generated under ``jax.jit`` from a fixed seed (no checkpoint ships with
+the repository).
+
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
         --requests 16 --max-new 24
@@ -30,10 +35,28 @@ import time
 import numpy as np
 
 
+def build_model(arch: str, smoke: bool, seed: int = 0):
+    """``(model, params, vocab)`` for ``arch`` — the smoke config or the
+    published one — with random parameters made under ``jax.jit`` from
+    ``seed`` (eager init would stage full-size float32 intermediates
+    per stacked layer on the device)."""
+    import jax
+
+    from repro.configs import get_config, get_smoke
+    from repro.models import build_model as _build
+
+    cfg = get_smoke(arch) if smoke else get_config(arch)
+    model = _build(cfg)
+    params = jax.jit(model.init_params)(jax.random.PRNGKey(seed))
+    return model, params, cfg.vocab_size
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced smoke config instead of the published "
+                         "widths of --arch")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -78,15 +101,7 @@ def main(argv=None):
     if args.null_model:
         model, params, vocab = None, None, 32_000
     else:
-        import jax
-
-        from repro.configs import get_smoke
-        from repro.models import build_model
-
-        cfg = get_smoke(args.arch)
-        model = build_model(cfg)
-        params = model.init_params(jax.random.PRNGKey(0))
-        vocab = cfg.vocab_size
+        model, params, vocab = build_model(args.arch, args.smoke)
 
     # the slot machine decodes stub tokens only — a real model needs the
     # ServingEngine's device decode step
@@ -178,4 +193,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

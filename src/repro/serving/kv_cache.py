@@ -311,7 +311,7 @@ class PagedKVCache:
         anything the budgeted :meth:`Factorizer.factorize` path fully
         factors, whereas dividing out a known pool is exact and
         width-agnostic (the same pool-reconstruction the vectorized
-        ``gcd_batch_exact`` path uses)."""
+        ``gcd_batch`` path uses)."""
         import math
         ca = self._chain_composite(req_a)
         cb = self._chain_composite(req_b)

@@ -58,7 +58,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from repro.core.composite import encode_relationship
+from repro.core.composite import encode_relationship, pack_limbs
 from repro.core.engine.tables import successor_table
 from repro.obs.trace import EV_PREFETCH
 
@@ -178,9 +178,8 @@ class VectorizedPagedKVCache(PagedKVCache):
                   if (p := self.assigner.prime_of(pid)) is not None]
         enc = encode_relationship(primes, self.registry.max_bits) \
             if primes else []
-        # wide (multi-limb) chunks exceed int64 — keep exact Python ints
-        # in an object array; the flat/limb kernel split happens at the
-        # gcd call (DESIGN.md §11)
+        # wide chunks exceed int64 — keep exact Python ints in an object
+        # array; the gcd call packs either kind into digits (DESIGN.md §3)
         dt = object if self.registry.wide else np.int64
         chunks = np.asarray(enc, dtype=dt)
         self._chain_chunks[req_id] = (chunks, self._assigner_epoch())
@@ -333,15 +332,15 @@ class VectorizedPagedKVCache(PagedKVCache):
 
     def _shared_primes(self, gcds: np.ndarray,
                        pool: np.ndarray) -> Set[int]:
-        """Decode pairwise chunk gcds into the shared prime set
-        (width-agnostic: exact dispatch picks flat vs limb kernels)."""
-        from repro.kernels.ops import factorize_batch_exact
+        """Decode pairwise chunk gcds into the shared prime set (the
+        digit kernels take every width)."""
+        from repro.kernels.ops import factorize_batch
 
         gs = sorted({int(g) for g in gcds if int(g) > 1})
         if not gs:
             return set()
-        facs, residual = factorize_batch_exact(gs, pool)
-        assert all(int(r) == 1 for r in residual), \
+        facs, residual = factorize_batch(gs, pool)
+        assert all(r == 1 for r in residual), \
             "chunk gcd escaped the chain pool"
         return {q for fs in facs for q in fs}
 
@@ -355,11 +354,11 @@ class VectorizedPagedKVCache(PagedKVCache):
     def shared_prefix_bulk(self, pairs: Sequence[Tuple[int, int]]
                            ) -> Dict[Tuple[int, int], List[int]]:
         """Shared pages for many request pairs through ONE batched gcd
-        call (all chunk cross-products concatenated).  Wide registries
-        route through the multi-limb gcd kernel with the union of the
-        side-a chain primes as the reconstruction pool (common primes of
-        any pair are a subset of that side's chain — DESIGN.md §11)."""
-        from repro.kernels.ops import gcd_batch, gcd_batch_limbs
+        call (all chunk cross-products concatenated), at any registry
+        width: the squarefree gcd kernel rebuilds each gcd from the union
+        of the side-a chain primes (common primes of any pair are a
+        subset of that side's chain — DESIGN.md §11)."""
+        from repro.kernels.ops import gcd_batch
 
         dt = self._chunk_dtype()
         blocks: List[Tuple[Tuple[int, int], np.ndarray, np.ndarray]] = []
@@ -375,13 +374,13 @@ class VectorizedPagedKVCache(PagedKVCache):
         flat_b = np.concatenate([b for _, _, b in blocks]) if blocks \
             else np.empty(0, dtype=dt)
         if not flat_a.size:
-            gcds = np.empty(0, dtype=dt)
-        elif self.registry.wide:
-            union_pool = sorted({q for pl in pools for q in pl})
-            gcds = np.asarray(
-                gcd_batch_limbs(flat_a, flat_b, union_pool), dtype=object)
+            gcds = np.empty(0, dtype=object)
         else:
-            gcds = gcd_batch(flat_a, flat_b)
+            union_pool = sorted({q for pl in pools for q in pl})
+            width = self.registry.n_limbs      # one compiled shape per registry
+            gcds = np.asarray(gcd_batch(pack_limbs(flat_a, width),
+                                        pack_limbs(flat_b, width), union_pool),
+                              dtype=object)
         out: Dict[Tuple[int, int], List[int]] = {}
         lo = 0
         for ((ra, rb), aa, _), pool in zip(blocks, pools):

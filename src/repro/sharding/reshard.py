@@ -220,7 +220,7 @@ class ShardSlices:
         LOST positions are (mode ``"partial"``).  Returns
         ``(n_refactorized, mode)``.
         """
-        from repro.kernels.ops import factorize_batch_exact
+        from repro.kernels.ops import factorize_batch
 
         arr = registry.composites_view()
         stale = (self.version != registry.version
@@ -236,8 +236,9 @@ class ShardSlices:
         lost = np.nonzero(self._owner == LOST)[0]
         if lost.size:
             pool = registry.primes_array()
-            facs, residual = factorize_batch_exact(arr[lost], pool)
-            assert all(int(r) == 1 for r in residual), \
+            facs, residual = factorize_batch(
+                registry.limbs_array()[lost], pool)
+            assert all(r == 1 for r in residual), \
                 "surviving composite escaped the prime pool (Theorem 1)"
             for pos, fs in zip(lost, facs):
                 self._primes[int(pos)] = tuple(sorted(int(q) for q in fs))

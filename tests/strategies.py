@@ -702,7 +702,7 @@ class LimbUniverseSpec:
     n_composites: int = 24
     max_factors: int = 20          # factors per composite (chain depth)
     max_bits: int = 256            # registry chunk width under test
-    big_primes: bool = True        # draw near the 31-bit kernel limb cap
+    big_primes: bool = True        # draw near the 25-bit kernel prime cap
 
 
 def build_limb_universe(spec: LimbUniverseSpec):
@@ -717,9 +717,10 @@ def build_limb_universe(spec: LimbUniverseSpec):
     small = [int(p) for p in sieve_primes(10_000)[5:]]
     pool = set(small[: spec.n_pool])
     if spec.big_primes:
-        # the top of the kernel limb word (primes < 2**31, DESIGN.md §11)
-        lo = (1 << 31) - 20_000
-        pool |= {int(p) for p in segmented_sieve(lo, 1 << 31)}
+        # the top of the kernel prime range (primes < 2**25, DESIGN.md §3)
+        from repro.core.primes import MAX_PRIME_LIMIT
+        lo = MAX_PRIME_LIMIT - 20_000
+        pool |= {int(p) for p in segmented_sieve(lo, MAX_PRIME_LIMIT)}
         pool |= {1_000_003, 999_983, 104_729, 15_485_863}
     pool = sorted(pool)
     comps = []
@@ -739,8 +740,9 @@ def build_limb_universe(spec: LimbUniverseSpec):
 
 def limb_universe_specs():
     """Strategy over limb-universe specs, biased toward the edges the
-    limb kernels care about: single-limb vs many-limb widths, chains
-    deep enough to span limbs, primes adjacent to the 31-bit cap."""
+    digit kernels care about: one-word vs many-digit widths, chains
+    deep enough to span many digits, primes adjacent to the 25-bit
+    cap."""
     return st.builds(
         LimbUniverseSpec,
         seed=st.integers(min_value=0, max_value=2**16),

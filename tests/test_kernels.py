@@ -1,28 +1,55 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, exact equality
-(integer kernels — no tolerance)."""
+"""Pallas kernels vs exact oracles: shape/width sweeps, exact equality
+(integer kernels — no tolerance).
 
+Every kernel takes int32 lanes (DESIGN.md §3).  The ``np.int32`` cases
+use composites below 2**31; the ``np.int64`` cases use composites above
+2**31 (products of primes just under the 25-bit kernel prime bound),
+which the kernels see as the same 6-bit digit rows as any other width.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import jax
-from jax.experimental import enable_x64 as _enable_x64
-import jax.numpy as jnp
-
+from repro.core.composite import n_limbs_for_bits, pack_limbs, unpack_limbs
+from repro.core.primes import (MAX_PRIME_LIMIT, PrimePool, segmented_sieve)
 from repro.kernels.factorize import (divisibility_mask_pallas,
                                      factorize_squarefree_pallas)
-from repro.kernels.gcd import gcd_pallas
+from repro.kernels.gcd import gcd_squarefree_pallas
 from repro.kernels.ops import divisibility_scan, factorize_batch, gcd_batch
 from repro.kernels.ref import (divisibility_mask_ref,
-                               factorize_squarefree_ref, gcd_ref)
+                               factorize_squarefree_ref, gcd_squarefree_ref)
 
 PRIMES_SMALL = np.array(
     [2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37, 41, 43, 47, 53, 59, 61,
      67, 71, 73, 79, 83, 89, 97, 101, 103, 107, 109, 113], dtype=np.int64)
 
+#: the 30 widest primes the kernels take: pairwise products are ~2**50
+PRIMES_WIDE = segmented_sieve(MAX_PRIME_LIMIT - 2_000, MAX_PRIME_LIMIT)[-30:]
+
+DIGITS = n_limbs_for_bits(62)           # a narrow registry row
+
 
 def _pad(x, mult, fill):
     pad = (-len(x)) % mult
     return np.concatenate([x, np.full(pad, fill, x.dtype)])
+
+
+def _primes(dtype):
+    return PRIMES_SMALL if dtype == np.int32 else PRIMES_WIDE
+
+
+def _pairs(rng, dtype, n, replace=True):
+    """``n`` products of two primes; ``replace=False`` keeps them
+    squarefree (distinct primes), which the pool gcd needs."""
+    ps = _primes(dtype)
+    comps = [int(a) * int(b) for a, b in
+             (rng.choice(ps, size=2, replace=replace) for _ in range(n))]
+    assert (max(comps) < 2**31) == (dtype == np.int32)
+    return comps
 
 
 @pytest.mark.parametrize("n,p,bn,bp", [
@@ -34,55 +61,56 @@ def _pad(x, mult, fill):
 @pytest.mark.parametrize("dtype", [np.int32, np.int64])
 def test_factorize_kernel_matches_ref(n, p, bn, bp, dtype):
     rng = np.random.default_rng(n + p)
-    pool = _pad(PRIMES_SMALL.astype(dtype), bp, 0)[:p]
-    pairs = rng.choice(PRIMES_SMALL, size=(n, 2), replace=True)
-    comps = (pairs[:, 0] * pairs[:, 1]).astype(dtype)
-    ctx = _enable_x64(True) if dtype == np.int64 else _null()
-    with ctx:
-        cj, pj = jnp.asarray(comps), jnp.asarray(pool)
-        mask, res = factorize_squarefree_pallas(cj, pj, block_n=bn, block_p=bp)
-        mref, rref = factorize_squarefree_ref(cj, pj)
-        assert (np.asarray(mask) == np.asarray(mref)).all()
-        assert (np.asarray(res) == np.asarray(rref)).all()
+    pool = _pad(_primes(dtype).astype(np.int32), bp, 0)[:p]
+    digits = pack_limbs(_pairs(rng, dtype, n), DIGITS)
+    mask, res = factorize_squarefree_pallas(
+        jnp.asarray(digits), jnp.asarray(pool), block_n=bn, block_p=bp,
+        interpret=True)
+    mref, rref = factorize_squarefree_ref(digits, pool)
+    assert (np.asarray(mask) == mref).all()
+    assert (np.asarray(res) == rref).all()
 
 
 @pytest.mark.parametrize("dtype", [np.int32, np.int64])
 def test_divmask_kernel_matches_ref(dtype):
     rng = np.random.default_rng(0)
-    comps = _pad((rng.choice(PRIMES_SMALL, size=(300, 2)).prod(axis=1)
-                  ).astype(dtype), 256, 1)
-    qs = _pad(PRIMES_SMALL.astype(dtype), 512, 0)
-    ctx = _enable_x64(True) if dtype == np.int64 else _null()
-    with ctx:
-        cj, qj = jnp.asarray(comps), jnp.asarray(qs)
-        mask = divisibility_mask_pallas(cj, qj)
-        mref = divisibility_mask_ref(cj, qj)
-        assert (np.asarray(mask) == np.asarray(mref)).all()
+    comps = _pairs(rng, dtype, 300) + [1] * (512 - 300)
+    digits = pack_limbs(comps, DIGITS)
+    qs = _pad(_primes(dtype).astype(np.int32), 512, 0)
+    mask = divisibility_mask_pallas(jnp.asarray(digits), jnp.asarray(qs),
+                                    interpret=True)
+    assert (np.asarray(mask) == divisibility_mask_ref(digits, qs)).all()
 
 
 @pytest.mark.parametrize("n", [1024, 2048, 4096])
 @pytest.mark.parametrize("dtype", [np.int32, np.int64])
 def test_gcd_kernel_matches_ref(n, dtype):
+    """Squarefree products below (int32) and above (int64) 2**31, gcd
+    rebuilt from the prime pool."""
     rng = np.random.default_rng(n)
-    hi = 2**28 if dtype == np.int32 else 2**40
-    a = rng.integers(1, hi, size=n).astype(dtype)
-    b = rng.integers(1, hi, size=n).astype(dtype)
-    ctx = _enable_x64(True) if dtype == np.int64 else _null()
-    with ctx:
-        g = gcd_pallas(jnp.asarray(a), jnp.asarray(b))
-        assert (np.asarray(g) == np.gcd(a, b)).all()
+    a = pack_limbs(_pairs(rng, dtype, n, replace=False), DIGITS)
+    b = pack_limbs(_pairs(rng, dtype, n, replace=False), DIGITS)
+    pool = _pad(_primes(dtype).astype(np.int32), 512, 0)
+    g = gcd_squarefree_pallas(jnp.asarray(a), jnp.asarray(b),
+                              jnp.asarray(pool), interpret=True)
+    assert (np.asarray(g) == gcd_squarefree_ref(a, b)).all()
 
 
 def test_gcd_zero_edge():
-    a = np.array([0, 5, 0, 12] + [1] * 124, dtype=np.int32)
-    b = np.array([7, 0, 0, 18] + [1] * 124, dtype=np.int32)
-    a, b = _pad(a, 1024, 1), _pad(b, 1024, 1)
-    g = gcd_pallas(jnp.asarray(a), jnp.asarray(b))
-    assert (np.asarray(g) == np.gcd(a, b)).all()
+    """gcd(0, x) = x (every pool prime divides 0), pad rows of 1 give 1,
+    and zero-padded pool primes divide nothing."""
+    va = [0, 6, 0, 1, 35] + [1] * 251
+    vb = [6, 0, 1, 35, 1] + [1] * 251
+    pool = _pad(PRIMES_SMALL.astype(np.int32), 512, 0)
+    g = gcd_squarefree_pallas(jnp.asarray(pack_limbs(va, DIGITS)),
+                              jnp.asarray(pack_limbs(vb, DIGITS)),
+                              jnp.asarray(pool), interpret=True)
+    assert unpack_limbs(np.asarray(g)) == \
+        [math.gcd(x, y) for x, y in zip(va, vb)]
 
 
 # --------------------------------------------------------------------------- #
-# host wrappers (padding, dtype pick, compaction)                              #
+# host wrappers (digit packing, range checks, padding, compaction)            #
 # --------------------------------------------------------------------------- #
 
 def test_factorize_batch_ragged():
@@ -105,12 +133,39 @@ def test_divisibility_scan_compaction():
 def test_scan_empty_inputs():
     out = divisibility_scan([], [3])
     assert len(out) == 1 and len(out[0]) == 0
-    assert gcd_batch([], []).size == 0
+    assert gcd_batch([], [], [2, 3]) == []
 
 
-class _null:
-    def __enter__(self):
-        return self
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+def test_gcd_batch_matches_math_gcd(dtype):
+    """The host wrapper over squarefree pairs below and above 2**31,
+    sides of unequal digit width included, equals ``math.gcd``."""
+    rng = np.random.default_rng(7)
+    a = _pairs(rng, dtype, 200, replace=False)
+    b = _pairs(rng, dtype, 199, replace=False) + [1]
+    want = [math.gcd(x, y) for x, y in zip(a, b)]
+    pool = [int(p) for p in _primes(dtype)]
+    assert gcd_batch(a, b, pool) == want
+    assert gcd_batch(pack_limbs(a, DIGITS), b, pool) == want
 
-    def __exit__(self, *a):
-        return False
+
+def test_kernel_prime_bound_is_enforced():
+    """Primes at or past 2**25 are refused where they enter the kernels
+    and where a pool would mint them — never truncated."""
+    with pytest.raises(ValueError, match="25-bit"):
+        divisibility_scan([6], [MAX_PRIME_LIMIT + 15])
+    with pytest.raises(ValueError, match="25-bit"):
+        factorize_batch([6], [2, MAX_PRIME_LIMIT + 15])
+    pool = PrimePool(level=3, lo=MAX_PRIME_LIMIT - 100, hi=None,
+                     initial_capacity=8)
+    minted = []
+    with pytest.raises(ValueError, match="25-bit"):
+        for _ in range(64):
+            minted.append(pool.allocate())
+    assert minted and max(minted) < MAX_PRIME_LIMIT
+
+
+def test_kernels_refuse_x64():
+    with jax.enable_x64(True):
+        with pytest.raises(RuntimeError, match="32-bit"):
+            divisibility_scan([6, 10], [2, 3])

@@ -1,8 +1,8 @@
-"""Differential fuzz of the multi-limb wide-composite path (ISSUE 8
-tentpole, DESIGN.md §11): LimbComposite encode/decode and the limb
-divisibility/factorize/gcd kernels against the exact Python-int oracle.
+"""Differential fuzz of the wide-composite path (DESIGN.md §3, §11):
+LimbComposite digit encode/decode and the divisibility/factorize/gcd
+kernels at every width against the exact Python-int oracle.
 
-Every kernel assertion here is bit-exactness — the limb path must agree
+Every kernel assertion here is bit-exactness — the digit path must agree
 with arbitrary-precision host arithmetic on every element, with zero
 false positives (asserted by re-factorization, Theorem 1)."""
 
@@ -17,13 +17,11 @@ from strategies import (LimbUniverseSpec, build_limb_universe,
 from repro.core.composite import (LIMB_BASE, LIMB_BITS, CompositeRegistry,
                                   LimbComposite, int_to_limbs, limbs_to_int,
                                   n_limbs_for_bits, pack_limbs, unpack_limbs)
-from repro.kernels import (divisibility_scan_limbs, factorize_batch_exact,
-                           factorize_batch_limbs, gcd_batch_exact,
-                           gcd_batch_limbs)
-from repro.kernels.ref import (divisibility_mask_limbs_ref,
-                               factorize_limbs_ref, gcd_limbs_ref)
+from repro.kernels import divisibility_scan, factorize_batch, gcd_batch
+from repro.kernels.ref import (divisibility_mask_ref,
+                               factorize_squarefree_ref, gcd_squarefree_ref)
 
-# widths covering 1 limb, a non-power-of-2 limb count, and deep chains
+# widths covering one int64 word, a non-power-of-2 width, and deep chains
 WIDTHS = (64, 96, 256, 1024)
 
 
@@ -41,7 +39,7 @@ def test_limb_encoding_roundtrip_deterministic():
         assert all(0 <= x < LIMB_BASE for x in limbs)
         assert limbs_to_int(limbs) == v
     arr = pack_limbs(vals, L)
-    assert arr.shape == (len(vals), L) and arr.dtype == np.int64
+    assert arr.shape == (len(vals), L) and arr.dtype == np.int32
     assert unpack_limbs(arr) == vals
 
 
@@ -49,9 +47,9 @@ def test_limb_composite_dataclass():
     c = LimbComposite.encode(2**100 + 7, n_limbs_for_bits(128))
     assert c.value == 2**100 + 7
     assert int(c) == 2**100 + 7
-    assert c.n_limbs == 4
+    assert c.n_limbs == 22                   # ceil(128 / 6) digits
     with pytest.raises(OverflowError):
-        LimbComposite.encode(2**64, 2)       # needs 3 limbs
+        LimbComposite.encode(2**64, 2)       # needs 11 digits
     with pytest.raises(ValueError):
         int_to_limbs(-1, 4)
 
@@ -80,16 +78,16 @@ def _check_universe(pool, comps, max_bits):
     qs = pool[:: max(1, len(pool) // 64)]
 
     # §4.2 divisibility scan
-    idx = divisibility_scan_limbs(limbs, qs)
-    ref_mask = divisibility_mask_limbs_ref(limbs, np.asarray(qs))
+    idx = divisibility_scan(limbs, qs)
+    ref_mask = divisibility_mask_ref(limbs, np.asarray(qs))
     for j, q in enumerate(qs):
         want = [i for i, c in enumerate(comps) if c % q == 0]
         assert list(idx[j]) == want, (q, max_bits)
         assert list(np.nonzero(ref_mask[:, j])[0]) == want
 
     # Algorithm 2 factorize: mask + exact residual
-    facs, residual = factorize_batch_limbs(limbs, pool)
-    _, ref_res = factorize_limbs_ref(limbs, np.asarray(pool))
+    facs, residual = factorize_batch(limbs, pool)
+    _, ref_res = factorize_squarefree_ref(limbs, np.asarray(pool))
     for c, fs, r, rr in zip(comps, facs, residual, unpack_limbs(ref_res)):
         rem = c
         for p in fs:
@@ -105,8 +103,9 @@ def _check_universe(pool, comps, max_bits):
     # pairwise gcd via pool reconstruction
     a = comps
     b = comps[1:] + comps[:1]
-    gs = gcd_batch_limbs(a, b, pool)
-    ref_gs = unpack_limbs(gcd_limbs_ref(pack_limbs(a, L), pack_limbs(b, L)))
+    gs = gcd_batch(a, b, pool)
+    ref_gs = unpack_limbs(gcd_squarefree_ref(pack_limbs(a, L),
+                                             pack_limbs(b, L)))
     for x, y, g, rg in zip(a, b, gs, ref_gs):
         assert g == math.gcd(x, y) == rg, (max_bits,)
 
@@ -118,17 +117,20 @@ def test_limb_kernels_match_oracle(max_bits):
 
 
 def test_limb_kernels_narrow_width_agrees_with_flat_path():
-    """At values that fit int64, the exact dispatchers take the flat
-    kernels — and the limb kernels must agree with them anyway."""
+    """Values that fit int64 give the same answers whether they are
+    packed at a narrow registry's digit count or a wide one's — the
+    kernels have one representation for every width."""
     pool, comps = _universe(seed=3, max_bits=62, big_primes=False,
                             max_factors=3)
     assert max(comps) < 2**63
-    facs_e, res_e = factorize_batch_exact(comps, pool)
-    facs_l, res_l = factorize_batch_limbs(comps, pool)
-    assert facs_e == facs_l and [int(r) for r in res_e] == res_l
+    narrow, wide = n_limbs_for_bits(62), n_limbs_for_bits(1024)
+    facs_n, res_n = factorize_batch(pack_limbs(comps, narrow), pool)
+    facs_w, res_w = factorize_batch(pack_limbs(comps, wide), pool)
+    assert facs_n == facs_w and res_n == res_w
     b = comps[1:] + comps[:1]
-    assert gcd_batch_exact(comps, b, pool) == \
-        gcd_batch_limbs(comps, b, pool) == \
+    assert gcd_batch(pack_limbs(comps, narrow), pack_limbs(b, narrow),
+                     pool) == \
+        gcd_batch(pack_limbs(comps, wide), pack_limbs(b, wide), pool) == \
         [math.gcd(x, y) for x, y in zip(comps, b)]
 
 
@@ -140,7 +142,7 @@ def test_partial_pool_residual_is_exact():
     c = 1
     for p in known + hidden:
         c *= p
-    facs, residual = factorize_batch_limbs([c], known)
+    facs, residual = factorize_batch([c], known)
     assert facs == [known]
     assert residual == [hidden[0] * hidden[1]]
 
@@ -158,7 +160,7 @@ def test_limb_kernels_match_oracle_fuzz(spec):
 
 def test_wide_registry_scan_tables_match_host():
     """kernel successor tables over a wide registry == the host oracle's
-    (the §4.2 scan routed through the limb kernels)."""
+    (the §4.2 scan routed through the digit kernels)."""
     from repro.core.assignment import PrimeAssigner
     from repro.core.engine.tables import successor_table
     from repro.core.primes import CacheLevel, HierarchicalPrimeAllocator
@@ -185,7 +187,7 @@ def test_wide_registry_scan_tables_match_host():
 
 
 def test_wide_sharded_table_matches_host():
-    """The collective gcd exchange (limb variant) produces the same
+    """The collective gcd exchange (wide digit rows) produces the same
     successor rows as the single-device host table at 2 and 4 shards."""
     from repro.core.assignment import PrimeAssigner
     from repro.core.engine.shard import (PrimeSpacePartition,

@@ -175,7 +175,7 @@ def test_kernel_wrappers_feed_the_ledger():
     profile.reset()
     with profile.profiling():
         divisibility_scan([6, 10, 15], [2, 3, 5])
-        gcd_batch([12, 18], [8, 27])
+        gcd_batch([6, 15], [10, 21], [2, 3, 5, 7])
     led = profile.summary()
     assert led["divisibility_scan"]["calls"] == 1
     assert led["divisibility_scan"]["items"] == 3

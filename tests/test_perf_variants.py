@@ -85,10 +85,7 @@ def test_split_k_cache_sharding_spec():
     from repro.configs import SHAPES, get_config
     from repro.sharding import partition as pt
 
-    try:   # jax 0.4.x signature; newer jax takes (shape, axis_names)
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
-    except TypeError:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("qwen3-32b")          # kv=8: cannot shard 16-way
     model = build_model(cfg)
     cache = model.cache_specs(SHAPES[2])   # decode_32k

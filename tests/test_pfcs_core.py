@@ -491,7 +491,7 @@ def test_register_many_error_parity_preserves_prefix():
     assert _registry_state(s) == _registry_state(b)
     with pytest.raises(ValueError):
         b.register_many([(17,)])            # < 2 distinct elements
-    # wide mode: oversized prime rejected with the canonical limb error
+    # wide mode: oversized prime rejected with the canonical bound error
     w = CompositeRegistry(max_bits=128)
-    with pytest.raises(ValueError, match="kernel limb word"):
+    with pytest.raises(ValueError, match="kernel prime bound"):
         w.register_many([(3, (1 << 31) + 11)])

@@ -63,3 +63,59 @@ def test_example_script_drives_the_launcher():
                  for e in a.elts if isinstance(e, ast.Constant)]
         assert "--front-end" not in flags, \
             "load-generator pass must use the default (slots) front-end"
+
+
+def test_launcher_smoke_flag_selects_the_config(monkeypatch):
+    """``--smoke`` asks for the reduced config; without it the launcher
+    builds the published widths of ``--arch`` (the flag used to be
+    stuck on)."""
+    import repro.launch.serve as serve
+
+    asked = []
+
+    class Built(Exception):
+        pass
+
+    def fake_build(arch, smoke, seed=0):
+        asked.append((arch, smoke))
+        raise Built
+
+    monkeypatch.setattr(serve, "build_model", fake_build)
+    for argv, want in ((["--arch", "gemma-2b"], ("gemma-2b", False)),
+                       (["--arch", "gemma-2b", "--smoke"],
+                        ("gemma-2b", True))):
+        try:
+            serve_main(argv)
+        except Built:
+            pass
+        assert asked.pop() == want
+
+
+def test_launcher_serves_a_smoke_model_end_to_end():
+    out = serve_main(["--arch", "qwen2.5-3b", "--smoke", "--requests", "3",
+                      "--max-new", "3", "--max-batch", "2", "--max-seq",
+                      "64", "--shared-prefix", "8"])
+    assert out["front_end"] == "engine"
+    assert out["completed"] == 3 and out["decode_tokens"] == 9
+
+
+def test_compile_cache_defers_to_the_environment(monkeypatch):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads it itself and
+    the entry points' helper sets no directory in code; it still keeps
+    programs of any compile time."""
+    import jax
+
+    from repro.launch.compile_cache import (DEFAULT_CACHE_DIR,
+                                            enable_compile_cache)
+
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    try:
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_secs)
+    assert DEFAULT_CACHE_DIR == ROOT / ".jax_cache"

@@ -14,16 +14,9 @@ from repro.training.train_loop import abstract_train_state
 
 
 def _fake_mesh(shape, axes):
-    """AbstractMesh carries axis sizes without needing real devices.
-
-    jax 0.4.x takes one ``((name, size), ...)`` tuple; newer jax takes
-    ``(shape, axis_names)`` — support both.
-    """
+    """AbstractMesh carries axis sizes without needing real devices."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 MESH = _fake_mesh((16, 16), ("data", "model"))

@@ -28,8 +28,9 @@ import sys
 from pathlib import Path
 from typing import List
 
-# all-caps markdown names anywhere, or an explicit docs/*.md path
-FILE_REF = re.compile(r"\b(docs/[a-z_]+\.md|[A-Z][A-Z_]*\.md)\b")
+# all-caps markdown names anywhere (with the directory path they are
+# given under, if any), or an explicit docs/*.md path
+FILE_REF = re.compile(r"(?<![\w/.-])(docs/[a-z_]+\.md|(?:[\w.-]+/)*[A-Z][A-Z_]*\.md)\b")
 SECTION_REF = re.compile(r"\bDESIGN\.md\s+§(\d+)")
 API_PROMISE = re.compile(r"documented with runnable examples in "
                          r"docs/api\.md")
