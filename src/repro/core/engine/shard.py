@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import profile
 from repro.obs.profile import kernel_scope
 from repro.sharding.stripes import BlockStripes
 
@@ -268,7 +269,8 @@ def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
     Q = queries.shape[1]
     K = chunks.shape[1]
 
-    with kernel_scope("sharded_gcd_exchange", items=S * C):
+    with kernel_scope("sharded_gcd_exchange", items=S * C, shards=S,
+                      c=C, q=Q, chunks=K):
         if mesh is not None and mesh.size == S:
             fn = _shard_map_scan(
                 mesh, (C, Q, K, pools.shape[1], cross_c.shape[1]), interpret)
@@ -286,7 +288,8 @@ def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
                 masks.append(m)
                 gs.append(g)
             mask, g = jnp.stack(masks), jnp.stack(gs)
-        return np.asarray(mask), np.asarray(g)
+        with profile.span("kernels.sharded_gcd_exchange.fetch"):
+            return np.asarray(mask), np.asarray(g)
 
 
 def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
@@ -324,49 +327,52 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     if arr.size == 0 or not keyed:
         return {d: [] for d, _ in keyed}
 
-    # ---- partition state: registry slices and query routing ------------- #
-    if precomputed is not None:
-        local_pos, cross_pos = precomputed
-    else:
-        local_pos, cross_pos = partition.classify(registry)
-    by_shard: List[List[Tuple[int, int]]] = [[] for _ in range(S)]
-    for d, p in keyed:
-        by_shard[partition.owner(p)].append((d, p))
+    with profile.span("tables.pack", queries=len(keyed),
+                      composites=int(arr.size)):
+        # ---- partition state: registry slices and query routing --------- #
+        if precomputed is not None:
+            local_pos, cross_pos = precomputed
+        else:
+            local_pos, cross_pos = partition.classify(registry)
+        by_shard: List[List[Tuple[int, int]]] = [[] for _ in range(S)]
+        for d, p in keyed:
+            by_shard[partition.owner(p)].append((d, p))
 
-    queries = _pad_rows([prime_lanes([p for _, p in sh]) for sh in by_shard],
-                        512, 0)
-    # query chunk products: each shard's owned query primes packed into
-    # registry-width composites — the gcd exchange payload (one wide
-    # chunk usually covers the whole shard's query set)
-    chunk_vals: List[List[int]] = []
-    for sh in by_shard:
-        ps = {p for _, p in sh}
-        chunk_vals.append(encode_relationship(ps, registry.max_bits)
-                          if ps else [])
-    # per-shard cross-slice width bucketed to powers of two, like every
-    # other stack: an exact ceil(cross/S) width would change the compiled
-    # shard_map shape on nearly every registry growth
-    need = -(-max(len(cross_pos), 1) // S)
-    per = 8
-    while per < need:
-        per *= 2
+        queries = _pad_rows([prime_lanes([p for _, p in sh])
+                             for sh in by_shard], 512, 0)
+        # query chunk products: each shard's owned query primes packed into
+        # registry-width composites — the gcd exchange payload (one wide
+        # chunk usually covers the whole shard's query set)
+        chunk_vals: List[List[int]] = []
+        for sh in by_shard:
+            ps = {p for _, p in sh}
+            chunk_vals.append(encode_relationship(ps, registry.max_bits)
+                              if ps else [])
+        # per-shard cross-slice width bucketed to powers of two, like every
+        # other stack: an exact ceil(cross/S) width would change the compiled
+        # shard_map shape on nearly every registry growth
+        need = -(-max(len(cross_pos), 1) // S)
+        per = 8
+        while per < need:
+            per *= 2
 
-    digits = registry.limbs_array()
-    L = registry.n_limbs
-    local_c = _pad_digit_stack(
-        [digits[np.asarray(pos, dtype=np.int64)]
-         if pos else np.empty((0, L), np.int32)
-         for pos in local_pos], 256, L)
-    chunks = _pad_digit_stack([pack_limbs(cv, L) for cv in chunk_vals], 1, L)
-    # the gcd-reconstruction pool: each shard's deduplicated query primes
-    # (zero-padded) — exactly the primes its chunks contain
-    pools = _pad_rows([prime_lanes(sorted({p for _, p in sh}))
-                       for sh in by_shard], 512, 0)
-    cross_digits = (digits[np.asarray(cross_pos, dtype=np.int64)]
-                    if cross_pos else np.empty((0, L), np.int32))
-    cross_sh = _pad_digit_stack(
-        [cross_digits[s * per:(s + 1) * per] for s in range(S)],
-        1, L, width=per)
+        digits = registry.limbs_array()
+        L = registry.n_limbs
+        local_c = _pad_digit_stack(
+            [digits[np.asarray(pos, dtype=np.int64)]
+             if pos else np.empty((0, L), np.int32)
+             for pos in local_pos], 256, L)
+        chunks = _pad_digit_stack([pack_limbs(cv, L) for cv in chunk_vals],
+                                  1, L)
+        # the gcd-reconstruction pool: each shard's deduplicated query primes
+        # (zero-padded) — exactly the primes its chunks contain
+        pools = _pad_rows([prime_lanes(sorted({p for _, p in sh}))
+                           for sh in by_shard], 512, 0)
+        cross_digits = (digits[np.asarray(cross_pos, dtype=np.int64)]
+                        if cross_pos else np.empty((0, L), np.int32))
+        cross_sh = _pad_digit_stack(
+            [cross_digits[s * per:(s + 1) * per] for s in range(S)],
+            1, L, width=per)
 
     # ---- kernel work (shard_map when the mesh matches) ------------------ #
     mask, gcds = _scan_sharded(local_c, queries, chunks, pools, cross_sh,
@@ -380,51 +386,53 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
         report.queries_per_shard = [len(sh) for sh in by_shard]
         report.gcd_pairs = int(chunks.shape[1] * S * per)
 
-    # ---- decode the gcd exchange: which cross composites contain which
-    # owned query primes (exact — unique factorization) ------------------- #
-    cross_of_prime: Dict[int, List[int]] = {}
-    for s in range(S):
-        if not by_shard[s] or not cross_pos:
-            continue
-        pool = sorted({p for _, p in by_shard[s]})
-        gs = gcds[s]                        # (K, X, L) digit rows
-        # value > 1 iff digit 0 > 1 or any higher digit nonzero
-        hit_k, hit_x = np.nonzero((gs[..., 0] > 1)
-                                  | (gs[..., 1:] != 0).any(axis=-1))
-        valid = hit_x < len(cross_pos)      # drop padding columns
-        hit_k, hit_x = hit_k[valid], hit_x[valid]
-        hit_vals = unpack_limbs(gs[hit_k, hit_x])
-        uniq = sorted(set(hit_vals))
-        if not uniq:
-            continue
-        facs, residual = factorize_batch(uniq, pool)
-        assert all(r == 1 for r in residual), \
-            "gcd escaped the shard's query pool"
-        fac_of = {g: fs for g, fs in zip(uniq, facs)}
-        for x, v in zip(hit_x, hit_vals):
-            for q in fac_of[v]:
-                cross_of_prime.setdefault(int(q), []).append(int(x))
+    with profile.span("tables.candidates"):
+        # ---- decode the gcd exchange: which cross composites contain which
+        # owned query primes (exact — unique factorization) --------------- #
+        cross_of_prime: Dict[int, List[int]] = {}
+        for s in range(S):
+            if not by_shard[s] or not cross_pos:
+                continue
+            pool = sorted({p for _, p in by_shard[s]})
+            gs = gcds[s]                        # (K, X, L) digit rows
+            # value > 1 iff digit 0 > 1 or any higher digit nonzero
+            hit_k, hit_x = np.nonzero((gs[..., 0] > 1)
+                                      | (gs[..., 1:] != 0).any(axis=-1))
+            valid = hit_x < len(cross_pos)      # drop padding columns
+            hit_k, hit_x = hit_k[valid], hit_x[valid]
+            hit_vals = unpack_limbs(gs[hit_k, hit_x])
+            uniq = sorted(set(hit_vals))
+            if not uniq:
+                continue
+            facs, residual = factorize_batch(uniq, pool)
+            assert all(r == 1 for r in residual), \
+                "gcd escaped the shard's query pool"
+            fac_of = {g: fs for g, fs in zip(uniq, facs)}
+            for x, v in zip(hit_x, hit_vals):
+                for q in fac_of[v]:
+                    cross_of_prime.setdefault(int(q), []).append(int(x))
 
-    # ---- assemble rows in the oracle's exact order ---------------------- #
-    out: Dict[int, List[int]] = {}
-    for s in range(S):
-        pos_map = local_pos[s]
-        for col, (d, p) in enumerate(by_shard[s]):
-            hits = [pos_map[i] for i in np.nonzero(mask[s, :len(pos_map),
-                                                        col])[0]]
-            hits.extend(cross_pos[x] for x in cross_of_prime.get(p, ()))
-            row: List[int] = []
-            seen: set = set()
-            for pos in sorted(hits):        # ascending == registry order
-                rel = registry.relationship_of_composite(int(arr[pos]))
-                if rel is None or rel.rel_id in seen:
-                    continue
-                seen.add(rel.rel_id)
-                for q in rel.primes:        # oracle's frozenset order
-                    if q == p:
+    with profile.span("tables.rows"):
+        # ---- assemble rows in the oracle's exact order ------------------ #
+        out: Dict[int, List[int]] = {}
+        for s in range(S):
+            pos_map = local_pos[s]
+            for col, (d, p) in enumerate(by_shard[s]):
+                hits = [pos_map[i] for i in
+                        np.nonzero(mask[s, :len(pos_map), col])[0]]
+                hits.extend(cross_pos[x] for x in cross_of_prime.get(p, ()))
+                row: List[int] = []
+                seen: set = set()
+                for pos in sorted(hits):        # ascending == registry order
+                    rel = registry.relationship_of_composite(int(arr[pos]))
+                    if rel is None or rel.rel_id in seen:
                         continue
-                    succ = assigner.data_of(q)
-                    if succ is not None:
-                        row.append(succ)
-            out[d] = row
+                    seen.add(rel.rel_id)
+                    for q in rel.primes:        # oracle's frozenset order
+                        if q == p:
+                            continue
+                        succ = assigner.data_of(q)
+                        if succ is not None:
+                            row.append(succ)
+                out[d] = row
     return out

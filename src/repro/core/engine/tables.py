@@ -36,6 +36,8 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import profile
+
 from ..pfcs_cache import PFCSCache
 from ..traces import Trace
 
@@ -167,70 +169,80 @@ def successor_table(registry, assigner, data_ids: Sequence[int],
     has an assigned prime (ids without one discover nothing — exactly
     the oracle's early return).
     """
-    keyed = [(int(d), p) for d in data_ids
-             if (p := assigner.prime_of(int(d))) is not None]
-    if discover == "host":
-        out: Dict[int, List[int]] = {}
-        for d, p in keyed:
-            row: List[int] = []
-            for rel in registry.containing(p):
-                for q in rel.primes:
-                    if q == p:
-                        continue
-                    succ = assigner.data_of(q)
-                    if succ is not None:
-                        row.append(succ)
-            out[d] = row
-        return out
-    if discover != "kernel":
+    if discover not in ("host", "kernel"):
         raise ValueError(f"discover must be 'host' or 'kernel', "
                          f"got {discover!r}")
+    with profile.span("tables.pack"):
+        keyed = [(int(d), p) for d in data_ids
+                 if (p := assigner.prime_of(int(d))) is not None]
+        arr = registry.composites_view()
+        kernel = discover == "kernel" and arr.size > 0 and bool(keyed)
+        if kernel:
+            primes = np.asarray([p for _, p in keyed], dtype=np.int64)
+            pool = registry.primes_array()
+            digits = registry.limbs_array()
+    # counts go to the span open around the call (``pages.refresh``)
+    profile.count(queries=len(keyed), composites=int(arr.size),
+                  pool=len(pool) if kernel else 0)
+    if discover == "host":
+        with profile.span("tables.rows"):
+            out: Dict[int, List[int]] = {}
+            for d, p in keyed:
+                row: List[int] = []
+                for rel in registry.containing(p):
+                    for q in rel.primes:
+                        if q == p:
+                            continue
+                        succ = assigner.data_of(q)
+                        if succ is not None:
+                            row.append(succ)
+                out[d] = row
+        return out
+
+    if not kernel:
+        return {d: [] for d, _ in keyed}
 
     from repro.kernels.ops import divisibility_scan, factorize_batch
 
-    arr = registry.composites_view()
-    if arr.size == 0 or not keyed:
-        return {d: [] for d, _ in keyed}
-
     # kernel pass 1: registry divisibility scan over the digit matrix
     # (every width, DESIGN.md §3), chunked over query primes
-    primes = np.asarray([p for _, p in keyed], dtype=np.int64)
-    digits = registry.limbs_array()
     cand: List[np.ndarray] = []
     for lo in range(0, len(primes), chunk):
         cand.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
 
     # kernel pass 2: decode every candidate composite once (Theorem 1
     # check: the decoded factors must contain the query prime)
-    needed = sorted({int(i) for idxs in cand for i in idxs})
+    with profile.span("tables.candidates"):
+        needed = sorted({int(i) for idxs in cand for i in idxs})
+    profile.count(candidates=len(needed))
     factors_of: Dict[int, set] = {}
     if needed:
         comps = arr[np.asarray(needed)]
-        facs, residual = factorize_batch(digits[np.asarray(needed)],
-                                         registry.primes_array())
+        facs, residual = factorize_batch(digits[np.asarray(needed)], pool)
         assert all(r == 1 for r in residual), \
             "registry composite escaped its pool"
         for c, fs in zip(comps, facs):
             factors_of[int(c)] = set(fs)
 
-    out = {}
-    for (d, p), idxs in zip(keyed, cand):
-        row = []
-        seen: set = set()
-        for i in idxs:                        # ascending == registry order
-            c = int(arr[int(i)])
-            assert p in factors_of[c], "divisibility hit must contain p"
-            rel = registry.relationship_of_composite(c)
-            if rel is None or rel.rel_id in seen:
-                continue
-            seen.add(rel.rel_id)
-            for q in rel.primes:              # oracle's frozenset order
-                if q == p:
+    with profile.span("tables.rows"):
+        out = {}
+        for (d, p), idxs in zip(keyed, cand):
+            row = []
+            seen: set = set()
+            for i in idxs:                    # ascending == registry order
+                c = int(arr[int(i)])
+                assert p in factors_of[c], "divisibility hit must contain p"
+                rel = registry.relationship_of_composite(c)
+                if rel is None or rel.rel_id in seen:
                     continue
-                succ = assigner.data_of(q)
-                if succ is not None:
-                    row.append(succ)
-        out[d] = row
+                seen.add(rel.rel_id)
+                for q in rel.primes:          # oracle's frozenset order
+                    if q == p:
+                        continue
+                    succ = assigner.data_of(q)
+                    if succ is not None:
+                        row.append(succ)
+            out[d] = row
     return out
 
 

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core.composite import (LIMB_BASE, MAX_PRIME_BITS, MAX_PRIME_LIMIT,
                                   n_limbs_for_bits, pack_limbs, unpack_limbs)
+from repro.obs import profile
 from repro.obs.profile import kernel_scope
 
 from .factorize import divisibility_mask_pallas, factorize_squarefree_pallas
@@ -129,13 +130,18 @@ def divisibility_scan(
     n, q = digits.shape[0], qs.shape[0]
     if n == 0 or q == 0:
         return [np.empty(0, dtype=np.int64) for _ in range(q)]
-    with kernel_scope("divisibility_scan", items=n):
+    with kernel_scope("divisibility_scan", items=n, n=n, q=q,
+                      padded_n=_bucket(n, block_n),
+                      padded_q=_bucket(q, block_p)):
         mask = divisibility_mask_pallas(
             jnp.asarray(_pad_rows_one(digits, block_n)),
             jnp.asarray(_pad_to(qs, block_p, 0)),
             block_n=block_n, block_p=block_p, interpret=interpret)
-        mask = np.asarray(mask)[:n, :q]
-    return [np.nonzero(mask[:, j])[0] for j in range(q)]
+        # the blocking copy: the device finishing the kernel, then D2H
+        with profile.span("kernels.divisibility_scan.fetch"):
+            mask = np.asarray(mask)
+        mask = mask[:n, :q]
+        return [np.nonzero(mask[:, j])[0] for j in range(q)]
 
 
 def factorize_batch(
@@ -159,16 +165,19 @@ def factorize_batch(
     n, p = digits.shape[0], pool.shape[0]
     if n == 0:
         return [], []
-    with kernel_scope("factorize_batch", items=n):
+    with kernel_scope("factorize_batch", items=n, n=n, p=p,
+                      padded_n=_bucket(n, block_n),
+                      padded_p=_bucket(p, block_p)):
         mask, residual = factorize_squarefree_pallas(
             jnp.asarray(_pad_rows_one(digits, block_n)),
             jnp.asarray(_pad_to(pool, block_p, 0)),
             block_n=block_n, block_p=block_p, interpret=interpret)
-        mask = np.asarray(mask)[:n, :p]
-        residual = np.asarray(residual)[:n]
-    factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]]
-               for i in range(n)]
-    return factors, unpack_limbs(residual)
+        with profile.span("kernels.factorize_batch.fetch"):
+            mask, residual = np.asarray(mask), np.asarray(residual)
+        mask, residual = mask[:n, :p], residual[:n]
+        factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]]
+                   for i in range(n)]
+        return factors, unpack_limbs(residual)
 
 
 def gcd_batch(
@@ -200,11 +209,14 @@ def gcd_batch(
     n = aa.shape[0]
     if n == 0:
         return []
-    with kernel_scope("gcd_batch", items=n):
+    with kernel_scope("gcd_batch", items=n, n=n, p=len(primes),
+                      padded_n=_bucket(n, block_n),
+                      padded_p=_bucket(len(primes), block_p)):
         g = gcd_squarefree_pallas(
             jnp.asarray(_pad_rows_one(aa, block_n)),
             jnp.asarray(_pad_rows_one(bb, block_n)),
             jnp.asarray(_pad_to(primes, block_p, 0)),
             block_n=block_n, block_p=block_p, interpret=interpret)
-        g = np.asarray(g)[:n]
-    return unpack_limbs(g)
+        with profile.span("kernels.gcd_batch.fetch"):
+            g = np.asarray(g)
+        return unpack_limbs(g[:n])

@@ -85,9 +85,10 @@ def main(argv=None):
                          "generation (scales to hundreds of slots)")
     ap.add_argument("--trace", metavar="PATH", default=None,
                     help="record the observability layer (event ring + "
-                         "telemetry + kernel ledger, DESIGN.md §13) and "
-                         "write the export JSON here; feed it to "
-                         "tools/trace_view.py for a Chrome trace")
+                         "telemetry + kernel ledger + program spans, "
+                         "DESIGN.md §13) and write the export JSON here; "
+                         "feed it to tools/trace_view.py for a Chrome "
+                         "trace")
     args = ap.parse_args(argv)
 
     obs = None
@@ -97,6 +98,8 @@ def main(argv=None):
         obs = Observability()
         profile.reset()
         profile.enable(True)
+        profile.clear_spans()
+        profile.record(True)
 
     if args.null_model:
         model, params, vocab = None, None, 32_000
@@ -179,6 +182,7 @@ def main(argv=None):
         from repro.obs import profile
 
         profile.enable(False)
+        profile.record(False)
         obs.export_json(args.trace)
         out["trace_events"] = obs.trace.total
         print(f"observability export ({obs.trace.total} events) "

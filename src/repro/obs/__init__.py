@@ -1,6 +1,6 @@
 """PFCS observability layer: event tracing, serving telemetry, kernel
-profiling (DESIGN.md §13) — disabled by default, provably inert when
-off.
+profiling and program spans (DESIGN.md §13) — disabled by default,
+provably inert when off.
 
 Every serving cache, slot front-end, and engine carries an ``obs``
 attribute that defaults to ``None``; every hook in the hot paths is
@@ -17,9 +17,9 @@ Documented with runnable examples in docs/api.md:
 :class:`~repro.obs.trace.EventTracer` (the int32 event ring),
 :func:`~repro.obs.trace.trace_diff` (the differential-trace axis),
 :class:`~repro.obs.telemetry.Telemetry` (gauges + histograms),
-:class:`~repro.obs.telemetry.Progress` (host-side rate reporting), and
+:class:`~repro.obs.telemetry.Progress` (host-side rate reporting),
 :func:`~repro.obs.profile.kernel_scope` (named-scope + launch-ledger
-profiling).
+profiling), and :func:`~repro.obs.profile.span` (the span recorder).
 """
 
 from __future__ import annotations
@@ -53,8 +53,10 @@ class Observability:
 
     ``trace_capacity=0`` keeps the tracer attached but recording
     nothing (pure counter bumps); ``telemetry=False`` drops the
-    telemetry sink entirely.  The kernel profiling ledger is
-    process-global (``repro.obs.profile``) and merely *reported* here.
+    telemetry sink entirely.  The kernel profiling ledger and the span
+    recorder are process-global (``repro.obs.profile``) and merely
+    *reported* here: the export carries the ledger and the spans the
+    recorder kept in memory.
     """
 
     def __init__(self, trace_capacity: int = 4096,
@@ -77,6 +79,7 @@ class Observability:
             "telemetry": (self.telemetry.export()
                           if self.telemetry is not None else None),
             "kernel_launches": profile.summary(),
+            "spans": [r.as_dict() for r in profile.spans()],
         }
 
     def export_json(self, path) -> None:

@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from repro.core.engine.shard import (PrimeSpacePartition, ShardScanReport,
                                      shard_mesh, sharded_successor_table)
@@ -115,10 +115,10 @@ class ElasticShardedPagedKVCache(ShardedPagedKVCache):
     # discovery (index-fed sharded scan)                                  #
     # ------------------------------------------------------------------ #
 
-    def refresh_tables(self, discover: Optional[str] = None) -> None:
+    def _build_rows(self, discover: Optional[str]
+                    ) -> Dict[int, List[int]]:
         if discover is not None:
-            super().refresh_tables(discover)
-            return
+            return super()._build_rows(discover)
         self._recover_dead()
         self.slices.sync(self.registry)
         self.last_scan = ShardScanReport()
@@ -127,7 +127,7 @@ class ElasticShardedPagedKVCache(ShardedPagedKVCache):
             self.partition, mesh=self.mesh, report=self.last_scan,
             precomputed=(self.slices.local(), self.slices.cross()))
         self._ensure_pages(self._next_page)
-        self._install_rows(rows)
+        return rows
 
     def _sync_tables(self) -> None:
         # failover on demand: a killed shard is recovered before any

@@ -64,6 +64,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs import profile
+
 from .elastic import ElasticShardedPagedKVCache
 from .expert_cache import ExpertCache
 from .expert_cache_vec import VectorizedExpertCache
@@ -217,6 +219,8 @@ class Request:
     tenant: int = 0                # namespace id (tenants= mode; else 0)
     generated: List[int] = field(default_factory=list)
     state: str = "queued"          # queued | running | done
+    # host stamps on time.perf_counter, the clock of repro.obs.profile
+    # spans and of the benchmark's host-clock metrics
     submit_t: float = 0.0
     first_token_t: Optional[float] = None
     done_t: Optional[float] = None
@@ -344,7 +348,7 @@ class ServingEngine:
         self._next_id += 1
         self.queue.append(Request(rid, list(prompt), max_new_tokens,
                                   tenant=int(tenant),
-                                  submit_t=time.monotonic()))
+                                  submit_t=time.perf_counter()))
         return rid
 
     def _admit(self) -> None:
@@ -409,10 +413,16 @@ class ServingEngine:
         Page placement for the WHOLE batch is a single ``touch_batch``
         call — with the vectorized cache that means bulk table-driven
         discovery; with the scalar oracle it degenerates to the per-page
-        scan loop.
+        scan loop.  The span ``engine.step``, counted by ``tick``.
         """
-        self._admit()
+        with profile.span("engine.step", tick=self.steps):
+            return self._step()
+
+    def _step(self) -> Dict[str, Any]:
+        with profile.span("engine.admit"):
+            self._admit()
         live = [(i, r) for i, r in enumerate(self.slots) if r is not None]
+        profile.count(live=len(live), queued=len(self.queue))
         if not live:
             return {"live": 0}
         self.peak_live = max(self.peak_live, len(live))
@@ -423,7 +433,8 @@ class ServingEngine:
                    if (n := len(self.pages.chains.get(r.req_id) or ()))
                    for j in range(max(0, n - self.reread_window), n)]
         if touches:
-            self.pages.touch_batch(touches)
+            with profile.span("pages.touch_batch", items=len(touches)):
+                self.pages.touch_batch(touches)
 
         router = None
         if self.model is not None:
@@ -433,12 +444,14 @@ class ServingEngine:
             for i, req in live:
                 toks[i, 0] = (req.generated[-1] if req.generated else
                               (req.prompt[-1] if req.prompt else 0))
-            out = self._decode(self.params, {"tokens": jnp.asarray(toks)},
-                               self.cache)
+            with profile.span("engine.decode"):
+                out = self._decode(self.params,
+                                   {"tokens": jnp.asarray(toks)}, self.cache)
             logits, self.cache = out[0], out[1]
-            if self._router_decode:
-                router = np.asarray(out[2])       # (n_moe_layers, B, K)
-            lg = np.asarray(logits)
+            with profile.span("engine.fetch"):
+                if self._router_decode:
+                    router = np.asarray(out[2])   # (n_moe_layers, B, K)
+                lg = np.asarray(logits)
             nxt_of = {i: int(np.argmax(lg[i, -1])) for i, _ in live}
         else:
             nxt_of = {i: self._stub_token(r) for i, r in live}
@@ -456,7 +469,7 @@ class ServingEngine:
             self.experts.observe_routing(sets)
             self.experts.activate_batch(sets)
 
-        now = time.monotonic()
+        now = time.perf_counter()
         for i, req in live:
             req.generated.append(nxt_of[i])
             if req.first_token_t is None:

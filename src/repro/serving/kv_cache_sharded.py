@@ -106,14 +106,14 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
     # sharded bulk discovery                                              #
     # ------------------------------------------------------------------ #
 
-    def refresh_tables(self, discover: Optional[str] = None) -> None:
-        """Rebuild every successor row by per-shard Pallas scans under
+    def _build_rows(self, discover: Optional[str]
+                    ) -> Dict[int, List[int]]:
+        """Every successor row by per-shard Pallas scans under
         ``shard_map`` + the cross-shard gcd exchange.  An explicit
         ``discover="host"|"kernel"`` falls back to the single-device
         bulk path (cross-check hook for the parity tests)."""
         if discover is not None:
-            super().refresh_tables(discover)
-            return
+            return super()._build_rows(discover)
         self.last_scan = ShardScanReport()
         rows = sharded_successor_table(self.registry, self.assigner,
                                        range(self._next_page),
@@ -123,7 +123,7 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
             for sh, n_local in enumerate(self.last_scan.local_composites):
                 self.obs.emit(EV_GCD_EXCHANGE, shard=sh, arg=n_local)
         self._ensure_pages(self._next_page)
-        self._install_rows(rows)
+        return rows
 
     # ------------------------------------------------------------------ #
     # owner-routed touches and per-shard accounting                       #

@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.composite import encode_relationship, pack_limbs
 from repro.core.engine.tables import successor_table
+from repro.obs import profile
 from repro.obs.trace import EV_PREFETCH
 
 from .kv_cache import PagedKVCache
@@ -216,13 +217,20 @@ class VectorizedPagedKVCache(PagedKVCache):
 
     def refresh_tables(self, discover: Optional[str] = None) -> None:
         """Rebuild every successor row in ONE bulk discovery call
-        (host replay or Pallas kernels)."""
+        (host replay or Pallas kernels): the span ``pages.refresh``,
+        whatever backend builds the rows."""
+        with profile.span("pages.refresh", pages=self._next_page):
+            rows = self._build_rows(discover)
+            with profile.span("pages.install", rows=len(rows)):
+                self._install_rows(rows)
+
+    def _build_rows(self, discover: Optional[str]
+                    ) -> Dict[int, List[int]]:
         backend = discover or self.discover
         if backend == "incremental":
             backend = "host"   # bulk rebuild semantics == host replay
-        rows = successor_table(self.registry, self.assigner,
+        return successor_table(self.registry, self.assigner,
                                range(self._next_page), discover=backend)
-        self._install_rows(rows)
 
     def _install_rows(self, rows: Dict[int, List[int]]) -> None:
         """Replace the whole successor table with freshly-built rows and

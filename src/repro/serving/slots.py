@@ -67,6 +67,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import profile
 from repro.obs.trace import (EV_ADMIT, EV_COMPLETE, EV_PREEMPT,
                              EV_PREFILL_CHUNK, EV_RESUME_PREFETCH)
 
@@ -409,17 +410,115 @@ class SlotMachine(_SlotFrontEnd):
     def step(self) -> Dict[str, Any]:
         """One tick: arrivals -> (preempt) -> admit -> decode/prefill
         masks -> ONE touch_batch -> token/MoE bookkeeping -> completion
-        -> ages."""
+        -> ages.  The span ``slots.step``, counted by ``tick``."""
+        with profile.span("slots.step", tick=self.now):
+            return self._step()
+
+    def _step(self) -> Dict[str, Any]:
         t = self.now
         self._arrivals()
         self.peak_in_flight = max(
             self.peak_in_flight,
             len(self.waiting) + int((self.phase != PHASE_FREE).sum()))
         fresh = np.zeros(self.max_batch, bool)
-        anchor_items: List[Tuple[int, int]] = []
+        with profile.span("slots.admit"):
+            self._preempt(t)
+            queued = len(self.waiting)
+            anchor_items = self._admit(t, fresh)
+        admitted = queued - len(self.waiting)
+        self.peak_live = max(self.peak_live,
+                             int((self.phase != PHASE_FREE).sum()))
 
-        # -- preemption (continuous policy only): queue pressure evicts
-        #    the decode slot with the most remaining work ---------------- #
+        # -- decode mask + window touches (slots live BEFORE this tick) -- #
+        decode_mask = (self.phase == PHASE_DECODE) & ~fresh
+        d_idx = np.flatnonzero(decode_mask)
+        L = self.chain_len[d_idx]
+        rows, pages_idx = _ranges(
+            np.maximum(0, L - self.reread_window).astype(np.int64),
+            L.astype(np.int64))
+        d_reqs = self.slot_req[d_idx]
+        decode_items = list(zip(d_reqs[rows].tolist(), pages_idx.tolist()))
+
+        # -- chunked prefill: one budget, greedy in slot order ----------- #
+        p_idx = np.flatnonzero(self.phase == PHASE_PREFILL)
+        prefill_items: List[Tuple[int, int]] = []
+        if len(p_idx):
+            need = (self.need_prompt[p_idx]
+                    - self.prefill_done[p_idx]).astype(np.int64)
+            excl = np.cumsum(need) - need
+            give = np.clip(self.prefill_tokens - excl, 0, need)
+            old = self.prefill_done[p_idx].astype(np.int64)
+            new = old + give
+            ps = self.page_size
+            rows, pages_idx = _ranges(-(-old // ps), -(-new // ps))
+            p_reqs = self.slot_req[p_idx]
+            prefill_items = list(zip(p_reqs[rows].tolist(),
+                                     pages_idx.tolist()))
+            if self.obs is not None:
+                for k, i in enumerate(p_idx):
+                    if give[k] > 0:
+                        self._note_prefill_chunk(int(i), int(p_reqs[k]),
+                                                 t, int(give[k]))
+            self.prefill_done[p_idx] = new.astype(np.int32)
+            finished = p_idx[new >= self.need_prompt[p_idx]]
+            self.phase[finished] = PHASE_DECODE
+            fresh[finished] = True               # decode starts NEXT tick
+            for i in finished:
+                self.requests[int(self.slot_req[i])].state = "decode"
+
+        # -- the tick's ONE bulk cache call ------------------------------ #
+        items = anchor_items + decode_items + prefill_items
+        if items:
+            with profile.span("pages.touch_batch", items=len(items)):
+                self.tier_log.extend(self.pages.touch_batch(items))
+
+        # -- token + MoE bookkeeping ------------------------------------- #
+        if len(d_idx):
+            if self.experts is not None:
+                g = (d_reqs.astype(np.int64) * 7919
+                     + self.gen[d_idx].astype(np.int64) * 104_729) \
+                    % len(self._moe_groups)
+                sets = [self._moe_groups[i] for i in g.tolist()]
+                self.experts.observe_routing(sets)
+                self.experts.activate_batch(sets)
+            first = d_idx[self.gen[d_idx] == 0]
+            for i in first:
+                self.requests[int(self.slot_req[i])].first_tick = t
+            self.gen[d_idx] += 1
+
+        # -- completion: vectorized mask, per-request release ------------ #
+        done_idx = d_idx[self.gen[d_idx] >= self.need_new[d_idx]]
+        with profile.span("slots.complete", done=len(done_idx)):
+            for i in done_idx:
+                req = self.requests[int(self.slot_req[i])]
+                req.generated = _stub_tokens(req.req_id, int(self.gen[i]))
+                req.state = "done"
+                req.done_tick = t
+                self.pages.release_request(req.req_id)
+                self._note_complete(int(i), req, t)
+            self.phase[done_idx] = PHASE_FREE
+            self.slot_req[done_idx] = -1
+
+        # -- ages: +1 for surviving occupants, 0 for fresh phases -------- #
+        occ = self.phase != PHASE_FREE
+        self.age[occ & ~fresh] += 1
+        self.age[fresh & occ] = 0
+        self._note_tick()
+        profile.count(admitted=admitted, decoding=len(d_idx),
+                      waiting=len(self.waiting))
+        self.now += 1
+        self.ticks += 1
+        out = {"live": int(occ.sum()), "waiting": len(self.waiting),
+               "page_stats": self.pages.stats}
+        if self.tenants is not None:
+            out["tenant_stats"] = self.pages.qos.tenant_stats
+        if self.experts is not None:
+            out["expert_stats"] = self.experts.stats
+        return out
+
+    def _preempt(self, t: int) -> None:
+        """Preemption (continuous policy only): queue pressure evicts
+        the decode slot with the most remaining work."""
         if (self.policy == "continuous" and self.preempt_wait is not None
                 and self.waiting
                 and t - self.waiting[0].requeue_tick >= self.preempt_wait
@@ -447,7 +546,10 @@ class SlotMachine(_SlotFrontEnd):
                 self.preemptions += 1
                 self._note_preempt(i, victim, t)
 
-        # -- admission: free slots x FIFO waiting queue ------------------ #
+    def _admit(self, t: int, fresh: np.ndarray) -> List[Tuple[int, int]]:
+        """Admission, free slots x FIFO waiting queue; returns the
+        resume anchors to touch this tick."""
+        anchor_items: List[Tuple[int, int]] = []
         gate_open = (self.policy == "continuous"
                      or not (self.phase != PHASE_FREE).any())
         if gate_open:
@@ -496,91 +598,7 @@ class SlotMachine(_SlotFrontEnd):
                 else:
                     self.phase[i] = PHASE_PREFILL
                     req.state = "prefill"
-        self.peak_live = max(self.peak_live,
-                             int((self.phase != PHASE_FREE).sum()))
-
-        # -- decode mask + window touches (slots live BEFORE this tick) -- #
-        decode_mask = (self.phase == PHASE_DECODE) & ~fresh
-        d_idx = np.flatnonzero(decode_mask)
-        L = self.chain_len[d_idx]
-        rows, pages_idx = _ranges(
-            np.maximum(0, L - self.reread_window).astype(np.int64),
-            L.astype(np.int64))
-        d_reqs = self.slot_req[d_idx]
-        decode_items = list(zip(d_reqs[rows].tolist(), pages_idx.tolist()))
-
-        # -- chunked prefill: one budget, greedy in slot order ----------- #
-        p_idx = np.flatnonzero(self.phase == PHASE_PREFILL)
-        prefill_items: List[Tuple[int, int]] = []
-        if len(p_idx):
-            need = (self.need_prompt[p_idx]
-                    - self.prefill_done[p_idx]).astype(np.int64)
-            excl = np.cumsum(need) - need
-            give = np.clip(self.prefill_tokens - excl, 0, need)
-            old = self.prefill_done[p_idx].astype(np.int64)
-            new = old + give
-            ps = self.page_size
-            rows, pages_idx = _ranges(-(-old // ps), -(-new // ps))
-            p_reqs = self.slot_req[p_idx]
-            prefill_items = list(zip(p_reqs[rows].tolist(),
-                                     pages_idx.tolist()))
-            if self.obs is not None:
-                for k, i in enumerate(p_idx):
-                    if give[k] > 0:
-                        self._note_prefill_chunk(int(i), int(p_reqs[k]),
-                                                 t, int(give[k]))
-            self.prefill_done[p_idx] = new.astype(np.int32)
-            finished = p_idx[new >= self.need_prompt[p_idx]]
-            self.phase[finished] = PHASE_DECODE
-            fresh[finished] = True               # decode starts NEXT tick
-            for i in finished:
-                self.requests[int(self.slot_req[i])].state = "decode"
-
-        # -- the tick's ONE bulk cache call ------------------------------ #
-        items = anchor_items + decode_items + prefill_items
-        if items:
-            self.tier_log.extend(self.pages.touch_batch(items))
-
-        # -- token + MoE bookkeeping ------------------------------------- #
-        if len(d_idx):
-            if self.experts is not None:
-                g = (d_reqs.astype(np.int64) * 7919
-                     + self.gen[d_idx].astype(np.int64) * 104_729) \
-                    % len(self._moe_groups)
-                sets = [self._moe_groups[i] for i in g.tolist()]
-                self.experts.observe_routing(sets)
-                self.experts.activate_batch(sets)
-            first = d_idx[self.gen[d_idx] == 0]
-            for i in first:
-                self.requests[int(self.slot_req[i])].first_tick = t
-            self.gen[d_idx] += 1
-
-        # -- completion: vectorized mask, per-request release ------------ #
-        done_idx = d_idx[self.gen[d_idx] >= self.need_new[d_idx]]
-        for i in done_idx:
-            req = self.requests[int(self.slot_req[i])]
-            req.generated = _stub_tokens(req.req_id, int(self.gen[i]))
-            req.state = "done"
-            req.done_tick = t
-            self.pages.release_request(req.req_id)
-            self._note_complete(int(i), req, t)
-        self.phase[done_idx] = PHASE_FREE
-        self.slot_req[done_idx] = -1
-
-        # -- ages: +1 for surviving occupants, 0 for fresh phases -------- #
-        occ = self.phase != PHASE_FREE
-        self.age[occ & ~fresh] += 1
-        self.age[fresh & occ] = 0
-        self._note_tick()
-        self.now += 1
-        self.ticks += 1
-        out = {"live": int(occ.sum()), "waiting": len(self.waiting),
-               "page_stats": self.pages.stats}
-        if self.tenants is not None:
-            out["tenant_stats"] = self.pages.qos.tenant_stats
-        if self.experts is not None:
-            out["expert_stats"] = self.experts.stats
-        return out
+        return anchor_items
 
 
 class SlotOracle(_SlotFrontEnd):

@@ -19,8 +19,12 @@ Three contracts are pinned here:
     (instant + counter + complete events under ``traceEvents``).
 """
 
+import itertools
 import json
 import sys
+import time
+import tracemalloc
+from contextlib import nullcontext
 from pathlib import Path
 
 import numpy as np
@@ -183,6 +187,194 @@ def test_kernel_wrappers_feed_the_ledger():
 
 
 # --------------------------------------------------------------------------- #
+# program spans                                                               #
+# --------------------------------------------------------------------------- #
+
+def test_span_off_reads_no_clock_and_allocates_nothing(monkeypatch):
+    calls = []
+    monkeypatch.setattr(profile, "_clock", lambda: calls.append(1) or 0.0)
+    profile.clear_spans()
+    assert not profile.recorded()
+    # every call returns the one shared null context: no object is built
+    assert profile.span("x") is profile.span("y", n=3) is profile._NULL
+    tracemalloc.start()
+    try:
+        start, _ = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        for _ in itertools.repeat(None, 10_000):
+            with profile.span("slots.step"):
+                pass
+        now, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert now <= start + 512 and peak <= start + 512   # the loop's own
+    profile.count(n=1)
+    assert calls == [] and profile.spans() == []
+
+
+def test_span_off_serving_keeps_no_records():
+    profile.clear_spans()
+    eng = _drive(SlotMachine, "vec", False, None)
+    assert eng.ticks > 0 and profile.spans() == []
+
+
+def test_recording_nests_counts_and_restores():
+    ticks = iter(range(100))
+    with profile.recording(clock=lambda: float(next(ticks))) as kept:
+        with profile.span("outer", a=1) as outer:
+            with profile.span("inner"):
+                profile.count(b=2)
+            profile.count(c=3)
+        with profile.span("second"):
+            pass
+    assert not profile.recorded()
+    inner, out, second = kept
+    assert (out.name, out.parent, out.start, out.end) == ("outer", None,
+                                                          0.0, 3.0)
+    assert out is outer and out.counts == {"a": 1, "c": 3}
+    assert (inner.parent, inner.start, inner.end) == (out.id, 1.0, 2.0)
+    assert inner.counts == {"b": 2} and second.parent is None
+    assert out.as_dict()["counts"] == {"a": 1, "c": 3}
+    # a sink takes the spans instead of the kept list
+    got = []
+    with profile.recording(sink=got.append) as kept:
+        with profile.span("s"):
+            pass
+    assert kept == [] and [r.name for r in got] == ["s"]
+
+
+def _kernel_machine():
+    m = SlotMachine(kv="vec", max_batch=3, page_size=4, hbm_pages=16,
+                    prefill_tokens=12)
+    m.pages.discover = "kernel"
+    rng = np.random.default_rng(3)
+    shared = list(rng.integers(0, 40, size=8))
+    for i in range(5):
+        m.submit(shared + list(rng.integers(0, 40, size=6 + i)),
+                 max_new_tokens=3)
+    return m
+
+
+def test_spans_nest_inside_the_tick_and_the_rebuild():
+    m = _kernel_machine()
+    before = m.pages.bulk_refreshes
+    with profile.recording() as kept:
+        m.run_until_idle()
+    by_id = {r.id: r for r in kept}
+    steps = [r for r in kept if r.name == "slots.step"]
+    assert [r.counts["tick"] for r in steps] == list(range(m.ticks))
+    assert all(r.parent is None for r in steps)
+    for r in kept:
+        if r.parent is not None:            # every child inside its parent
+            p = by_id[r.parent]
+            assert p.start <= r.start <= r.end <= p.end
+    refreshes = [r for r in kept if r.name == "pages.refresh"]
+    assert len(refreshes) == m.pages.bulk_refreshes - before > 0
+    for ref in refreshes:
+        assert by_id[ref.parent].name == "pages.touch_batch"
+        assert by_id[by_id[ref.parent].parent].name == "slots.step"
+        kids = {r.name for r in kept if r.parent == ref.id}
+        assert {"tables.pack", "kernels.divisibility_scan",
+                "tables.candidates", "kernels.factorize_batch",
+                "tables.rows", "pages.install"} <= kids
+        assert {"pages", "queries", "composites", "pool",
+                "candidates"} <= set(ref.counts)
+    for name in ("divisibility_scan", "factorize_batch"):
+        for k in (r for r in kept if r.name == f"kernels.{name}"):
+            fetch = [r for r in kept if r.parent == k.id]
+            assert [r.name for r in fetch] == [f"kernels.{name}.fetch"]
+            assert k.counts["padded_n"] >= k.counts["n"] > 0
+    admits = [r for r in kept if r.name == "slots.admit"]
+    assert sum(r.counts.get("admitted", 0) for r in steps) == 5
+    assert len(admits) == len(steps)
+    assert {by_id[r.parent].name for r in kept
+            if r.name == "slots.complete"} == {"slots.step"}
+
+
+@pytest.mark.parametrize("kv", ["sharded", "elastic"])
+def test_every_backend_emits_the_rebuild_span(kv):
+    m = SlotMachine(kv=kv, **CFG)
+    before = m.pages.bulk_refreshes
+    with profile.recording() as kept:
+        drive_slots(m, build_poisson_arrivals(GOLDEN_SPEC))
+    refreshes = [r for r in kept if r.name == "pages.refresh"]
+    assert len(refreshes) == m.pages.bulk_refreshes - before > 0
+    ids = {r.id for r in refreshes}
+    assert any(r.name == "kernels.sharded_gcd_exchange.fetch" for r in kept)
+    assert {r.name for r in kept if r.parent in ids} >= {
+        "tables.pack", "kernels.sharded_gcd_exchange", "tables.rows",
+        "pages.install"}
+
+
+def test_kernel_ledger_unchanged_with_spans_on():
+    from repro.kernels.ops import divisibility_scan, factorize_batch, \
+        gcd_batch
+
+    def launches():
+        divisibility_scan([6, 10, 15], [2, 3, 5])
+        factorize_batch([6, 35], [2, 3, 5, 7])
+        gcd_batch([6, 15], [10, 21], [2, 3, 5, 7])
+
+    with profile.profiling():
+        launches()
+        off = profile.summary()
+    with profile.profiling(), profile.recording() as kept:
+        launches()
+        on = profile.summary()
+    strip = lambda led: {k: (v["calls"], v["items"]) for k, v in led.items()}
+    assert strip(on) == strip(off)
+    for name, rec in on.items():
+        span = next(r for r in kept if r.name == f"kernels.{name}")
+        # the ledger's wall is the span's own pair of clock reads
+        assert rec["wall_s"] == span.end - span.start
+        assert span.counts["items"] == rec["items"]
+
+
+class _StubModel:
+    """A model with the decode interface and no weights: logits favour
+    token (position + 1)."""
+
+    vocab = 16
+
+    def init_cache(self, batch, max_seq):
+        import jax.numpy as jnp
+        return {"len": jnp.zeros((batch,), jnp.int32)}
+
+    def decode_step(self, params, batch, cache):
+        import jax
+        import jax.numpy as jnp
+        nxt = (cache["len"] + 1) % self.vocab
+        logits = jax.nn.one_hot(nxt, self.vocab)[:, None, :]
+        return logits, dict(cache, len=cache["len"] + 1)
+
+
+def test_engine_spans_and_stamps_share_the_clock():
+    from repro.serving.engine import ServingEngine
+
+    eng = ServingEngine(_StubModel(), params=None, max_batch=2,
+                        max_seq=32, page_size=4, hbm_pages=32)
+    t0 = time.perf_counter()
+    with profile.recording() as kept:
+        for i in range(3):
+            eng.submit([1, 2, 3 + i], max_new_tokens=3)
+        done = eng.run_until_idle()
+    t1 = time.perf_counter()
+    assert len(done) == 3
+    for r in done:
+        assert t0 <= r.submit_t <= r.first_token_t <= r.done_t <= t1
+    steps = [r for r in kept if r.name == "engine.step"]
+    assert [r.counts["tick"] for r in steps] == list(range(eng.steps))
+    ids = {r.id for r in steps}
+    kids = [r.name for r in kept if r.parent in ids]
+    for name in ("engine.admit", "engine.decode", "engine.fetch",
+                 "pages.touch_batch"):
+        assert kids.count(name) == len(steps), name
+    # the engine's first-token stamp falls inside the step that made it
+    first = min(r.first_token_t for r in done)
+    assert any(s.start <= first <= s.end for s in steps)
+
+
+# --------------------------------------------------------------------------- #
 # inertness: attaching obs never perturbs placement                           #
 # --------------------------------------------------------------------------- #
 
@@ -218,22 +410,37 @@ def _placement_state(eng):
     )
 
 
+# the recorder-on cases are the same sweep with program spans recorded
+SWEEP = ([pytest.param(kv, dedup, False, id=f"{kv}-{dedup}")
+          for kv, dedup in BACKENDS]
+         + [pytest.param(kv, dedup, True, id=f"{kv}-{dedup}-spans")
+            for kv, dedup in BACKENDS])
+
+
 @pytest.mark.parametrize("cls", [SlotMachine, SlotOracle])
-@pytest.mark.parametrize("kv,dedup", BACKENDS)
-def test_tracing_off_parity_sweep(cls, kv, dedup):
+@pytest.mark.parametrize("kv,dedup,spans", SWEEP)
+def test_tracing_off_parity_sweep(cls, kv, dedup, spans):
     """obs=None, a zero-capacity tracer, and a live tracer+telemetry
-    all produce byte-identical placement — the inertness contract."""
+    all produce byte-identical placement — the inertness contract —
+    with the span recorder off and on."""
     base = _placement_state(_drive(cls, kv, dedup, None))
     zero = Observability(trace_capacity=0, telemetry=False)
     live = Observability(trace_capacity=4096)
-    assert _placement_state(_drive(cls, kv, dedup, zero)) == base
-    eng = _drive(cls, kv, dedup, live)
+    with (profile.recording() if spans else nullcontext()) as kept:
+        if spans:
+            assert _placement_state(_drive(cls, kv, dedup, None)) == base
+        assert _placement_state(_drive(cls, kv, dedup, zero)) == base
+        eng = _drive(cls, kv, dedup, live)
     assert _placement_state(eng) == base
     # the live run actually observed something
     assert live.trace.total > 0
     assert live.telemetry.ticks_seen == eng.ticks
     # and the zero-capacity tracer counted the same emissions
     assert zero.trace.total == live.trace.total
+    if spans and cls is SlotMachine:
+        # three drives, one slots.step span per tick of each
+        assert sum(r.name == "slots.step" for r in kept) == 3 * eng.ticks
+    assert not profile.recorded()
 
 
 # --------------------------------------------------------------------------- #
@@ -339,18 +546,22 @@ def test_attach_detach():
 # --------------------------------------------------------------------------- #
 
 def test_export_roundtrip_through_trace_view(tmp_path):
-    eng, obs = _traced(SlotMachine)
+    with profile.recording():
+        eng, obs = _traced(SlotMachine)
+    kept = profile.spans()
     profile.reset()
     with profile.profiling():
         with profile.kernel_scope("fake_kernel", items=7):
             pass
     path = tmp_path / "obs.json"
     obs.export_json(path)
+    profile.clear_spans()
     payload = json.loads(path.read_text())
     assert payload["schema"]["1"] == "admit"
     assert payload["trace"]["total"] == obs.trace.total
     assert payload["telemetry"]["ticks_seen"] == eng.ticks
     assert payload["kernel_launches"]["fake_kernel"]["items"] == 7
+    assert [sp["name"] for sp in payload["spans"]] == [r.name for r in kept]
 
     chrome = convert(payload)
     evs = chrome["traceEvents"]
@@ -364,11 +575,30 @@ def test_export_roundtrip_through_trace_view(tmp_path):
     # counter events carry their gauge value under args[name]
     ctr = next(e for e in evs if e["ph"] == "C")
     assert ctr["args"][ctr["name"]] is not None
-    # kernel spans are laid end to end on pid 1
+    # spans are drawn at their recorded times, microseconds from the first
+    t0 = min(r.start for r in kept)
     spans = [e for e in evs if e["ph"] == "X"]
-    assert spans and all(e["pid"] == 1 and e["dur"] >= 1 for e in spans)
+    assert len(spans) == len(kept)
+    for e, r in zip(spans, kept):
+        assert e["pid"] == 1 and e["name"] == r.name
+        assert e["ts"] == pytest.approx((r.start - t0) * 1e6)
+        assert e["dur"] == pytest.approx((r.end - r.start) * 1e6)
+    # ring events sit at the start of their tick's slots.step span
+    step_at = {e["args"]["tick"]: e["ts"] for e in spans
+               if e["name"] == "slots.step"}
+    for e in inst:
+        assert e["ts"] == step_at[e["args"]["tick"]]
+    assert ctr["ts"] in step_at.values()
     # the whole thing serializes (what chrome://tracing loads)
     json.dumps(chrome)
+
+
+def test_trace_view_without_spans_lays_ticks_1ms_apart():
+    _, obs = _traced(SlotOracle)
+    chrome = convert(obs.export())
+    inst = [e for e in chrome["traceEvents"] if e["ph"] == "i"]
+    assert inst and all(e["ts"] == e["args"]["tick"] * 1000 for e in inst)
+    assert not [e for e in chrome["traceEvents"] if e["ph"] == "X"]
 
 
 def test_trace_view_cli(tmp_path, capsys):

@@ -2,19 +2,21 @@
 """Convert an ``Observability.export_json`` payload into Chrome
 ``trace_event`` JSON (load it in chrome://tracing or https://ui.perfetto.dev).
 
-Mapping (one synthetic microsecond timeline; 1 engine tick = 1 ms so
-tick-granular serving traces stay readable next to wall-clock kernel
-spans):
+Mapping, in microseconds from the first recorded span:
 
+  * program spans     -> complete events (``ph: "X"``) on pid 1
+                         ("program") at their recorded start and end,
+                         nested by time; their counts are the args
   * trace ring rows   -> instant events (``ph: "i"``) on pid 0
-                         ("serving"); slot-scoped events land on
-                         ``tid = slot``, cache/shard events on a shared
-                         "cache" track
+                         ("serving") at the start of their tick's
+                         ``slots.step`` / ``engine.step`` span;
+                         slot-scoped events land on ``tid = slot``,
+                         cache/shard events on a shared "cache" track
   * telemetry gauges  -> counter events (``ph: "C"``) keyed by gauge
-                         name at their recorded tick
-  * kernel ledger     -> complete events (``ph: "X"``) on pid 1
-                         ("kernels"), laid end to end with their
-                         accumulated wall clocks
+                         name at their tick's time
+
+A payload without spans (the recorder was off) has no clock: its ticks
+are laid out 1 ms apart instead.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --trace obs.json
@@ -23,11 +25,14 @@ Usage:
 
 from __future__ import annotations
 
+import bisect
 import json
 import sys
+from typing import Callable, Dict, List
 
-TICK_US = 1000          # one serving tick rendered as 1 ms
+TICK_US = 1000          # one serving tick as 1 ms, for payloads with no spans
 CACHE_TID = 99          # track for events with no slot attribution
+TICK_SPANS = ("slots.step", "engine.step")
 
 _META = [
     {"ph": "M", "pid": 0, "name": "process_name",
@@ -35,14 +40,47 @@ _META = [
     {"ph": "M", "pid": 0, "tid": CACHE_TID, "name": "thread_name",
      "args": {"name": "cache"}},
     {"ph": "M", "pid": 1, "name": "process_name",
-     "args": {"name": "kernels"}},
+     "args": {"name": "program"}},
 ]
+
+
+def _tick_clock(spans: List[dict], t0: float) -> Callable[[int], float]:
+    """tick -> microseconds: the start of the tick's step span, else of
+    the latest step span before it (the first one for earlier ticks)."""
+    starts: Dict[int, float] = {}
+    for sp in spans:
+        tick = sp.get("counts", {}).get("tick")
+        if sp["name"] in TICK_SPANS and tick is not None:
+            starts.setdefault(int(tick), (sp["start"] - t0) * 1e6)
+    if not starts:
+        return lambda tick: max(int(tick), 0) * TICK_US
+    ticks = sorted(starts)
+
+    def at(tick: int) -> float:
+        i = bisect.bisect_right(ticks, tick) - 1
+        return starts[ticks[max(i, 0)]]
+
+    return at
 
 
 def convert(payload: dict) -> dict:
     """Observability export dict -> Chrome trace_event dict."""
     schema = {int(k): v for k, v in payload.get("schema", {}).items()}
+    spans = payload.get("spans") or []
+    t0 = min((sp["start"] for sp in spans), default=0.0)
+    tick_us = _tick_clock(spans, t0)
     out = list(_META)
+
+    # -- program spans -> complete events --------------------------------- #
+    for sp in spans:
+        out.append({
+            "name": sp["name"], "ph": "X",
+            "ts": (sp["start"] - t0) * 1e6,
+            "dur": (sp["end"] - sp["start"]) * 1e6,
+            "pid": 1, "tid": 0,
+            "args": dict(sp.get("counts", {}), id=sp["id"],
+                         parent=sp["parent"]),
+        })
 
     # -- trace ring rows -> instant events -------------------------------- #
     trace = payload.get("trace") or {}
@@ -58,10 +96,11 @@ def convert(payload: dict) -> dict:
         args = {k: v for k, v in ev.items()
                 if k not in ("kind", "tick", "slot") and v != -1}
         args["seq"] = seq
+        args["tick"] = now
         out.append({
             "name": schema.get(ev.get("kind"), f"kind{ev.get('kind')}"),
             "ph": "i", "s": "t",
-            "ts": now * TICK_US,
+            "ts": tick_us(now),
             "pid": 0,
             "tid": slot if slot >= 0 else CACHE_TID,
             "args": args,
@@ -73,24 +112,10 @@ def convert(payload: dict) -> dict:
         for tick, value in ring:
             out.append({
                 "name": name, "ph": "C",
-                "ts": max(int(tick), 0) * TICK_US,
+                "ts": tick_us(int(tick)),
                 "pid": 0,
                 "args": {name: value},
             })
-
-    # -- kernel launch ledger -> complete spans --------------------------- #
-    cursor = 0
-    for name, rec in sorted((payload.get("kernel_launches") or {}).items()):
-        dur = max(int(rec.get("wall_s", 0.0) * 1e6), 1)
-        out.append({
-            "name": name, "ph": "X",
-            "ts": cursor, "dur": dur,
-            "pid": 1, "tid": 0,
-            "args": {"calls": rec.get("calls", 0),
-                     "items": rec.get("items", 0),
-                     "wall_s": rec.get("wall_s", 0.0)},
-        })
-        cursor += dur
 
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
