@@ -15,23 +15,33 @@ int32 arithmetic:
 
     Horner remainder   r = (r * 2**6 + digit) % p      r < p
                        => r * 2**6 + digit < p * 2**6 <= 2**31
-    short division     cur = carry * 2**6 + digit;  q = cur // p
-                       carry < p  => the same bound; q < 2**6
+    short division     cur = carry * 2**6 + digit;  q = cur // d
+                       d <= p, carry < d  => the same bound; q < 2**6
 
 Layout of one (BP primes) x (BN composites) grid cell:
     digits tile      (L, BN)  int32  VMEM
     primes tile      (BP, 1)  int32  VMEM — broadcast along lanes
-    primes scalars   (1, BP)  int32  SMEM — read one at a time
     mask out tile    (BP, BN) bool   — mask[j, i] = p_j | c_i
     residual out     (L, BN)  int32  — c_i // prod(dividing p_j)
+    take scratch     (BP, BN) int32  — the mask, read by every round
 
 Grid: (N/BN, P/BP).  The prime axis (j) is the innermost, sequentially
 executed grid dimension, so the residual tile accumulates the running
 cofactor across prime tiles: the composite at j == 0, then divided by
 every dividing prime as tiles stream through (the matmul K-loop
-accumulator pattern).  Inside a tile the division walks the primes one
-at a time with a scalar read from SMEM and a dynamic sublane read of its
-mask row — no dynamic lane indexing, no cross-lane reduction.
+accumulator pattern).
+
+Inside a tile the division runs in rounds that follow the hits, not the
+pool.  Each round divides every lane by a divisor ``d`` of its own, a
+``(1, BN)`` row: the prime of the lane's highest dividing mask row below
+the one it took last (a masked max of a row-index iota over sublanes,
+then a masked max of the primes), or 1 once the lane has none left,
+which leaves its digits as they are.  Rows are picked by index, so a
+prime repeated in the pool divides once per row, as the oracle does.
+The tile runs as many rounds as its most-divided lane has dividing
+primes, a count reduced from the mask: none for a tile nothing divides,
+one or two for the two-prime composites of chain edges, never more than
+BP — where the walk over every prime ran BP.  No dynamic lane indexing.
 """
 
 from __future__ import annotations
@@ -72,7 +82,7 @@ def _divmask_kernel(c_ref, p_ref, mask_ref):
     mask_ref[...] = _divides(c_ref, p_ref[...])
 
 
-def _factorize_kernel(c_ref, p_ref, ps_ref, mask_ref, res_ref, take_ref):
+def _factorize_kernel(c_ref, p_ref, mask_ref, res_ref, take_ref):
     j = pl.program_id(1)
     L = c_ref.shape[0]
     divides = _divides(c_ref, p_ref[...])
@@ -83,24 +93,34 @@ def _factorize_kernel(c_ref, p_ref, ps_ref, mask_ref, res_ref, take_ref):
     def _init():
         res_ref[...] = c_ref[...]
 
-    # divide the residual by every dividing prime of this tile, one
-    # prime at a time: floor(floor(c / a) / b) == floor(c / (a * b)), so
-    # the result equals c // prod(dividing primes) exactly
-    def per_prime(jj, _):
-        p = jnp.maximum(ps_ref[0, jj], 1)
-        take = take_ref[pl.ds(jj, 1), :] != 0             # (1, BN)
+    # divide the residual by every dividing prime of this tile, one per
+    # lane a round: floor(floor(c / a) / b) == floor(c / (a * b)), so the
+    # result equals c // prod(dividing primes) exactly.  A round takes, in
+    # each lane, the highest dividing row below the one it took last; the
+    # tile runs as many rounds as its most-divided lane has hits.
+    rows = lax.broadcasted_iota(jnp.int32, take_ref.shape, 0)
+    rounds = jnp.max(jnp.sum(take_ref[...], axis=0))
+
+    def per_round(_, last):                 # (1, BN): each lane's last row
+        left = jnp.logical_and(take_ref[...] != 0, rows < last)
+        row = jnp.max(jnp.where(left, rows, -1), axis=0, keepdims=True)
+        # the row's prime, 1 in a lane with none left: divides by nothing
+        d = jnp.max(jnp.where(rows == row, p_ref[...], 1), axis=0,
+                    keepdims=True)
 
         def step(k, carry):
-            row = pl.ds(L - 1 - k, 1)
-            cur = carry * _BASE + res_ref[row, :]
-            q = cur // p
-            res_ref[row, :] = jnp.where(take, q, res_ref[row, :])
-            return cur - q * p
+            at = pl.ds(L - 1 - k, 1)
+            cur = carry * _BASE + res_ref[at, :]
+            q = cur // d
+            res_ref[at, :] = q
+            return cur - q * d
 
-        lax.fori_loop(0, L, step, jnp.zeros(take.shape, jnp.int32))
-        return 0
+        lax.fori_loop(0, L, step, jnp.zeros(row.shape, jnp.int32))
+        return row
 
-    lax.fori_loop(0, ps_ref.shape[1], per_prime, 0)
+    lax.fori_loop(0, rounds, per_round,
+                  jnp.full((1, take_ref.shape[1]), take_ref.shape[0],
+                           jnp.int32))
 
 
 def _specs(L: int, block_n: int, block_p: int):
@@ -166,11 +186,11 @@ def factorize_squarefree_pallas(
     oracle."""
     n, p = _check(digits, primes, block_n, block_p)
     L = digits.shape[1]
-    dspec, pspec, sspec, mspec = _specs(L, block_n, block_p)
+    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
     mask, residual = pl.pallas_call(
         _factorize_kernel,
         grid=(n // block_n, p // block_p),
-        in_specs=[dspec, pspec, sspec],
+        in_specs=[dspec, pspec],
         out_specs=[mspec, dspec],
         out_shape=[jax.ShapeDtypeStruct((p, n), jnp.bool_),
                    jax.ShapeDtypeStruct((L, n), jnp.int32)],
@@ -179,5 +199,5 @@ def factorize_squarefree_pallas(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="factorize_squarefree",
-    )(digits.T, primes.reshape(p, 1), primes.reshape(p // block_p, 1, block_p))
+    )(digits.T, primes.reshape(p, 1))
     return mask.T, residual.T

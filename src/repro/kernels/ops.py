@@ -177,6 +177,9 @@ def factorize_batch(
         mask, residual = mask[:n, :p], residual[:n]
         factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]]
                    for i in range(n)]
+        # dividing (composite, prime) pairs, which the kernel's division
+        # rounds follow
+        profile.count(hits=sum(map(len, factors)))
         return factors, unpack_limbs(residual)
 
 

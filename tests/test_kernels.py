@@ -71,6 +71,70 @@ def test_factorize_kernel_matches_ref(n, p, bn, bp, dtype):
     assert (np.asarray(res) == rref).all()
 
 
+def _edge_case(name):
+    """``(values, pool, bits, block_n, block_p)`` for one case of the
+    division rounds: how many dividing primes a lane has in a tile, and
+    where."""
+    small = [int(q) for q in PRIMES_SMALL]
+    wide = [int(q) for q in PRIMES_WIDE]
+    first15 = math.prod(small[:15])                 # 2 * 3 * ... * 47
+    if name == "many_primes_narrow":
+        vals = [first15, 6, first15 // 2, 1] + [1] * 252
+        return vals, _pad(PRIMES_SMALL.astype(np.int32), 512, 0), 62, 256, 512
+    if name == "many_primes_wide":
+        vals = [math.prod(wide), math.prod(wide[::2]), wide[0] * wide[-1],
+                math.prod(wide[1:]) * 7] + [1] * 252
+        return vals, _pad(PRIMES_WIDE.astype(np.int32), 512, 0), 1024, \
+            256, 512
+    if name == "squared_factor":
+        vals = [4 * 3, 9 * 5 * 7, 2**5 * 3, 47**2 * 43, 113**2,
+                wide[0]**2 * 5] + [1] * 250
+        pool = np.concatenate([PRIMES_SMALL, [wide[0]]]).astype(np.int32)
+        return vals, _pad(pool, 512, 0), 62, 256, 512
+    if name == "no_hits":
+        # the second composite tile and the second prime tile hit nothing
+        vals = ([127 * 131, 6, 1, 137 * 139 * 149] + [1] * 252
+                + [127 * 131 * 151] * 256)
+        pool = np.concatenate([PRIMES_SMALL.astype(np.int32),
+                               np.zeros(128 - 30, np.int32),
+                               np.asarray([157, 163, 167], np.int32),
+                               np.zeros(128 - 3, np.int32)])
+        return vals, pool, 62, 256, 128
+    if name == "hits_across_prime_tiles":
+        # primes 17 lanes apart, zeros between: four prime tiles of 128
+        pool = np.zeros(512, np.int32)
+        pool[:30 * 17:17] = PRIMES_SMALL
+        vals = [first15, math.prod(small[::7]), 2 * 113, 61 * 107] \
+            + [math.prod(small[k:k + 8]) for k in range(0, 22)] + [1] * 230
+        return vals, pool, 62, 128, 128
+    if name == "pad_rows_and_zero_primes":
+        vals = [6, 35, 0, 1, 143] + [1] * 251
+        pool = np.zeros(512, np.int32)
+        pool[[3, 40, 300, 511]] = [2, 5, 7, 11]
+        return vals, pool, 62, 256, 512
+    if name == "repeated_pool_prime":
+        # selected by row, not by value: a repeated prime divides twice
+        vals = [9 * 5, 3 * 7, 5, 27] + [1] * 252
+        return vals, _pad(np.asarray([3, 5, 3, 7], np.int32), 512, 0), \
+            62, 256, 512
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "many_primes_narrow", "many_primes_wide", "squared_factor", "no_hits",
+    "hits_across_prime_tiles", "pad_rows_and_zero_primes",
+    "repeated_pool_prime"])
+def test_factorize_kernel_edge_cases_match_ref(case):
+    vals, pool, bits, bn, bp = _edge_case(case)
+    digits = pack_limbs(vals, n_limbs_for_bits(bits))
+    mask, res = factorize_squarefree_pallas(
+        jnp.asarray(digits), jnp.asarray(pool), block_n=bn, block_p=bp,
+        interpret=True)
+    mref, rref = factorize_squarefree_ref(digits, pool)
+    assert (np.asarray(mask) == mref).all()
+    assert (np.asarray(res) == rref).all()
+
+
 @pytest.mark.parametrize("dtype", [np.int32, np.int64])
 def test_divmask_kernel_matches_ref(dtype):
     rng = np.random.default_rng(0)

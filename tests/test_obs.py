@@ -330,6 +330,16 @@ def test_kernel_ledger_unchanged_with_spans_on():
         assert span.counts["items"] == rec["items"]
 
 
+def test_factorize_hits_counts_the_factors_returned():
+    from repro.kernels.ops import factorize_batch
+
+    with profile.recording() as kept:
+        facs, _ = factorize_batch([6, 35, 143, 101, 2 * 3 * 5 * 7, 1],
+                                  [2, 3, 5, 7, 11, 13])
+    span = next(r for r in kept if r.name == "kernels.factorize_batch")
+    assert span.counts["hits"] == sum(map(len, facs)) == 10
+
+
 class _StubModel:
     """A model with the decode interface and no weights: logits favour
     token (position + 1)."""
