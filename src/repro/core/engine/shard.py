@@ -25,17 +25,15 @@ the global registry (registration) order — the candidate-order contract
 the serving cache's parity tests pin down.
 
 **Per-shard bulk discovery under shard_map.**  Successor rows are
-rebuilt per shard through the SAME Pallas kernels the single-device
-path uses (``divisibility_mask_pallas`` for the §4.2 scan), mapped over
-the mesh with ``shard_map``: every shard scans its own registry slice
-against its own query primes.  Cross-shard relationships are resolved
-by a **collective batched-gcd exchange**: each shard contributes its
-slice of the cross-shard composites, ``lax.all_gather`` replicates them
-along the mesh, and each shard computes ``gcd_squarefree_pallas`` of
-its *query chunk products* (its owned query primes packed into
-registry-width composites) against every gathered composite.  A gcd > 1 decodes — exactly, by
-unique factorization — to the member primes the shard owns, so no
-per-query modulo scan ever crosses shard boundaries.
+rebuilt per shard through the SAME Pallas kernel the single-device path
+uses (``divisibility_mask_pallas`` for the §4.2 scan), mapped over the
+mesh with ``shard_map``.  Cross-shard relationships are resolved by a
+**collective exchange**: each shard contributes its slice of the
+cross-shard composites, ``lax.all_gather`` replicates them along the
+mesh, and each shard runs ONE divisibility scan of its local rows plus
+every gathered cross row against its own query primes.  A cross
+composite's hits in a shard's mask are exactly the member primes that
+shard owns, so no query ever leaves its owner shard.
 
 When the host exposes fewer devices than shards (the common laptop
 case), the same math runs as a per-shard host loop over the identical
@@ -56,7 +54,6 @@ from repro.obs import profile
 from repro.obs.profile import kernel_scope
 from repro.sharding.stripes import BlockStripes
 
-from ..composite import encode_relationship
 from ..primes import CacheLevel, LEVEL_PRIME_RANGES
 
 __all__ = ["PrimeSpacePartition", "shard_mesh", "sharded_successor_table",
@@ -182,82 +179,75 @@ def _pad_digit_stack(rows: Sequence[np.ndarray], mult: int, L: int,
 
 @dataclass
 class ShardScanReport:
-    """Per-refresh work split (benchmark / introspection output)."""
+    """Per-refresh work split (benchmark / introspection output).
+    ``cross_rows`` is the gathered cross slice every shard scans after
+    its local rows (padded, ``n_shards`` times the per-shard width)."""
 
     n_shards: int = 0
     used_shard_map: bool = False
     local_composites: List[int] = field(default_factory=list)
     cross_composites: int = 0
     queries_per_shard: List[int] = field(default_factory=list)
-    gcd_pairs: int = 0
+    cross_rows: int = 0
 
 
-def _one_shard_scan(lc, qs, ck, pool, gathered_cross, *, n_chunks: int,
-                    interpret: bool):
-    """One shard's kernel work: local divisibility mask + cross gcds.
+#: the divisibility kernel's row tile; a shard's local plus gathered rows
+#: are padded to a multiple of it
+_ROW_BLOCK = 256
 
-    ``lc`` (C, L) local digit rows, ``qs`` (Q,) owned query primes,
-    ``ck`` (K, L) query chunk products, ``gathered_cross`` (X, L) every
-    shard's cross composites.  The gcd pool is the shard's own
-    (deduplicated, zero-padded) query primes — chunk products are
-    products of exactly those primes, so the pool covers every possible
-    common factor.
-    """
+
+def _one_shard_scan(lc, qs, gathered, *, interpret: bool):
+    """One shard's kernel work: the ``(C + X, Q)`` divisibility mask of
+    its local rows ``lc`` (C, L), then every shard's gathered cross rows
+    ``gathered`` (X, L), against its owned query primes ``qs`` (Q,).
+    Pad rows encode the value 1 and match nothing."""
     import jax.numpy as jnp
 
     from repro.kernels.factorize import divisibility_mask_pallas
-    from repro.kernels.gcd import gcd_squarefree_pallas
 
-    gcd_block = 256
-    mask = divisibility_mask_pallas(lc, qs, interpret=interpret)
-    # batched-gcd exchange: every query chunk x every cross composite
-    x, L = gathered_cross.shape
-    a = jnp.repeat(ck, x, axis=0)                       # (K*X, L)
-    b = jnp.tile(gathered_cross, (n_chunks, 1))
-    pad = (-a.shape[0]) % gcd_block
-    one = jnp.broadcast_to((jnp.arange(L) == 0).astype(a.dtype), (pad, L))
-    a = jnp.concatenate([a, one])
-    b = jnp.concatenate([b, one])
-    g = gcd_squarefree_pallas(a, b, pool, block_n=gcd_block,
-                              interpret=interpret)
-    return mask, g[:n_chunks * x].reshape(n_chunks, x, L)
+    rows = jnp.concatenate([lc, gathered])
+    pad = (-rows.shape[0]) % _ROW_BLOCK
+    if pad:
+        one = (jnp.arange(rows.shape[1]) == 0).astype(rows.dtype)
+        rows = jnp.concatenate(
+            [rows, jnp.broadcast_to(one, (pad, rows.shape[1]))])
+    return divisibility_mask_pallas(rows, qs, block_n=_ROW_BLOCK,
+                                    interpret=interpret)
 
 
-@functools.lru_cache(maxsize=64)
-def _shard_map_scan(mesh, shapes: Tuple[int, ...], interpret: bool):
-    """Compiled shard_map scan, memoized per (mesh, bucketed shapes)."""
+@functools.lru_cache(maxsize=16)
+def _shard_map_scan(mesh, interpret: bool):
+    """The shard_map scan over ``mesh``, memoized per mesh; ``jit`` keeps
+    one executable per bucketed input shape."""
     import jax
 
     from repro.sharding.partition import shard_stack_spec
 
     axes = tuple(mesh.axis_names)
     spec = shard_stack_spec(mesh)       # leading shard axis over data x model
-    _, _, K, _, _ = shapes
 
-    def body(lc, qs, ck, pool, xc):
+    def body(lc, qs, xc):
         gathered = jax.lax.all_gather(xc[0], axes, tiled=True)
-        mask, g = _one_shard_scan(lc[0], qs[0], ck[0], pool[0], gathered,
-                                  n_chunks=K, interpret=interpret)
-        return mask[None], g[None]
+        return _one_shard_scan(lc[0], qs[0], gathered,
+                               interpret=interpret)[None]
 
-    return jax.jit(jax.shard_map(body, mesh=mesh,
-                                 in_specs=(spec,) * 5,
-                                 out_specs=(spec, spec), check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False))
 
 
 def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
-                  chunks: np.ndarray, pools: np.ndarray,
-                  cross_c: np.ndarray,
-                  mesh) -> Tuple[np.ndarray, np.ndarray]:
-    """The per-shard kernel work: local divisibility masks + cross gcds.
+                  cross_c: np.ndarray, mesh, **counts) -> np.ndarray:
+    """The per-shard kernel work: one divisibility mask a shard.
 
     Inputs are padded stacks: (S, C, L) local digit rows, (S, Q) query
-    primes, (S, K, L) query-chunk digits, (S, P) gcd pools and (S, X, L)
-    cross slices; returns ``(local_mask (S, C, Q), gcd digits (S, K, X,
-    L))``.  With a mesh of exactly S devices the work runs under
-    ``shard_map`` (one shard per device, cross composites replicated by
-    ``lax.all_gather`` — the collective exchange); otherwise a host loop
-    runs the identical kernels per shard.
+    primes and (S, X/S, L) cross slices; returns the (S, R, Q) masks, R
+    being C + X rounded up to the kernel's row tile, rows ``[0, C)``
+    local and ``[C, C + X)`` the gathered cross rows in slice order.
+    With a mesh of exactly S devices the work runs under ``shard_map``
+    (one shard per device, the cross slices replicated by
+    ``lax.all_gather`` — the collective exchange); otherwise a host
+    loop runs the identical kernel per shard.  ``counts`` go to the
+    span.
     """
     import jax.numpy as jnp
 
@@ -266,30 +256,22 @@ def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
     interpret = interpret_mode()
     check_32bit()
     S, C, L = local_c.shape
-    Q = queries.shape[1]
-    K = chunks.shape[1]
-
-    with kernel_scope("sharded_gcd_exchange", items=S * C, shards=S,
-                      c=C, q=Q, chunks=K):
+    X = S * cross_c.shape[1]
+    with kernel_scope("sharded_exchange", items=S * (C + X), shards=S,
+                      c=C, q=queries.shape[1], **counts):
         if mesh is not None and mesh.size == S:
-            fn = _shard_map_scan(
-                mesh, (C, Q, K, pools.shape[1], cross_c.shape[1]), interpret)
-            mask, g = fn(jnp.asarray(local_c), jnp.asarray(queries),
-                         jnp.asarray(chunks), jnp.asarray(pools),
-                         jnp.asarray(cross_c))
-        else:                           # host loop, same kernels, same math
+            mask = _shard_map_scan(mesh, interpret)(
+                jnp.asarray(local_c), jnp.asarray(queries),
+                jnp.asarray(cross_c))
+        else:                           # host loop, same kernel, same math
             gathered = jnp.asarray(cross_c.reshape(-1, L))
-            masks, gs = [], []
-            for s in range(S):
-                m, g = _one_shard_scan(
-                    jnp.asarray(local_c[s]), jnp.asarray(queries[s]),
-                    jnp.asarray(chunks[s]), jnp.asarray(pools[s]), gathered,
-                    n_chunks=K, interpret=interpret)
-                masks.append(m)
-                gs.append(g)
-            mask, g = jnp.stack(masks), jnp.stack(gs)
-        with profile.span("kernels.sharded_gcd_exchange.fetch"):
-            return np.asarray(mask), np.asarray(g)
+            mask = jnp.stack([
+                _one_shard_scan(jnp.asarray(local_c[s]),
+                                jnp.asarray(queries[s]), gathered,
+                                interpret=interpret)
+                for s in range(S)])
+        with profile.span("kernels.sharded_exchange.fetch"):
+            return np.asarray(mask)
 
 
 def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
@@ -304,9 +286,9 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     Produces BIT-IDENTICAL rows (same candidates, same order — global
     registry order, deduplicated by relationship, expanded in
     ``rel.primes`` order) while splitting the scan work by prime
-    ownership: each shard's Pallas divisibility scan touches only its
-    local registry slice, and only cross-shard relationships ride the
-    collective gcd exchange.
+    ownership: each shard's Pallas divisibility scan covers its local
+    registry slice and the gathered cross-shard slice, against only the
+    query primes it owns.
 
     ``precomputed`` optionally supplies the ``(local_pos, cross_pos)``
     registry split (e.g. the maintained
@@ -316,9 +298,7 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
     primes yields identical rows — a prime's hits can only come from the
     chunk containing it.
     """
-    from repro.kernels.ops import factorize_batch, prime_lanes
-
-    from ..composite import pack_limbs, unpack_limbs
+    from repro.kernels.ops import prime_lanes
 
     S = partition.n_shards
     keyed = [(int(d), p) for d in data_ids
@@ -340,14 +320,6 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
 
         queries = _pad_rows([prime_lanes([p for _, p in sh])
                              for sh in by_shard], 512, 0)
-        # query chunk products: each shard's owned query primes packed into
-        # registry-width composites — the gcd exchange payload (one wide
-        # chunk usually covers the whole shard's query set)
-        chunk_vals: List[List[int]] = []
-        for sh in by_shard:
-            ps = {p for _, p in sh}
-            chunk_vals.append(encode_relationship(ps, registry.max_bits)
-                              if ps else [])
         # per-shard cross-slice width bucketed to powers of two, like every
         # other stack: an exact ceil(cross/S) width would change the compiled
         # shard_map shape on nearly every registry growth
@@ -362,12 +334,6 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
             [digits[np.asarray(pos, dtype=np.int64)]
              if pos else np.empty((0, L), np.int32)
              for pos in local_pos], 256, L)
-        chunks = _pad_digit_stack([pack_limbs(cv, L) for cv in chunk_vals],
-                                  1, L)
-        # the gcd-reconstruction pool: each shard's deduplicated query primes
-        # (zero-padded) — exactly the primes its chunks contain
-        pools = _pad_rows([prime_lanes(sorted({p for _, p in sh}))
-                           for sh in by_shard], 512, 0)
         cross_digits = (digits[np.asarray(cross_pos, dtype=np.int64)]
                         if cross_pos else np.empty((0, L), np.int32))
         cross_sh = _pad_digit_stack(
@@ -375,55 +341,44 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
             1, L, width=per)
 
     # ---- kernel work (shard_map when the mesh matches) ------------------ #
-    mask, gcds = _scan_sharded(local_c, queries, chunks, pools, cross_sh,
-                               mesh)
+    n_local = [len(p) for p in local_pos]
+    mask = _scan_sharded(local_c, queries, cross_sh, mesh,
+                         cross=len(cross_pos), gathered=S * per,
+                         local_max=max(n_local), local_min=min(n_local),
+                         q_max=max(len(sh) for sh in by_shard))
 
     if report is not None:
         report.n_shards = S
         report.used_shard_map = mesh is not None and mesh.size == S
-        report.local_composites = [len(p) for p in local_pos]
+        report.local_composites = n_local
         report.cross_composites = len(cross_pos)
         report.queries_per_shard = [len(sh) for sh in by_shard]
-        report.gcd_pairs = int(chunks.shape[1] * S * per)
+        report.cross_rows = S * per
 
     with profile.span("tables.candidates"):
-        # ---- decode the gcd exchange: which cross composites contain which
-        # owned query primes (exact — unique factorization) --------------- #
-        cross_of_prime: Dict[int, List[int]] = {}
+        # ---- each owned query's hits, as registry positions: local rows
+        # first, then the gathered cross rows (cross index == gathered
+        # row, the slices being consecutive); padded queries hit nothing
+        C, Q = local_c.shape[1], mask.shape[2]
+        hits_of: List[List[np.ndarray]] = []
         for s in range(S):
-            if not by_shard[s] or not cross_pos:
-                continue
-            pool = sorted({p for _, p in by_shard[s]})
-            gs = gcds[s]                        # (K, X, L) digit rows
-            # value > 1 iff digit 0 > 1 or any higher digit nonzero
-            hit_k, hit_x = np.nonzero((gs[..., 0] > 1)
-                                      | (gs[..., 1:] != 0).any(axis=-1))
-            valid = hit_x < len(cross_pos)      # drop padding columns
-            hit_k, hit_x = hit_k[valid], hit_x[valid]
-            hit_vals = unpack_limbs(gs[hit_k, hit_x])
-            uniq = sorted(set(hit_vals))
-            if not uniq:
-                continue
-            facs, residual = factorize_batch(uniq, pool)
-            assert all(r == 1 for r in residual), \
-                "gcd escaped the shard's query pool"
-            fac_of = {g: fs for g, fs in zip(uniq, facs)}
-            for x, v in zip(hit_x, hit_vals):
-                for q in fac_of[v]:
-                    cross_of_prime.setdefault(int(q), []).append(int(x))
+            row_pos = np.full(mask.shape[1], -1, np.int64)
+            row_pos[:n_local[s]] = local_pos[s]
+            row_pos[C:C + len(cross_pos)] = cross_pos
+            row, col = np.divmod(np.flatnonzero(mask[s]), Q)
+            pos = row_pos[row]
+            order = np.lexsort((pos, col))              # by query, then pos
+            hits_of.append(np.split(pos[order], np.searchsorted(
+                col[order], np.arange(1, len(by_shard[s])))))
 
     with profile.span("tables.rows"):
         # ---- assemble rows in the oracle's exact order ------------------ #
         out: Dict[int, List[int]] = {}
         for s in range(S):
-            pos_map = local_pos[s]
-            for col, (d, p) in enumerate(by_shard[s]):
-                hits = [pos_map[i] for i in
-                        np.nonzero(mask[s, :len(pos_map), col])[0]]
-                hits.extend(cross_pos[x] for x in cross_of_prime.get(p, ()))
+            for (d, p), hits in zip(by_shard[s], hits_of[s]):
                 row: List[int] = []
                 seen: set = set()
-                for pos in sorted(hits):        # ascending == registry order
+                for pos in hits:                # ascending == registry order
                     rel = registry.relationship_of_composite(int(arr[pos]))
                     if rel is None or rel.rel_id in seen:
                         continue

@@ -17,7 +17,7 @@ carries it on the device's clock.  :func:`count` adds counts to the
 innermost open span, for numbers known only part-way through it.
 
 **Kernel scopes.**  Every Pallas launch wrapper in ``repro.kernels.ops``
-(and the shard_map gcd exchange in ``repro.core.engine.shard``) runs its
+(and the shard_map exchange in ``repro.core.engine.shard``) runs its
 body under :func:`kernel_scope`, which
 
   * always annotates the region with ``jax.named_scope`` — a metadata

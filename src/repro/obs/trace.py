@@ -4,7 +4,7 @@
 Every typed runtime event — slot admission, chunked-prefill step,
 preemption, resume-prefetch, completion, eviction (victim + tenant),
 prefetch issue, dedup hit / promotion / COW divergence, shared-page
-age-out, shard gcd-exchange, recovery refactorization — is one row of
+age-out, shard exchange, recovery refactorization — is one row of
 eight ``int32`` lanes in a preallocated ring:
 
     (kind, tick, slot, req, page, tenant, shard, arg)
@@ -56,7 +56,7 @@ EV_DEDUP_HIT = 8        #: admission hit an existing shared page (page)
 EV_DEDUP_PROMOTE = 9    #: private content promoted to a shared page
 EV_COW = 10             #: copy-on-write divergence (page=fresh private)
 EV_AGE_OUT = 11         #: zero-ref shared page aged out, prime recycled
-EV_GCD_EXCHANGE = 12    #: sharded collective gcd exchange (shard, arg)
+EV_GCD_EXCHANGE = 12    #: a shard's sharded table rebuild (shard, arg)
 EV_RECOVERY = 13        #: shard recovery refactorization (shard, arg)
 
 EVENT_NAMES = {

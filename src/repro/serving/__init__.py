@@ -14,7 +14,7 @@ Public entry points (documented with runnable examples in docs/api.md):
     table-driven chain discovery (DESIGN.md §5, the serving hot path)
   * :class:`ShardedPagedKVCache`    — mesh-partitioned PFCS state:
     per-shard prime ranges, registry slices, and ``shard_map`` bulk
-    discovery with a cross-shard gcd exchange (DESIGN.md §6)
+    discovery with a cross-shard all-gather exchange (DESIGN.md §6)
   * :class:`ServingEngine`          — continuous-batching engine over
     any of the caches; :meth:`ServingEngine.submit` /
     :meth:`ServingEngine.step` drive the request lifecycle

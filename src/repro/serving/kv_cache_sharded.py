@@ -11,16 +11,17 @@ bulk-discovery work — across a ``("data", "model")`` device mesh
     prime-value blocks striped round-robin).  A chain edge whose two
     page primes share an owner lives in that shard's registry slice;
     an edge straddling prime ranges is cross-shard and rides the
-    collective gcd exchange.
+    collective exchange.
   * **Per-shard bulk discovery.**  Successor tables are rebuilt
-    per-shard through the existing Pallas divisibility kernels under
+    per-shard through the existing Pallas divisibility kernel under
     ``shard_map`` (:func:`repro.core.engine.shard.
-    sharded_successor_table`); cross-shard chains are resolved by a
-    collective batched-gcd exchange (``lax.all_gather`` + the gcd
-    kernel).  The assembled rows are bit-identical to the single-device
-    table, so every placement decision — and therefore every
-    ``PARITY_COUNTERS`` entry — stays bit-exact against the scalar
-    oracle at ANY shard count (``tests/test_serving_sharded.py``).
+    sharded_successor_table`): each shard all-gathers the cross-shard
+    composites (``lax.all_gather``, the collective exchange), then runs
+    one divisibility scan of its local and gathered rows against the
+    query primes it owns.  The assembled rows are bit-identical to the
+    single-device table, so every placement decision — and therefore
+    every ``PARITY_COUNTERS`` entry — stays bit-exact against the
+    scalar oracle at ANY shard count (``tests/test_serving_sharded.py``).
   * **Owner-routed accounting.**  ``touch_batch`` routes each touch to
     the owner shard of the touched page: per-shard ``PageStats`` carry
     the same counters as the oracle's, and their field-wise sum equals
@@ -109,7 +110,7 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
     def _build_rows(self, discover: Optional[str]
                     ) -> Dict[int, List[int]]:
         """Every successor row by per-shard Pallas scans under
-        ``shard_map`` + the cross-shard gcd exchange.  An explicit
+        ``shard_map``, cross-shard rows all-gathered.  An explicit
         ``discover="host"|"kernel"`` falls back to the single-device
         bulk path (cross-check hook for the parity tests)."""
         if discover is not None:

@@ -48,7 +48,7 @@ import numpy as np
 
 __all__ = ["CROSS", "LOST", "ReshardPlan", "ShardSlices"]
 
-#: Owner code: chunk's primes span shards; scanned via the gcd exchange.
+#: Owner code: chunk's primes span shards; scanned via the all-gather exchange.
 CROSS = -1
 #: Owner code: entry belonged to a dead shard; must be re-factorized.
 LOST = -2

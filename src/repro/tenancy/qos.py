@@ -418,7 +418,7 @@ class TenantedShardedPagedKVCache(_TenantedVecPlacement,
     ownership stripes over SHARDS for discovery work (DESIGN.md §6) and
     over TENANTS for isolation/quotas (§8) — two independent pure
     functions of the same prime value, so the per-shard bulk rebuild
-    and the collective gcd exchange run unchanged over the tenanted
+    and the collective exchange run unchanged over the tenanted
     prime space."""
 
     def __init__(self, hbm_pages: int = 1024, page_size: int = 16,

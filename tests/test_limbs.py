@@ -187,7 +187,7 @@ def test_wide_registry_scan_tables_match_host():
 
 
 def test_wide_sharded_table_matches_host():
-    """The collective gcd exchange (wide digit rows) produces the same
+    """The collective exchange (wide digit rows) produces the same
     successor rows as the single-device host table at 2 and 4 shards."""
     from repro.core.assignment import PrimeAssigner
     from repro.core.engine.shard import (PrimeSpacePartition,

@@ -300,10 +300,20 @@ def test_every_backend_emits_the_rebuild_span(kv):
     refreshes = [r for r in kept if r.name == "pages.refresh"]
     assert len(refreshes) == m.pages.bulk_refreshes - before > 0
     ids = {r.id for r in refreshes}
-    assert any(r.name == "kernels.sharded_gcd_exchange.fetch" for r in kept)
     assert {r.name for r in kept if r.parent in ids} >= {
-        "tables.pack", "kernels.sharded_gcd_exchange", "tables.rows",
-        "pages.install"}
+        "tables.pack", "kernels.sharded_exchange", "tables.candidates",
+        "tables.rows", "pages.install"}
+    exchanges = [r for r in kept if r.name == "kernels.sharded_exchange"]
+    assert len(exchanges) == len(refreshes)
+    for ex in exchanges:
+        assert [r.name for r in kept if r.parent == ex.id] == [
+            "kernels.sharded_exchange.fetch"]
+        c = ex.counts
+        # logical sizes: the imbalance and the cross share of a rebuild
+        assert 0 <= c["local_min"] <= c["local_max"] <= c["c"]
+        assert 0 <= c["cross"] <= c["gathered"]
+        assert c["gathered"] % c["shards"] == 0
+        assert 0 < c["q_max"] <= c["q"]
 
 
 def test_kernel_ledger_unchanged_with_spans_on():

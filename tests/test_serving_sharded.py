@@ -159,8 +159,11 @@ def test_classify_partitions_registry_in_order():
 # sharded bulk discovery == single-device bulk discovery                      #
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("n_shards", [1, 2, 3])
-def test_sharded_successor_table_matches_global(n_shards):
+@pytest.mark.parametrize("n_shards,stripes", [
+    (1, 8), (2, 8), (3, 8), (4, 8),
+    # one-prime-wide blocks: most chain edges straddle owners
+    (4, 100_000)], ids=["1", "2", "3", "4", "4-mostly-cross"])
+def test_sharded_successor_table_matches_global(n_shards, stripes):
     from repro.core.engine import successor_table
 
     kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4,
@@ -172,9 +175,13 @@ def test_sharded_successor_table_matches_global(n_shards):
         kv.register_request(r, shared[:int(rng.integers(0, 16))] + tail)
     pages = range(kv._next_page)
     host = successor_table(kv.registry, kv.assigner, pages, discover="host")
-    part = PrimeSpacePartition(n_shards)
+    part = PrimeSpacePartition(n_shards, stripes)
+    local, cross = part.classify(kv.registry)
+    if stripes > 8:
+        assert len(cross) > sum(map(len, local))
+    # shard_map where the host has the devices, else the host loop
     sharded = sharded_successor_table(kv.registry, kv.assigner, pages,
-                                      part, mesh=None)
+                                      part, mesh=shard_mesh(n_shards))
     assert sharded == host
 
 

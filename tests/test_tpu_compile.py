@@ -7,7 +7,8 @@ the chip, so these tests guard the device path on every change:
 
 * each discovery kernel at 4,096 composites x 1,024 primes, with
   narrow (62-bit) and wide (1,024-bit) digit rows;
-* the sharded exchange's ``shard_map`` scan over a 2x2 mesh;
+* the sharded exchange's ``shard_map`` scan over a 2x2 mesh, at the
+  four-chip page-manager cell's buckets;
 * the full-width ``qwen2.5-3b`` decode step and parameter init, which
   must fit one chip.
 
@@ -72,22 +73,25 @@ def test_discovery_kernel_compiles(one_chip, kernel, bits):
     assert _is_kernel_program(fn.lower(*args).compile())
 
 
-def test_shard_map_scan_compiles_on_four_chips(topo):
+@pytest.mark.parametrize("C", [4096, 8192])
+def test_shard_map_scan_compiles_on_four_chips(topo, C):
+    """The exchange at the four-chip page-manager cell's buckets: local
+    rows C (and the next bucket), 1,024 gathered cross rows, 4,096
+    query primes a shard."""
     from repro.core.engine.shard import _shard_map_scan
     from repro.sharding.partition import shard_stack_spec
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
     stack = NamedSharding(mesh, shard_stack_spec(mesh))
-    S, C, Q, K, P, X = 4, 1024, 512, 1, 512, 64
+    S, Q, X = 4, 4096, 1024
     L = n_limbs_for_bits(62)
-    fn = _shard_map_scan(mesh, (C, Q, K, P, X), False)
+    fn = _shard_map_scan(mesh, False)
     compiled = fn.lower(_sds((S, C, L), jnp.int32, stack),
                         _sds((S, Q), jnp.int32, stack),
-                        _sds((S, K, L), jnp.int32, stack),
-                        _sds((S, P), jnp.int32, stack),
-                        _sds((S, X, L), jnp.int32, stack)).compile()
+                        _sds((S, X // S, L), jnp.int32, stack)).compile()
     text = compiled.as_text()
     assert "all-gather" in text and "tpu_custom_call" in text
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 
 def _qwen25_3b(one_chip):
