@@ -42,11 +42,19 @@ The tile runs as many rounds as its most-divided lane has dividing
 primes, a count reduced from the mask: none for a tile nothing divides,
 one or two for the two-prime composites of chain edges, never more than
 BP — where the walk over every prime ran BP.  No dynamic lane indexing.
+
+Two entries run each kernel: ``divisibility_mask_pallas`` and
+``factorize_squarefree_pallas`` return the dense mask, and
+``divisibility_hits_pallas`` and ``factorize_hits_pallas`` compact it in
+the same jitted program into a fixed-size vector of hit coordinates
+(``_compact_rows``, ``_first_rows``), which is all the host wrappers
+copy.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +64,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.composite import LIMB_BITS
 
-__all__ = ["divisibility_mask_pallas", "factorize_squarefree_pallas"]
+__all__ = ["divisibility_mask_pallas", "divisibility_hits_pallas",
+           "factorize_squarefree_pallas", "factorize_hits_pallas",
+           "HITS_PER_ROW"]
 
 _BASE = 1 << LIMB_BITS
+#: lanes of one compaction block (:func:`_compact_rows`)
+_BLOCK = 128
+#: hit slots a compacting launch keeps: per padded query prime of a
+#: scan, per padded composite of a factorization.  A launch with more
+#: leaves its wrapper the dense mask to copy.  A chain edge is divided
+#: by two primes, so the page cache's launches fill about half.
+HITS_PER_ROW = 4
 
 
 def _divides(c_ref, p):
@@ -141,6 +158,111 @@ def _check(digits, primes, block_n: int, block_p: int):
     return n, p
 
 
+def _divmask_call(digits, primes, block_n: int, block_p: int,
+                  interpret: bool):
+    """The scan's ``pallas_call``: the ``(P, N)`` query-major mask."""
+    n, p = _check(digits, primes, block_n, block_p)
+    L = digits.shape[1]
+    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
+    return pl.pallas_call(
+        _divmask_kernel,
+        grid=(n // block_n, p // block_p),
+        in_specs=[dspec, pspec],
+        out_specs=mspec,
+        out_shape=jax.ShapeDtypeStruct((p, n), jnp.bool_),
+        interpret=interpret,
+        name="divisibility_mask",
+    )(digits.T, primes.reshape(p, 1))
+
+
+def _factorize_call(digits, primes, block_n: int, block_p: int,
+                    interpret: bool):
+    """The factorization's ``pallas_call``: the ``(P, N)`` prime-major
+    mask and the ``(L, N)`` digit-major residual."""
+    n, p = _check(digits, primes, block_n, block_p)
+    L = digits.shape[1]
+    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
+    return pl.pallas_call(
+        _factorize_kernel,
+        grid=(n // block_n, p // block_p),
+        in_specs=[dspec, pspec],
+        out_specs=[mspec, dspec],
+        out_shape=[jax.ShapeDtypeStruct((p, n), jnp.bool_),
+                   jax.ShapeDtypeStruct((L, n), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_p, block_n), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="factorize_squarefree",
+    )(digits.T, primes.reshape(p, 1))
+
+
+def _prefix_counts(x):
+    """Inclusive running sums along the last axis of a small-count
+    ``(K, W)`` array, as one matmul by an upper-triangular matrix of
+    ones: exact in float32 while every sum stays below 2**24."""
+    w = x.shape[-1]
+    upper = jnp.triu(jnp.ones((w, w), jnp.float32))
+    return jnp.dot(x.astype(jnp.float32), upper,
+                   precision=lax.Precision.HIGHEST).astype(jnp.int32)
+
+
+def _compact_rows(mask, cap: int):
+    """The set cells of a ``(R, C)`` bool mask in row-major order, found
+    on the device: ``(total, per_row, cols)``, where ``per_row`` is the
+    ``(R,)`` count of each row and ``cols`` the column of each of the
+    first ``cap`` set cells, -1 past ``total``.
+
+    Hit ``k`` lies in the row where the running count passes ``k``;
+    in that row, in the block of ``_BLOCK`` lanes where the row's
+    running count passes the hit's rank in the row; in that block, at
+    the lane where the block's own running count passes its rank in the
+    block.  Each step
+    counts, for all ``cap`` hits at once, the rows, blocks or lanes
+    wholly before it: compares and sums, two gathers of whole rows, no
+    search loop, no scatter, and nothing sized by the data.
+    """
+    r, c = mask.shape
+    width = math.gcd(c, _BLOCK)
+    blocks = mask.reshape(r * (c // width), width)
+    per_block = jnp.sum(blocks, axis=1, dtype=jnp.int32).reshape(r, -1)
+    per_row = jnp.sum(per_block, axis=1)
+    row_end = jnp.cumsum(per_row)
+    total = row_end[-1]
+    k = jnp.arange(cap, dtype=jnp.int32)
+
+    def locate(ends, counts, rank):
+        """(index, rank inside it) of each hit's segment, ``ends`` the
+        segments' running counts, broadcast against ``rank[:, None]``."""
+        before = ends <= rank[:, None]
+        return (jnp.sum(before, axis=1, dtype=jnp.int32),
+                rank - jnp.sum(jnp.where(before, counts, 0), axis=1))
+
+    row, rank = locate(row_end[None, :], per_row[None, :], k)
+    row = jnp.minimum(row, r - 1)
+    counts = per_block[row]                               # (cap, C / width)
+    block, rank = locate(_prefix_counts(counts), counts, rank)
+    block = jnp.minimum(block, counts.shape[1] - 1)
+    lanes = blocks[row * counts.shape[1] + block]         # (cap, width)
+    lane = jnp.sum(_prefix_counts(lanes) <= rank[:, None], axis=1)
+    return total, per_row, jnp.where(k < total, block * width + lane, -1)
+
+
+def _first_rows(mask, slots: int):
+    """For each column of a ``(R, C)`` bool mask, its set count and the
+    rows of its first ``slots`` set cells, ascending, ``R`` past the
+    count: ``(per_col (C,), rows (C, slots))``.  One pass over the mask
+    a slot, each a masked minimum down the columns."""
+    r = mask.shape[0]
+    index = lax.broadcasted_iota(jnp.int32, mask.shape, 0)
+    last = jnp.full((mask.shape[1],), -1, jnp.int32)
+    rows = []
+    for _ in range(slots):
+        last = jnp.min(jnp.where(mask & (index > last), index, r), axis=0)
+        rows.append(last)
+    return jnp.sum(mask, axis=0, dtype=jnp.int32), jnp.stack(rows, axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
 def divisibility_mask_pallas(
     digits: jnp.ndarray,       # (N, L) int32 digits, N % block_n == 0
@@ -150,25 +272,39 @@ def divisibility_mask_pallas(
     block_p: int = 512,
     interpret: bool = False,
 ):
-    """Prefetch candidate scan (§4.2): ``mask[i, j] = primes[j] | c_i``.
-
-    The host compacts per-query candidate lists from the ``(N, P)`` mask.
-    Padding rows (value 0 or 1) match nothing; zero-padded primes never
-    divide.
+    """Prefetch candidate scan (§4.2): ``mask[i, j] = primes[j] | c_i``,
+    the dense ``(N, P)`` mask, which the sharded exchange compacts on
+    the host.  ``ops.divisibility_scan`` runs the same kernel through
+    :func:`divisibility_hits_pallas` instead, which compacts the mask on
+    the device into ``HITS_PER_ROW`` slots a padded query and leaves the
+    host the dense mask only when the hits overflow them.  Padding rows
+    (value 0 or 1) match nothing; zero-padded primes never divide.
     """
-    n, p = _check(digits, primes, block_n, block_p)
-    L = digits.shape[1]
-    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
-    mask = pl.pallas_call(
-        _divmask_kernel,
-        grid=(n // block_n, p // block_p),
-        in_specs=[dspec, pspec],
-        out_specs=mspec,
-        out_shape=jax.ShapeDtypeStruct((p, n), jnp.bool_),
-        interpret=interpret,
-        name="divisibility_mask",
-    )(digits.T, primes.reshape(p, 1))
-    return mask.T
+    return _divmask_call(digits, primes, block_n, block_p, interpret).T
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
+def divisibility_hits_pallas(
+    digits: jnp.ndarray,       # (N, L) int32 digits, N % block_n == 0
+    primes: jnp.ndarray,       # (P,) int32 query primes, P % block_p == 0
+    *,
+    block_n: int = 256,
+    block_p: int = 512,
+    interpret: bool = False,
+):
+    """The scan with its hits compacted on the device, in one program.
+
+    Returns ``(hits, mask)``.  ``hits`` is one int32 vector: the number
+    of set cells, the ``(P,)`` hit count of each query prime, then the
+    registry row of each of the first ``HITS_PER_ROW * P`` hits in
+    (query, row) order, -1 past the total.  ``mask`` is the ``(P, N)``
+    query-major mask, left on the device for a host that finds the
+    total above that cap.
+    """
+    mask = _divmask_call(digits, primes, block_n, block_p, interpret)
+    total, per_query, rows = _compact_rows(mask,
+                                           HITS_PER_ROW * primes.shape[0])
+    return jnp.concatenate([total[None], per_query, rows]), mask
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
@@ -184,20 +320,32 @@ def factorize_squarefree_pallas(
     the dividing pool primes and ``c // prod(dividing primes)`` (1 when
     the pool fully factors a squarefree composite) — see the ref.py
     oracle."""
-    n, p = _check(digits, primes, block_n, block_p)
-    L = digits.shape[1]
-    dspec, pspec, _, mspec = _specs(L, block_n, block_p)
-    mask, residual = pl.pallas_call(
-        _factorize_kernel,
-        grid=(n // block_n, p // block_p),
-        in_specs=[dspec, pspec],
-        out_specs=[mspec, dspec],
-        out_shape=[jax.ShapeDtypeStruct((p, n), jnp.bool_),
-                   jax.ShapeDtypeStruct((L, n), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((block_p, block_n), jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="factorize_squarefree",
-    )(digits.T, primes.reshape(p, 1))
+    mask, residual = _factorize_call(digits, primes, block_n, block_p,
+                                     interpret)
     return mask.T, residual.T
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
+def factorize_hits_pallas(
+    digits: jnp.ndarray,       # (N, L) int32 digits, N % block_n == 0
+    primes: jnp.ndarray,       # (P,) int32 pool, P % block_p == 0
+    *,
+    block_n: int = 256,
+    block_p: int = 512,
+    interpret: bool = False,
+):
+    """The factorization with its mask compacted on the device, in one
+    program.
+
+    Returns ``(hits, residual (N, L), mask (P, N))``.  ``hits`` is one
+    int32 vector: the ``(N,)`` count of each composite's dividing pool
+    primes, then for each composite the pool indices of its first
+    ``HITS_PER_ROW`` dividing primes, ascending (P past the count).
+    ``mask`` is left on the device for a host that finds a count above
+    ``HITS_PER_ROW``.
+    """
+    mask, residual = _factorize_call(digits, primes, block_n, block_p,
+                                     interpret)
+    per_composite, prime_of = _first_rows(mask, HITS_PER_ROW)
+    return (jnp.concatenate([per_composite, prime_of.reshape(-1)]),
+            residual.T, mask)

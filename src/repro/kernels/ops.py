@@ -25,7 +25,8 @@ from repro.core.composite import (LIMB_BASE, MAX_PRIME_BITS, MAX_PRIME_LIMIT,
 from repro.obs import profile
 from repro.obs.profile import kernel_scope
 
-from .factorize import divisibility_mask_pallas, factorize_squarefree_pallas
+from .factorize import (HITS_PER_ROW, divisibility_hits_pallas,
+                        factorize_hits_pallas)
 from .gcd import gcd_squarefree_pallas
 
 __all__ = ["divisibility_scan", "factorize_batch", "gcd_batch",
@@ -110,6 +111,25 @@ def _pad_rows_one(digits: np.ndarray, mult: int) -> np.ndarray:
     return np.concatenate([digits, one])
 
 
+def _scan_hits(hits, mask, padded_q: int):
+    """The one blocking copy of a compacting scan: ``(count of each
+    padded query, registry row of each hit in (query, row) order,
+    dense)``.
+
+    ``hits`` is the launch's ``[total, counts (padded_q), rows (cap)]``
+    vector.  A launch whose total overflowed its cap copies the dense
+    ``(padded_q, N)`` mask as well and compacts it here (``dense``
+    True), so the answer is exact either way.
+    """
+    hits = np.asarray(hits)
+    total, per_query, rows = (int(hits[0]), hits[1:1 + padded_q],
+                              hits[1 + padded_q:])
+    if total <= rows.shape[0]:
+        return per_query, rows[:total], False
+    m = np.asarray(mask)
+    return m.sum(axis=1), np.nonzero(m)[1], True
+
+
 def divisibility_scan(
     registry,                       # ints, or an (N, L) digit matrix
     query_primes: Sequence[int],
@@ -117,10 +137,14 @@ def divisibility_scan(
     block_p: int = 512,
     interpret: bool | None = None,
 ) -> List[np.ndarray]:
-    """For each query prime, indices of registry composites it divides.
+    """For each query prime, indices of registry composites it divides,
+    ascending.
 
-    The §4.2 prefetch scan: host compacts the kernel's boolean mask into
-    candidate index lists.
+    The §4.2 prefetch scan.  The launch compacts its mask on the device
+    (:func:`~repro.kernels.factorize.divisibility_hits_pallas`) and the
+    host copies the hit rows and per-query counts, not the mask; past
+    ``HITS_PER_ROW`` hits a padded query it copies the dense mask
+    instead.
     """
     if interpret is None:
         interpret = interpret_mode()
@@ -130,18 +154,20 @@ def divisibility_scan(
     n, q = digits.shape[0], qs.shape[0]
     if n == 0 or q == 0:
         return [np.empty(0, dtype=np.int64) for _ in range(q)]
+    padded_q = _bucket(q, block_p)
     with kernel_scope("divisibility_scan", items=n, n=n, q=q,
-                      padded_n=_bucket(n, block_n),
-                      padded_q=_bucket(q, block_p)):
-        mask = divisibility_mask_pallas(
+                      padded_n=_bucket(n, block_n), padded_q=padded_q):
+        hits, mask = divisibility_hits_pallas(
             jnp.asarray(_pad_rows_one(digits, block_n)),
             jnp.asarray(_pad_to(qs, block_p, 0)),
             block_n=block_n, block_p=block_p, interpret=interpret)
-        # the blocking copy: the device finishing the kernel, then D2H
+        # the blocking copy: the device finishing the launch, then D2H
         with profile.span("kernels.divisibility_scan.fetch"):
-            mask = np.asarray(mask)
-        mask = mask[:n, :q]
-        return [np.nonzero(mask[:, j])[0] for j in range(q)]
+            per_query, rows, dense = _scan_hits(hits, mask, padded_q)
+        # the mask's set cells, every one in a real row and query
+        profile.count(hits=len(rows), dense=int(dense))
+        return np.split(rows.astype(np.int64),
+                        np.cumsum(per_query[:q - 1]))
 
 
 def factorize_batch(
@@ -154,8 +180,13 @@ def factorize_batch(
     """Factor each composite against the pool.
 
     Returns ``(factors, residuals)`` — per composite the dividing pool
-    primes and the remaining cofactor as an exact Python int (1 when the
-    pool fully factors a squarefree composite).
+    primes in pool order and the remaining cofactor as an exact Python
+    int (1 when the pool fully factors a squarefree composite).  The
+    launch compacts its mask on the device
+    (:func:`~repro.kernels.factorize.factorize_hits_pallas`) and the host
+    copies each composite's count and first ``HITS_PER_ROW`` pool
+    indices, not the mask; a launch with a composite past that copies
+    the dense mask instead.
     """
     if interpret is None:
         interpret = interpret_mode()
@@ -165,22 +196,31 @@ def factorize_batch(
     n, p = digits.shape[0], pool.shape[0]
     if n == 0:
         return [], []
+    padded_n = _bucket(n, block_n)
     with kernel_scope("factorize_batch", items=n, n=n, p=p,
-                      padded_n=_bucket(n, block_n),
-                      padded_p=_bucket(p, block_p)):
-        mask, residual = factorize_squarefree_pallas(
+                      padded_n=padded_n, padded_p=_bucket(p, block_p)):
+        hits, residual, mask = factorize_hits_pallas(
             jnp.asarray(_pad_rows_one(digits, block_n)),
             jnp.asarray(_pad_to(pool, block_p, 0)),
             block_n=block_n, block_p=block_p, interpret=interpret)
         with profile.span("kernels.factorize_batch.fetch"):
-            mask, residual = np.asarray(mask), np.asarray(residual)
-        mask, residual = mask[:n, :p], residual[:n]
-        factors = [[int(pool[j]) for j in np.nonzero(mask[i])[0]]
-                   for i in range(n)]
+            hits, residual = np.asarray(hits), np.asarray(residual)
+            per_composite = hits[:n]
+            dense = int(per_composite.max()) > HITS_PER_ROW
+            if dense:
+                m = np.asarray(mask)[:, :n].T
+        if dense:
+            per_composite, cols = m.sum(axis=1), np.nonzero(m)[1]
+        else:
+            slots = hits[padded_n:].reshape(padded_n, HITS_PER_ROW)[:n]
+            cols = slots[np.arange(HITS_PER_ROW) < per_composite[:, None]]
+        flat = pool[cols].tolist()
+        ends = np.cumsum(per_composite).tolist()
+        factors = [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
         # dividing (composite, prime) pairs, which the kernel's division
         # rounds follow
-        profile.count(hits=sum(map(len, factors)))
-        return factors, unpack_limbs(residual)
+        profile.count(hits=len(cols), dense=int(dense))
+        return factors, unpack_limbs(residual[:n])
 
 
 def gcd_batch(

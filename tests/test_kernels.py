@@ -16,7 +16,9 @@ import pytest
 
 from repro.core.composite import n_limbs_for_bits, pack_limbs, unpack_limbs
 from repro.core.primes import (MAX_PRIME_LIMIT, PrimePool, segmented_sieve)
-from repro.kernels.factorize import (divisibility_mask_pallas,
+from repro.kernels.factorize import (HITS_PER_ROW, divisibility_hits_pallas,
+                                     divisibility_mask_pallas,
+                                     factorize_hits_pallas,
                                      factorize_squarefree_pallas)
 from repro.kernels.gcd import gcd_squarefree_pallas
 from repro.kernels.ops import divisibility_scan, factorize_batch, gcd_batch
@@ -192,6 +194,74 @@ def test_factorize_batch_int64_path():
 def test_divisibility_scan_compaction():
     idx = divisibility_scan([6, 10, 15, 21], [2, 3, 5, 7])
     assert [list(i) for i in idx] == [[0, 1], [0, 2, 3], [1, 2], [3]]
+
+
+def _wrapper_case(name):
+    """``(values, query primes, pool, bits)`` for the compacting
+    wrappers."""
+    small = [int(q) for q in PRIMES_SMALL]
+    wide = [int(q) for q in PRIMES_WIDE]
+    if name == "narrow":
+        vals = _pairs(np.random.default_rng(3), np.int32, 300)
+        # 127 divides nothing: a query with no hits, among the rest
+        return vals, [127] + small, small + [127], 62
+    if name == "wide":
+        vals = _pairs(np.random.default_rng(4), np.int64, 300) + [
+            math.prod(wide[:4])]
+        return vals, [wide[0] + 2] + wide, wide, 1024
+    if name == "empty_registry":
+        return [], small, small, 62
+    if name == "mostly_padding":
+        # whole 128-lane blocks of pad rows and 500 pad query rows
+        return [6, 35, 1], [7, 3], [2, 3, 5, 7], 62
+    if name == "over_the_cap":
+        # 15 dividing primes a composite and 3,842 hits in a scan of 512
+        # padded queries, past HITS_PER_ROW: the dense fallback
+        first15 = math.prod(small[:15])
+        return [first15] * 256 + [6], small, small, 62
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", ["narrow", "wide", "empty_registry",
+                                  "mostly_padding", "over_the_cap"])
+def test_compacted_wrappers_match_dense_ref(case):
+    """Hit coordinates compacted on the device give exactly the oracle's
+    per-query rows and per-composite factors and residuals, the dense
+    fallback past the cap included."""
+    vals, queries, pool, bits = _wrapper_case(case)
+    digits = pack_limbs(vals, n_limbs_for_bits(bits))
+    if case == "over_the_cap":
+        assert 15 > HITS_PER_ROW and len(vals) * 15 > HITS_PER_ROW * 512
+    idx = divisibility_scan(digits, queries)
+    mask = divisibility_mask_ref(digits, queries)
+    assert len(idx) == len(queries)
+    for j, got in enumerate(idx):
+        assert got.dtype == np.int64
+        assert got.tolist() == np.nonzero(mask[:, j])[0].tolist()
+    facs, resid = factorize_batch(digits, pool)
+    fmask, fres = factorize_squarefree_ref(digits, np.asarray(pool))
+    assert facs == [[pool[j] for j in np.nonzero(row)[0]] for row in fmask]
+    assert resid == unpack_limbs(fres)
+
+
+def test_compacting_entries_compile_once_per_padded_shape():
+    """The number of hits sizes nothing: wrapper calls of one padded
+    shape, the first with no hits (as the benchmark's warm-up makes
+    them) and the next with many, share one executable."""
+    ones = np.zeros((256, DIGITS), np.int32)
+    ones[:, 0] = 1
+    hits = pack_limbs(_pairs(np.random.default_rng(5), np.int32, 256),
+                      DIGITS)
+    pool = [int(q) for q in PRIMES_SMALL]
+    entries = (divisibility_hits_pallas, factorize_hits_pallas)
+    totals, sizes = [], []
+    for d in (ones, hits):
+        idx = divisibility_scan(d, pool)
+        facs, _ = factorize_batch(d, pool)
+        totals.append((sum(map(len, idx)), sum(map(len, facs))))
+        sizes.append([f._cache_size() for f in entries])
+    assert totals[0] == (0, 0) and min(totals[1]) > 0
+    assert sizes[0] == sizes[1]
 
 
 def test_scan_empty_inputs():

@@ -350,6 +350,31 @@ def test_factorize_hits_counts_the_factors_returned():
     assert span.counts["hits"] == sum(map(len, facs)) == 10
 
 
+@pytest.mark.parametrize("over_the_cap", [False, True])
+def test_compacting_wrappers_count_hits_and_dense(over_the_cap):
+    """``hits`` on the scan is the dense mask's set count; ``dense`` is 1
+    on both wrappers only when a launch's hits overflow their slots and
+    the dense mask is copied instead."""
+    import math
+
+    from repro.kernels.ops import as_digits, divisibility_scan, \
+        factorize_batch
+    from repro.kernels.ref import divisibility_mask_ref
+
+    primes = [2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37, 41, 43, 47]
+    # 15 hits a composite overflow the 4 slots a padded row keeps
+    vals = ([math.prod(primes)] * 256 if over_the_cap
+            else [6, 35, 143, 101, 2 * 3 * 5 * 7, 1])
+    with profile.recording() as kept:
+        divisibility_scan(vals, primes)
+        factorize_batch(vals, primes)
+    scan, fact = (next(r for r in kept if r.name == f"kernels.{name}")
+                  for name in ("divisibility_scan", "factorize_batch"))
+    assert scan.counts["hits"] == \
+        divisibility_mask_ref(as_digits(vals), primes).sum()
+    assert scan.counts["dense"] == fact.counts["dense"] == int(over_the_cap)
+
+
 class _StubModel:
     """A model with the decode interface and no weights: logits favour
     token (position + 1)."""

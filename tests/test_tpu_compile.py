@@ -6,7 +6,8 @@ tiling rule, a program too large for 16 GiB of HBM) it would refuse on
 the chip, so these tests guard the device path on every change:
 
 * each discovery kernel at 4,096 composites x 1,024 primes, with
-  narrow (62-bit) and wide (1,024-bit) digit rows;
+  narrow (62-bit) and wide (1,024-bit) digit rows, and the scan and
+  the factorization with their masks compacted on the device;
 * the sharded exchange's ``shard_map`` scan over a 2x2 mesh, at the
   four-chip page-manager cell's buckets;
 * the full-width ``qwen2.5-3b`` decode step and parameter init, which
@@ -56,9 +57,12 @@ def _is_kernel_program(compiled) -> bool:
 
 @pytest.mark.parametrize("bits", [62, 1024])
 @pytest.mark.parametrize("kernel", ["divisibility_mask", "factorize",
-                                    "gcd_squarefree"])
+                                    "gcd_squarefree", "divisibility_hits",
+                                    "factorize_hits"])
 def test_discovery_kernel_compiles(one_chip, kernel, bits):
-    from repro.kernels.factorize import (divisibility_mask_pallas,
+    from repro.kernels.factorize import (divisibility_hits_pallas,
+                                         divisibility_mask_pallas,
+                                         factorize_hits_pallas,
                                          factorize_squarefree_pallas)
     from repro.kernels.gcd import gcd_squarefree_pallas
 
@@ -69,6 +73,8 @@ def test_discovery_kernel_compiles(one_chip, kernel, bits):
         "divisibility_mask": (divisibility_mask_pallas, (digits, primes)),
         "factorize": (factorize_squarefree_pallas, (digits, primes)),
         "gcd_squarefree": (gcd_squarefree_pallas, (digits, digits, primes)),
+        "divisibility_hits": (divisibility_hits_pallas, (digits, primes)),
+        "factorize_hits": (factorize_hits_pallas, (digits, primes)),
     }[kernel]
     assert _is_kernel_program(fn.lower(*args).compile())
 
