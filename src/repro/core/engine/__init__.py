@@ -39,19 +39,21 @@ Public entry points (documented with runnable examples in docs/api.md):
   * :func:`successor_table` — bulk chain-successor discovery (the serving
     paged-KV cache's table-refresh path, DESIGN.md §5)
   * :func:`sharded_successor_table` — the mesh-partitioned twin:
-    per-shard Pallas scans under ``shard_map`` + the cross-shard gcd
-    exchange, bit-identical rows (DESIGN.md §6), with
-    :class:`PrimeSpacePartition` as the ownership rule
+    per-shard Pallas scans under ``shard_map`` + the all-gathered
+    cross-shard slice (DESIGN.md §6), with :class:`PrimeSpacePartition`
+    as the ownership rule; both builders take their rows from
+    ``rows_from_hits``
 """
 
 from .batch import VECTORIZED_SYSTEMS, simulate_batch, simulate_trace, sweep
 from .shard import (PrimeSpacePartition, shard_mesh,
                     sharded_successor_table)
 from .tables import (PFCSTables, pfcs_tables, related_bulk,
-                     successor_table)
+                     rows_from_hits, successor_table)
 
 __all__ = [
     "simulate_trace", "simulate_batch", "sweep", "VECTORIZED_SYSTEMS",
     "PFCSTables", "pfcs_tables", "related_bulk", "successor_table",
+    "rows_from_hits",
     "PrimeSpacePartition", "shard_mesh", "sharded_successor_table",
 ]

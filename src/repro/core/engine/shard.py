@@ -13,16 +13,18 @@ block's factorization locality (neighbouring chain pages get
 neighbouring primes under Algorithm 1's ascending allocation); striping
 keeps ownership balanced even though allocation is ascending.  Ownership
 is a pure O(1) function of the prime value — no directory, no
-coordination — so every shard can classify any composite locally.
+coordination — so every shard can place any composite locally.
 
-**Sharded registry classification.**  A relationship whose member
-primes all fall in one shard's ranges is *shard-local*: its composite
-chunks live only in that shard's registry slice and are scanned only
-there.  A relationship straddling prime ranges (a chain edge whose two
-page primes have different owners) is *cross-shard*: its chunks go to
-the exchanged slice that every shard scans.  Classification preserves
-the global registry (registration) order — the candidate-order contract
-the serving cache's parity tests pin down.
+**The registry split.**  :class:`repro.sharding.reshard.ShardSlices`
+labels each composite chunk by the owners of the primes dividing it: a
+chunk whose primes one shard owns is *shard-local* and scanned only
+there; a chunk whose primes span owners (a chain edge whose two page
+primes have different owners) is *cross-shard* and goes to the
+exchanged slice every shard scans.  A prime's hits can only come from a
+chunk that contains it, so the split loses no (query prime, position)
+hit.  The index is kept current as the registry grows and preserves
+the global registry (registration) order — the candidate-order
+contract the serving cache's parity tests pin down.
 
 **Per-shard bulk discovery under shard_map.**  Successor rows are
 rebuilt per shard through the SAME Pallas kernel the single-device path
@@ -50,11 +52,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.kernels.ops import _bucket, prime_lanes
 from repro.obs import profile
 from repro.obs.profile import kernel_scope
 from repro.sharding.stripes import BlockStripes
 
 from ..primes import CacheLevel, LEVEL_PRIME_RANGES
+from .tables import keyed_queries, rows_from_hits
 
 __all__ = ["PrimeSpacePartition", "shard_mesh", "sharded_successor_table",
            "ShardScanReport"]
@@ -92,28 +96,6 @@ class PrimeSpacePartition:
     def owners(self, primes: Sequence[int]) -> np.ndarray:
         return self.stripes.owners(primes)
 
-    def classify(self, registry) -> Tuple[List[List[int]], List[int]]:
-        """Split the live registry into per-shard-local and cross-shard
-        composite *positions* (indices into ``registry.composites_array()``
-        — global registration order, which both scan paths preserve).
-
-        A relationship is local to shard ``s`` iff every member prime is
-        owned by ``s``; otherwise every chunk of it is cross-shard.
-        """
-        arr = registry.composites_view()
-        local: List[List[int]] = [[] for _ in range(self.n_shards)]
-        cross: List[int] = []
-        for pos in range(arr.size):
-            rel = registry.relationship_of_composite(int(arr[pos]))
-            if rel is None:                       # pragma: no cover - defensive
-                continue
-            owners = {self.owner(q) for q in rel.primes}
-            if len(owners) == 1:
-                local[owners.pop()].append(pos)
-            else:
-                cross.append(pos)
-        return local, cross
-
     def describe(self) -> str:
         parts = [f"{CacheLevel.NAMES[lvl]}:block={w}"
                  for lvl, (_, w) in sorted(self._blocks.items())]
@@ -147,12 +129,8 @@ def shard_mesh(n_shards: int):
 def _pad_rows(rows: Sequence[np.ndarray], mult: int, fill: int,
               dtype=np.int32) -> np.ndarray:
     """Stack ragged 1-D arrays into (S, W), W bucketed to ``mult * 2**k``
-    — power-of-two buckets bound the number of distinct compiled shapes
-    as tables grow across refreshes."""
-    need = max([r.shape[0] for r in rows] + [1])
-    width = mult
-    while width < need:
-        width *= 2
+    (:func:`repro.kernels.ops._bucket`)."""
+    width = _bucket(max([r.shape[0] for r in rows] + [1]), mult)
     out = np.full((len(rows), width), fill, dtype=dtype)
     for i, r in enumerate(rows):
         out[i, :r.shape[0]] = r
@@ -164,11 +142,8 @@ def _pad_digit_stack(rows: Sequence[np.ndarray], mult: int, L: int,
     """Stack ragged (n_i, L) digit matrices into (S, W, L); pad rows
     encode the value 1 (match nothing) and W is bucketed to
     ``mult * 2**k`` like :func:`_pad_rows`."""
-    need = max([r.shape[0] for r in rows] + [1])
     if width is None:
-        width = mult
-        while width < need:
-            width *= 2
+        width = _bucket(max([r.shape[0] for r in rows] + [1]), mult)
     out = np.zeros((len(rows), width, L), dtype=np.int32)
     out[:, :, 0] = 1
     for i, r in enumerate(rows):
@@ -275,45 +250,34 @@ def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
 
 
 def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
-                            partition: PrimeSpacePartition,
-                            mesh=None,
+                            slices, mesh=None,
                             report: Optional[ShardScanReport] = None,
-                            precomputed: Optional[Tuple[List[List[int]],
-                                                        List[int]]] = None,
                             ) -> Dict[int, List[int]]:
     """Mesh-partitioned twin of :func:`repro.core.engine.successor_table`.
 
     Produces BIT-IDENTICAL rows (same candidates, same order — global
     registry order, deduplicated by relationship, expanded in
-    ``rel.primes`` order) while splitting the scan work by prime
-    ownership: each shard's Pallas divisibility scan covers its local
-    registry slice and the gathered cross-shard slice, against only the
-    query primes it owns.
+    ``rel.primes`` order, by the shared
+    :func:`~repro.core.engine.tables.rows_from_hits`) while splitting the
+    scan work by prime ownership: each shard's Pallas divisibility scan
+    covers its local registry slice and the gathered cross-shard slice,
+    against only the query primes it owns.
 
-    ``precomputed`` optionally supplies the ``(local_pos, cross_pos)``
-    registry split (e.g. the maintained
-    :class:`repro.sharding.reshard.ShardSlices` index) instead of the
-    O(registry) :meth:`PrimeSpacePartition.classify` walk.  Any split
-    that routes each position to a shard owning one of its chunk's
-    primes yields identical rows — a prime's hits can only come from the
-    chunk containing it.
+    ``slices`` is the registry split, a
+    :class:`repro.sharding.reshard.ShardSlices`; it is brought up to the
+    registry's version here, and its partition routes the queries.
     """
-    from repro.kernels.ops import prime_lanes
-
+    partition = slices.partition
     S = partition.n_shards
-    keyed = [(int(d), p) for d in data_ids
-             if (p := assigner.prime_of(int(d))) is not None]
-    arr = registry.composites_view()
-    if arr.size == 0 or not keyed:
+    keyed = keyed_queries(assigner, data_ids)
+    if registry.n_composites == 0 or not keyed:
         return {d: [] for d, _ in keyed}
 
     with profile.span("tables.pack", queries=len(keyed),
-                      composites=int(arr.size)):
+                      composites=registry.n_composites):
         # ---- partition state: registry slices and query routing --------- #
-        if precomputed is not None:
-            local_pos, cross_pos = precomputed
-        else:
-            local_pos, cross_pos = partition.classify(registry)
+        slices.sync(registry)
+        local_pos, cross_pos = slices.local(), slices.cross()
         by_shard: List[List[Tuple[int, int]]] = [[] for _ in range(S)]
         for d, p in keyed:
             by_shard[partition.owner(p)].append((d, p))
@@ -323,10 +287,7 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
         # per-shard cross-slice width bucketed to powers of two, like every
         # other stack: an exact ceil(cross/S) width would change the compiled
         # shard_map shape on nearly every registry growth
-        need = -(-max(len(cross_pos), 1) // S)
-        per = 8
-        while per < need:
-            per *= 2
+        per = _bucket(-(-max(len(cross_pos), 1) // S), 8)
 
         digits = registry.limbs_array()
         L = registry.n_limbs
@@ -360,7 +321,7 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
         # first, then the gathered cross rows (cross index == gathered
         # row, the slices being consecutive); padded queries hit nothing
         C, Q = local_c.shape[1], mask.shape[2]
-        hits_of: List[List[np.ndarray]] = []
+        hits_of: List[np.ndarray] = []
         for s in range(S):
             row_pos = np.full(mask.shape[1], -1, np.int64)
             row_pos[:n_local[s]] = local_pos[s]
@@ -368,26 +329,10 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
             row, col = np.divmod(np.flatnonzero(mask[s]), Q)
             pos = row_pos[row]
             order = np.lexsort((pos, col))              # by query, then pos
-            hits_of.append(np.split(pos[order], np.searchsorted(
-                col[order], np.arange(1, len(by_shard[s])))))
+            n_q = len(by_shard[s])
+            hits_of.extend(np.split(pos[order], np.searchsorted(
+                col[order], np.arange(1, n_q)))[:n_q])
 
     with profile.span("tables.rows"):
-        # ---- assemble rows in the oracle's exact order ------------------ #
-        out: Dict[int, List[int]] = {}
-        for s in range(S):
-            for (d, p), hits in zip(by_shard[s], hits_of[s]):
-                row: List[int] = []
-                seen: set = set()
-                for pos in hits:                # ascending == registry order
-                    rel = registry.relationship_of_composite(int(arr[pos]))
-                    if rel is None or rel.rel_id in seen:
-                        continue
-                    seen.add(rel.rel_id)
-                    for q in rel.primes:        # oracle's frozenset order
-                        if q == p:
-                            continue
-                        succ = assigner.data_of(q)
-                        if succ is not None:
-                            row.append(succ)
-                out[d] = row
-    return out
+        return rows_from_hits(registry, assigner,
+                              [q for sh in by_shard for q in sh], hits_of)

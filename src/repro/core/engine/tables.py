@@ -42,7 +42,7 @@ from ..pfcs_cache import PFCSCache
 from ..traces import Trace
 
 __all__ = ["PFCSTables", "pfcs_tables", "related_bulk", "make_pfcs_cache",
-           "successor_table"]
+           "successor_table", "rows_from_hits", "keyed_queries"]
 
 
 class PFCSTables(NamedTuple):
@@ -73,6 +73,97 @@ def make_pfcs_cache(trace: Trace,
     return cache
 
 
+def keyed_queries(assigner, data_ids: Sequence[int]) -> List[Tuple[int, int]]:
+    """Query routing: ``(data id, prime)`` for every id with an assigned
+    prime, in the given order (ids without one discover nothing — the
+    oracle's early return)."""
+    return [(int(d), p) for d in data_ids
+            if (p := assigner.prime_of(int(d))) is not None]
+
+
+def _relationships(rel_of, composites: List[int]) -> list:
+    """The relationships of ``composites`` (hits in ascending registry
+    position), each once, in registry order."""
+    rels, seen = [], set()
+    for c in composites:
+        rel = rel_of(c)
+        if rel is not None and rel.rel_id not in seen:
+            seen.add(rel.rel_id)
+            rels.append(rel)
+    return rels
+
+
+def _expand(rels, p: int, data_of) -> List[int]:
+    """The other members of each relationship, as data ids, in
+    ``rel.primes`` order (the oracle's frozenset order); repeats kept."""
+    row: List[int] = []
+    for rel in rels:
+        for q in rel.primes:
+            if q != p:
+                succ = data_of(q)
+                if succ is not None:
+                    row.append(succ)
+    return row
+
+
+def rows_from_hits(registry, assigner, keyed: Sequence[Tuple[int, int]],
+                   hits: Sequence[np.ndarray]) -> Dict[int, List[int]]:
+    """Successor rows from scan hits, in the oracle's order.
+
+    ``hits[i]`` holds the ascending registry positions whose composite
+    ``keyed[i]``'s prime divides.  Each row walks those composites'
+    relationships in registry order, each relationship once, and expands
+    it in ``rel.primes`` order; repeated targets are kept (the touch-time
+    residency check skips them).  The one-chip kernel path and the
+    sharded path both build their rows here.
+    """
+    comps = registry.composites_view()
+    rel_of, data_of = registry.relationship_of_composite, assigner.data_of
+    return {d: _expand(_relationships(rel_of, comps[idxs].tolist()), p,
+                       data_of)
+            for (d, p), idxs in zip(keyed, hits)}
+
+
+def _check_hits(primes: np.ndarray, hits: Sequence[np.ndarray],
+                needed: np.ndarray, factors: Sequence[Sequence[int]]) -> None:
+    """Theorem 1: every (query prime, hit position) pair of the scan is a
+    (factor, composite) pair of the decoded factorizations."""
+    hit = (np.concatenate(hits) << 32) | np.repeat(
+        primes, [len(h) for h in hits])
+    decoded = (np.repeat(needed, [len(f) for f in factors]) << 32) | \
+        np.fromiter((q for f in factors for q in f), np.int64)
+    assert np.isin(hit, decoded).all(), "divisibility hit must contain p"
+
+
+def _kernel_hits(keyed: Sequence[Tuple[int, int]], digits: np.ndarray,
+                 pool: np.ndarray, chunk: int):
+    """The kernel passes of a bulk discovery: the §4.2 divisibility scan
+    of the digit matrix in ``chunk``-query slices, then one
+    ``factorize_batch`` over the sorted unique candidates, checked
+    against the hits (Theorem 1).
+
+    Returns ``(hits, needed, factors)``: per query the ascending hit
+    positions, the candidate positions, and their decoded factors.
+    """
+    # looked up on the module at call time: a caller may wrap them
+    from repro.kernels.ops import divisibility_scan, factorize_batch
+
+    primes = np.asarray([p for _, p in keyed], dtype=np.int64)
+    hits: List[np.ndarray] = []
+    for lo in range(0, len(primes), chunk):
+        hits.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
+    with profile.span("tables.candidates"):
+        needed = np.unique(np.concatenate(hits))
+    profile.count(candidates=len(needed))
+    factors: List[List[int]] = []
+    if needed.size:
+        factors, residual = factorize_batch(digits[needed], pool)
+        assert all(r == 1 for r in residual), \
+            "registry composite escaped its pool"
+    _check_hits(primes, hits, needed, factors)
+    return hits, needed, factors
+
+
 def related_bulk(cache: PFCSCache, keys: Sequence[int],
                  chunk: int = 1024) -> Dict[int, List[Tuple[int, float]]]:
     """Bulk relationship discovery through the Pallas kernels.
@@ -82,62 +173,36 @@ def related_bulk(cache: PFCSCache, keys: Sequence[int],
     batched trial-division kernel, and return the weight-ranked related
     elements — the device twin of
     ``IntelligentPrefetcher.related_elements``, with identical ordering.
+    The scan, decode and check are :func:`successor_table`'s.
     """
-    from repro.kernels.ops import divisibility_scan, factorize_batch
-
     registry = cache.registry
     assigner = cache.assigner
     arr = registry.composites_view()
-    keyed = [(int(k), p) for k in keys
-             if (p := assigner.prime_of(int(k))) is not None]
+    keyed = keyed_queries(assigner, keys)
     if arr.size == 0 or not keyed:
         return {}
 
-    # kernel pass 1: registry divisibility scan, chunked over query primes
-    primes = np.asarray([p for _, p in keyed], dtype=np.int64)
-    digits = registry.limbs_array()
-    cand: List[np.ndarray] = []
-    for lo in range(0, len(primes), chunk):
-        cand.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
-
-    # kernel pass 2: decode every candidate composite once
-    needed = sorted({int(i) for idxs in cand for i in idxs})
-    factors_of: Dict[int, set] = {}
-    if needed:
-        comps = arr[np.asarray(needed)]
-        pool = registry.primes_array()
-        facs, residual = factorize_batch(digits[np.asarray(needed)], pool)
-        assert all(r == 1 for r in residual), \
-            "registry composite escaped its pool"
+    hits, needed, factors = _kernel_hits(keyed, registry.limbs_array(),
+                                         registry.primes_array(), chunk)
+    if needed.size:
         stats = cache.factorizer.stats
-        for c, fs in zip(comps, facs):
-            factors_of[int(c)] = set(fs)
+        for c, fs in zip(arr[needed].tolist(), factors):
             cache.factorizer.cache.put(int(c), tuple(sorted(fs)))
         # stage accounting: the kernel's trial division decodes each
         # composite once; every further (prime, composite) incidence is a
         # factorization-cache hit (DESIGN.md §3)
-        incidences = sum(len(idxs) for idxs in cand)
+        incidences = sum(len(idxs) for idxs in hits)
         stats.trial_division += len(needed)
         stats.cache_hits += incidences - len(needed)
         stats.total += incidences
 
+    rel_of, data_of = registry.relationship_of_composite, assigner.data_of
     out: Dict[int, List[Tuple[int, float]]] = {}
-    for (k, p), idxs in zip(keyed, cand):
+    for (k, p), idxs in zip(keyed, hits):
         ranked: Dict[int, float] = {}
-        seen = set()
-        for i in idxs:
-            c = int(arr[int(i)])
-            assert p in factors_of[c], "divisibility hit must contain p"
-            rel = registry.relationship_of_composite(c)
-            if rel is None or rel.rel_id in seen:
-                continue
-            seen.add(rel.rel_id)
-            for q in rel.primes:     # same frozenset order as the oracle
-                if q == p:
-                    continue
-                tgt = assigner.data_of(q)
-                if tgt is not None:
-                    ranked[tgt] = max(ranked.get(tgt, 0.0), rel.weight)
+        for rel in _relationships(rel_of, arr[idxs].tolist()):
+            for tgt in _expand((rel,), p, data_of):
+                ranked[tgt] = max(ranked.get(tgt, 0.0), rel.weight)
         out[k] = sorted(ranked.items(), key=lambda kv: -kv[1])
     return out
 
@@ -161,9 +226,10 @@ def successor_table(registry, assigner, data_ids: Sequence[int],
 
       * ``discover="host"``   — replays ``registry.containing`` per id
         (charging the host factorizer exactly as the scalar cache does);
+        the reference the kernel rows are tested against;
       * ``discover="kernel"`` — one bulk pass through the Pallas
         ``divisibility_scan`` / ``factorize_batch`` kernels, the TPU
-        registry-refresh deployment (mirrors :func:`related_bulk`).
+        registry-refresh deployment, then :func:`rows_from_hits`.
 
     Returns ``{data_id: [successor data_id, ...]}`` for every id that
     has an assigned prime (ids without one discover nothing — exactly
@@ -173,77 +239,25 @@ def successor_table(registry, assigner, data_ids: Sequence[int],
         raise ValueError(f"discover must be 'host' or 'kernel', "
                          f"got {discover!r}")
     with profile.span("tables.pack"):
-        keyed = [(int(d), p) for d in data_ids
-                 if (p := assigner.prime_of(int(d))) is not None]
-        arr = registry.composites_view()
-        kernel = discover == "kernel" and arr.size > 0 and bool(keyed)
+        keyed = keyed_queries(assigner, data_ids)
+        n = registry.n_composites
+        kernel = discover == "kernel" and n > 0 and bool(keyed)
         if kernel:
-            primes = np.asarray([p for _, p in keyed], dtype=np.int64)
             pool = registry.primes_array()
             digits = registry.limbs_array()
     # counts go to the span open around the call (``pages.refresh``)
-    profile.count(queries=len(keyed), composites=int(arr.size),
+    profile.count(queries=len(keyed), composites=n,
                   pool=len(pool) if kernel else 0)
     if discover == "host":
         with profile.span("tables.rows"):
-            out: Dict[int, List[int]] = {}
-            for d, p in keyed:
-                row: List[int] = []
-                for rel in registry.containing(p):
-                    for q in rel.primes:
-                        if q == p:
-                            continue
-                        succ = assigner.data_of(q)
-                        if succ is not None:
-                            row.append(succ)
-                out[d] = row
-        return out
-
+            return {d: _expand(registry.containing(p), p, assigner.data_of)
+                    for d, p in keyed}
     if not kernel:
         return {d: [] for d, _ in keyed}
 
-    from repro.kernels.ops import divisibility_scan, factorize_batch
-
-    # kernel pass 1: registry divisibility scan over the digit matrix
-    # (every width, DESIGN.md §3), chunked over query primes
-    cand: List[np.ndarray] = []
-    for lo in range(0, len(primes), chunk):
-        cand.extend(divisibility_scan(digits, primes[lo:lo + chunk]))
-
-    # kernel pass 2: decode every candidate composite once (Theorem 1
-    # check: the decoded factors must contain the query prime)
-    with profile.span("tables.candidates"):
-        needed = sorted({int(i) for idxs in cand for i in idxs})
-    profile.count(candidates=len(needed))
-    factors_of: Dict[int, set] = {}
-    if needed:
-        comps = arr[np.asarray(needed)]
-        facs, residual = factorize_batch(digits[np.asarray(needed)], pool)
-        assert all(r == 1 for r in residual), \
-            "registry composite escaped its pool"
-        for c, fs in zip(comps, facs):
-            factors_of[int(c)] = set(fs)
-
+    hits, _, _ = _kernel_hits(keyed, digits, pool, chunk)
     with profile.span("tables.rows"):
-        out = {}
-        for (d, p), idxs in zip(keyed, cand):
-            row = []
-            seen: set = set()
-            for i in idxs:                    # ascending == registry order
-                c = int(arr[int(i)])
-                assert p in factors_of[c], "divisibility hit must contain p"
-                rel = registry.relationship_of_composite(c)
-                if rel is None or rel.rel_id in seen:
-                    continue
-                seen.add(rel.rel_id)
-                for q in rel.primes:          # oracle's frozenset order
-                    if q == p:
-                        continue
-                    succ = assigner.data_of(q)
-                    if succ is not None:
-                        row.append(succ)
-            out[d] = row
-    return out
+        return rows_from_hits(registry, assigner, keyed, hits)
 
 
 def pfcs_tables(trace: Trace,

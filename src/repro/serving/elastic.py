@@ -52,10 +52,10 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
-from repro.core.engine.shard import (PrimeSpacePartition, ShardScanReport,
-                                     shard_mesh, sharded_successor_table)
+from repro.core.engine.shard import (PrimeSpacePartition, shard_mesh,
+                                     sharded_successor_table)
 from repro.obs.trace import EV_RECOVERY
-from repro.sharding.reshard import ReshardPlan, ShardSlices
+from repro.sharding.reshard import ReshardPlan
 from repro.training.elastic import ElasticPlanner, FleetState, StragglerMonitor
 
 from .kv_cache import PARITY_COUNTERS, PageStats
@@ -92,10 +92,10 @@ class RecoveryReport:
 
 class ElasticShardedPagedKVCache(ShardedPagedKVCache):
     """``ShardedPagedKVCache`` with live ``resize``/``fail_shard``/
-    ``recover_shard`` and a maintained
-    :class:`~repro.sharding.reshard.ShardSlices` registry index (which
-    also feeds the sharded scan via ``precomputed=``, replacing the
-    per-refresh ``classify`` walk)."""
+    ``recover_shard``: the inherited
+    :class:`~repro.sharding.reshard.ShardSlices` registry index is
+    re-striped on a resize and rebuilt by re-factorization after a shard
+    loss."""
 
     def __init__(self, hbm_pages: int = 1024, page_size: int = 16,
                  prefetch_budget: int = 4, n_shards: int = 2,
@@ -105,29 +105,19 @@ class ElasticShardedPagedKVCache(ShardedPagedKVCache):
                          prefetch_budget=prefetch_budget, n_shards=n_shards,
                          mesh=mesh, stripes_per_shard=stripes_per_shard,
                          max_bits=max_bits)
-        self.slices = ShardSlices(self.partition)
         self.dead_shards: set = set()
         self.recoveries = 0
         self.reshard_log: List[ReshardPlan] = []
         self.recovery_log: List[RecoveryReport] = []
 
     # ------------------------------------------------------------------ #
-    # discovery (index-fed sharded scan)                                  #
+    # discovery: dead shards first, then the shared sharded rebuild       #
     # ------------------------------------------------------------------ #
 
     def _build_rows(self, discover: Optional[str]
                     ) -> Dict[int, List[int]]:
-        if discover is not None:
-            return super()._build_rows(discover)
         self._recover_dead()
-        self.slices.sync(self.registry)
-        self.last_scan = ShardScanReport()
-        rows = sharded_successor_table(
-            self.registry, self.assigner, range(self._next_page),
-            self.partition, mesh=self.mesh, report=self.last_scan,
-            precomputed=(self.slices.local(), self.slices.cross()))
-        self._ensure_pages(self._next_page)
-        return rows
+        return super()._build_rows(discover)
 
     def _sync_tables(self) -> None:
         # failover on demand: a killed shard is recovered before any
@@ -178,17 +168,10 @@ class ElasticShardedPagedKVCache(ShardedPagedKVCache):
             raise ValueError(f"shard {shard} is not dead")
         n_refac, mode = self.slices.recover(self.registry)
         pages = self._owned_pages(shard)
-        report = ShardScanReport()
-        rows = sharded_successor_table(
-            self.registry, self.assigner, pages, self.partition,
-            mesh=self.mesh, report=report,
-            precomputed=(self.slices.local(), self.slices.cross()))
+        rows = sharded_successor_table(self.registry, self.assigner, pages,
+                                       self.slices, mesh=self.mesh)
         self._ensure_pages(self._next_page)
-        for d, row in rows.items():
-            self._succ[d, :] = EMPTY
-            self._succ_len[d] = 0
-            for succ in row:
-                self._succ_append(d, succ)
+        self._write_rows(rows)
         self.dead_shards.discard(shard)
         self.recoveries += 1
         rep = RecoveryReport(shard=shard, mode=mode, refactorized=n_refac,

@@ -42,11 +42,10 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
 from repro.core.engine.shard import (PrimeSpacePartition, ShardScanReport,
                                      shard_mesh, sharded_successor_table)
 from repro.obs.trace import EV_GCD_EXCHANGE
+from repro.sharding.reshard import ShardSlices
 
 from .kv_cache import PARITY_COUNTERS, PageStats
 from .kv_cache_vec import VectorizedPagedKVCache
@@ -73,6 +72,9 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
                          max_bits=max_bits)
         self.partition = PrimeSpacePartition(n_shards, stripes_per_shard)
         self.n_shards = self.partition.n_shards
+        # the registry split the sharded scan reads, kept current as the
+        # registry grows
+        self.slices = ShardSlices(self.partition)
         if mesh == "auto":
             mesh = shard_mesh(self.n_shards)
         if mesh is not None and mesh.size != self.n_shards:
@@ -92,17 +94,6 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
         p = self.assigner.prime_of(pid)
         return 0 if p is None else self.partition.owner(p)
 
-    def shard_composites(self) -> Tuple[List[np.ndarray], np.ndarray]:
-        """Current registry partition: per-shard-local composite arrays
-        plus the cross-shard array, in global registration order (object
-        dtype when the registry is wide)."""
-        arr = self.registry.composites_view()
-        local_pos, cross_pos = self.partition.classify(self.registry)
-        return ([arr[np.asarray(pos, dtype=np.int64)]
-                 if pos else np.empty(0, arr.dtype) for pos in local_pos],
-                arr[np.asarray(cross_pos, dtype=np.int64)]
-                if cross_pos else np.empty(0, arr.dtype))
-
     # ------------------------------------------------------------------ #
     # sharded bulk discovery                                              #
     # ------------------------------------------------------------------ #
@@ -117,9 +108,8 @@ class ShardedPagedKVCache(VectorizedPagedKVCache):
             return super()._build_rows(discover)
         self.last_scan = ShardScanReport()
         rows = sharded_successor_table(self.registry, self.assigner,
-                                       range(self._next_page),
-                                       self.partition, mesh=self.mesh,
-                                       report=self.last_scan)
+                                       range(self._next_page), self.slices,
+                                       mesh=self.mesh, report=self.last_scan)
         if self.obs is not None:
             for sh, n_local in enumerate(self.last_scan.local_composites):
                 self.obs.emit(EV_GCD_EXCHANGE, shard=sh, arg=n_local)

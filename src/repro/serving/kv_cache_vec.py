@@ -238,11 +238,19 @@ class VectorizedPagedKVCache(PagedKVCache):
         backend, including the sharded one)."""
         self._succ.fill(EMPTY)
         self._succ_len.fill(0)
+        self._write_rows(rows)
+        self.bulk_refreshes += 1
+        self._table_version = self.registry.version
+
+    def _write_rows(self, rows: Dict[int, List[int]]) -> None:
+        """Replace the successor rows of the pages in ``rows`` (a whole
+        rebuild's, or a recovered shard's)."""
+        pages = list(rows)
+        self._succ[pages] = EMPTY
+        self._succ_len[pages] = 0
         for page, row in rows.items():
             for succ in row:
                 self._succ_append(page, succ)
-        self.bulk_refreshes += 1
-        self._table_version = self.registry.version
 
     def successor_rows(self) -> Dict[int, List[int]]:
         """Current table as plain lists (tests/introspection)."""

@@ -1,11 +1,10 @@
 """Elastic re-striping plans and the per-shard registry slice index.
 
-The sharded discovery path (``core.engine.shard``) derives "which shard
-scans which composite" from :meth:`PrimeSpacePartition.classify` on
-every table refresh — an O(registry) walk that re-materializes metadata
-the partition function already determines.  This module turns that
-transient classification into a *maintained index*, :class:`ShardSlices`,
-so elastic events can be answered incrementally:
+The sharded discovery path (``core.engine.shard``) needs "which shard
+scans which composite" on every table refresh.  :class:`ShardSlices` is
+that split as a *maintained index*: it labels only the positions the
+registry gained since the last refresh, and it answers elastic events
+incrementally:
 
 * **Resize (re-stripe).**  A shard-count change swaps the
   :class:`~repro.sharding.stripes.BlockStripes` modulus under the same
@@ -28,15 +27,13 @@ so elastic events can be answered incrementally:
   Theorem 1) the rebuilt index is bit-equal to one built from intact
   metadata; the chaos fuzz in ``tests/test_elastic.py`` pins that.
 
-**Chunk-level ownership.**  ``PrimeSpacePartition.classify`` labels a
-position by ALL primes of its relationship; this index labels it by the
-primes dividing *that chunk* (recoverable from the composite value
-alone, which is what survives a shard loss).  The two produce identical
-scan results: a prime's divisibility/gcd hits can only come from the
-chunk that contains it, so routing each chunk to its own primes' owner
-preserves every (query prime, position) hit pair.  For the serving
-workload — pairwise chain edges, single-chunk relationships — the two
-classifications coincide exactly.
+**Chunk-level ownership.**  The index labels a position by the primes
+dividing *that chunk* (recoverable from the composite value alone,
+which is what survives a shard loss): the one shard owning them all, or
+:data:`CROSS`.  A prime's divisibility hits can only come from a chunk
+that contains it, so routing each chunk to its own primes' owner keeps
+every (query prime, position) hit pair; a relationship wider than one
+chunk may have its chunks on different shards.
 """
 
 from __future__ import annotations
@@ -94,8 +91,7 @@ class ShardSlices:
     for entries forgotten with a dead shard.  ``sync`` keeps the index
     current incrementally (append-only registry growth classifies only
     the tail); ``local()``/``cross()`` export the exact position lists
-    :func:`repro.core.engine.shard.sharded_successor_table` consumes via
-    its ``precomputed=`` argument.
+    :func:`repro.core.engine.shard.sharded_successor_table` scans.
     """
 
     def __init__(self, partition):
@@ -106,7 +102,7 @@ class ShardSlices:
         self._primes: List[Tuple[int, ...]] = []
 
     # ------------------------------------------------------------------ #
-    # classification                                                     #
+    # labelling                                                          #
     # ------------------------------------------------------------------ #
 
     def _owners_of(self, primes_list: Sequence[Tuple[int, ...]]
@@ -128,7 +124,7 @@ class ShardSlices:
                 out[j] = seg[0]
         return out
 
-    def _classify_tail(self, registry, arr: np.ndarray, lo: int) -> None:
+    def _label_tail(self, registry, arr: np.ndarray, lo: int) -> None:
         new_primes: List[Tuple[int, ...]] = []
         for pos in range(lo, arr.size):
             v = int(arr[pos])
@@ -158,13 +154,13 @@ class ShardSlices:
                 and np.array_equal(arr[:n_old], self._values)):
             mode = "append"
             self._values = arr.copy()
-            self._classify_tail(registry, arr, n_old)
+            self._label_tail(registry, arr, n_old)
         else:
             mode = "rebuild" if n_old else "append"
             self._values = arr.copy()
             self._owner = np.empty(0, np.int32)
             self._primes = []
-            self._classify_tail(registry, arr, 0)
+            self._label_tail(registry, arr, 0)
         self.version = registry.version
         return mode
 

@@ -14,7 +14,7 @@ striped round-robin*: the mesh-sharded discovery layer
     balanced even though allocation is ascending;
   * **pure O(1) ownership** — ``owner(value)`` is arithmetic on the
     value alone (no directory, no coordination), so any holder of a
-    prime can classify any composite locally.
+    prime can place any composite locally.
 
 This module is that machinery, extracted so the two layers share one
 implementation (and one set of block-width caps) instead of diverging

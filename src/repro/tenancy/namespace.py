@@ -121,7 +121,7 @@ class TenantNamespace:
     Ownership is pure O(1) arithmetic on the prime value
     (``BlockStripes``), so membership tests
     vectorize over whole registries and any holder of a prime can
-    classify it without coordination.  ``n_tenants == 1`` degenerates to
+    name its tenant without coordination.  ``n_tenants == 1`` degenerates to
     the untenanted prime space: tenant 0 owns every block, and a
     1-tenant namespace allocator is value-for-value identical to the
     global ``HierarchicalPrimeAllocator``.

@@ -194,6 +194,7 @@ def test_wide_sharded_table_matches_host():
                                          sharded_successor_table)
     from repro.core.engine.tables import successor_table
     from repro.core.primes import CacheLevel, HierarchicalPrimeAllocator
+    from repro.sharding.reshard import ShardSlices
 
     reg = CompositeRegistry(max_bits=640)
     assigner = PrimeAssigner(HierarchicalPrimeAllocator(), reg)
@@ -207,8 +208,8 @@ def test_wide_sharded_table_matches_host():
                      kind="chain")
     host = successor_table(reg, assigner, ids, discover="host")
     for n_shards in (2, 4):
-        part = PrimeSpacePartition(n_shards)
-        rows = sharded_successor_table(reg, assigner, ids, part, mesh=None)
+        split = ShardSlices(PrimeSpacePartition(n_shards))
+        rows = sharded_successor_table(reg, assigner, ids, split, mesh=None)
         assert rows == host, f"{n_shards} shards"
 
 

@@ -244,6 +244,29 @@ def test_successor_table_backends_agree():
     assert kv.bulk_refreshes == 1
 
 
+def test_kernel_table_refuses_a_false_hit(monkeypatch):
+    """Theorem 1: a scan hit on a composite the query prime does not
+    divide trips the kernel path's check against the decoded factors."""
+    from repro.core.engine import successor_table
+    from repro.kernels import ops
+
+    kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4)
+    kv.register_request(0, list(range(16)))        # edges (0,1) (1,2) (2,3)
+    scan = ops.divisibility_scan
+
+    def planted(digits, primes):
+        hits = scan(digits, primes)
+        # page 0's prime hits the last edge, which it is not part of
+        hits[0] = np.union1d(hits[0], [digits.shape[0] - 1])
+        return hits
+
+    monkeypatch.setattr(ops, "divisibility_scan", planted)
+    with pytest.raises(AssertionError,
+                       match="divisibility hit must contain p"):
+        successor_table(kv.registry, kv.assigner, range(4),
+                        discover="kernel")
+
+
 def test_shared_prefix_gcd_kernel_parity():
     """The vectorized cache recovers shared prefixes through the batched
     gcd kernel over chunked chain composites — identical to the scalar

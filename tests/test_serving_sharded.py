@@ -22,6 +22,7 @@ from repro.core.engine.shard import (PrimeSpacePartition, shard_mesh,
 from repro.serving.kv_cache import PARITY_COUNTERS, PagedKVCache
 from repro.serving.kv_cache_sharded import ShardedPagedKVCache
 from repro.serving.kv_cache_vec import VectorizedPagedKVCache
+from repro.sharding.reshard import ShardSlices
 
 
 def _differential(spec: KVWorkloadSpec, hbm: int, budget: int,
@@ -139,18 +140,24 @@ def test_classify_partitions_registry_in_order():
         # partition's prime blocks, so the cross-shard path is live
         tail = list(rng.integers(0, 3000, size=int(rng.integers(80, 200))))
         kv.register_request(r, shared[:int(rng.integers(0, 24))] + tail)
-    local, cross = kv.partition.classify(kv.registry)
-    arr = kv.registry.composites_array()
+    kv.slices.sync(kv.registry)
+    local, cross = kv.slices.local(), kv.slices.cross()
+    arr = kv.registry.composites_view()
     all_pos = sorted(p for sh in local for p in sh) + sorted(cross)
     assert sorted(all_pos) == list(range(arr.size))       # exact partition
+    assert cross == sorted(cross)
+
+    def chunk_owners(pos):
+        c = int(arr[pos])
+        rel = kv.registry.relationship_of_composite(c)
+        return {kv.partition.owner(q) for q in rel.primes if c % q == 0}
+
     for s, sh in enumerate(local):
         assert sh == sorted(sh)                           # registry order
         for pos in sh:
-            rel = kv.registry.relationship_of_composite(int(arr[pos]))
-            assert {kv.partition.owner(q) for q in rel.primes} == {s}
+            assert chunk_owners(pos) == {s}
     for pos in cross:
-        rel = kv.registry.relationship_of_composite(int(arr[pos]))
-        assert len({kv.partition.owner(q) for q in rel.primes}) > 1
+        assert len(chunk_owners(pos)) > 1
     # at this scale chains straddle prime blocks: the exchange is live
     assert cross, "workload produced no cross-shard chains"
 
@@ -159,12 +166,17 @@ def test_classify_partitions_registry_in_order():
 # sharded bulk discovery == single-device bulk discovery                      #
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("n_shards,stripes", [
-    (1, 8), (2, 8), (3, 8), (4, 8),
+@pytest.mark.parametrize("n_shards,stripes,group", [
+    (1, 8, False), (2, 8, False), (3, 8, False), (4, 8, False),
     # one-prime-wide blocks: most chain edges straddle owners
-    (4, 100_000)], ids=["1", "2", "3", "4", "4-mostly-cross"])
-def test_sharded_successor_table_matches_global(n_shards, stripes):
+    (4, 100_000, False),
+    # a group of MEM primes wider than one chunk whose chunks each have
+    # one owner while the relationship has two
+    (4, 8, True)], ids=["1", "2", "3", "4", "4-mostly-cross",
+                        "4-multi-chunk-group"])
+def test_sharded_successor_table_matches_global(n_shards, stripes, group):
     from repro.core.engine import successor_table
+    from repro.core.primes import CacheLevel
 
     kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4,
                                 prefetch_budget=3)
@@ -174,14 +186,40 @@ def test_sharded_successor_table_matches_global(n_shards, stripes):
         tail = list(rng.integers(0, 200, size=int(rng.integers(4, 16))))
         kv.register_request(r, shared[:int(rng.integers(0, 16))] + tail)
     pages = range(kv._next_page)
-    host = successor_table(kv.registry, kv.assigner, pages, discover="host")
     part = PrimeSpacePartition(n_shards, stripes)
-    local, cross = part.classify(kv.registry)
+    if group:
+        # three MEM primes in the first block (shard 0), three past the
+        # block boundary (shard 1): 2**62 fits three, so two chunks
+        mem = kv.assigner.allocator.pool(CacheLevel.MEM)
+        lo, width = part._blocks[CacheLevel.MEM]
+        ids = list(range(kv._next_page, kv._next_page + 6))
+        for d in ids[:3]:
+            kv.assigner.assign(d, CacheLevel.MEM)
+        while mem.allocate() < lo + width:
+            pass
+        for d in ids[3:]:
+            kv.assigner.assign(d, CacheLevel.MEM)
+        members = [kv.assigner.prime_of(d) for d in ids]
+        kv.registry.register(members, kind="group")
+        group_pos = [i for i, c in
+                     enumerate(kv.registry.composites_view().tolist())
+                     if kv.registry.relationship_of_composite(c).kind
+                     == "group"]
+        assert len(group_pos) == 2
+        assert len({part.owner(q) for q in members}) == 2
+        pages = range(ids[-1] + 1)
+    host = successor_table(kv.registry, kv.assigner, pages, discover="host")
+    sl = ShardSlices(part)
+    sl.sync(kv.registry)
+    local, cross = sl.local(), sl.cross()
+    if group:
+        # each chunk is local to its own shard: the split is by chunk
+        assert group_pos[0] in local[0] and group_pos[1] in local[1]
     if stripes > 8:
         assert len(cross) > sum(map(len, local))
     # shard_map where the host has the devices, else the host loop
     sharded = sharded_successor_table(kv.registry, kv.assigner, pages,
-                                      part, mesh=shard_mesh(n_shards))
+                                      sl, mesh=shard_mesh(n_shards))
     assert sharded == host
 
 
