@@ -27,15 +27,18 @@ the global registry (registration) order — the candidate-order
 contract the serving cache's parity tests pin down.
 
 **Per-shard bulk discovery under shard_map.**  Successor rows are
-rebuilt per shard through the SAME Pallas kernel the single-device path
-uses (``divisibility_mask_pallas`` for the §4.2 scan), mapped over the
-mesh with ``shard_map``.  Cross-shard relationships are resolved by a
-**collective exchange**: each shard contributes its slice of the
-cross-shard composites, ``lax.all_gather`` replicates them along the
-mesh, and each shard runs ONE divisibility scan of its local rows plus
-every gathered cross row against its own query primes.  A cross
+rebuilt per shard through the SAME program the single-device path uses
+(``divisibility_hits_pallas``: the §4.2 scan, its hits compacted on the
+device), mapped over the mesh with ``shard_map``.  Cross-shard
+relationships are resolved by a **collective exchange**: each shard
+contributes its slice of the cross-shard composites, ``lax.all_gather``
+replicates them along the mesh, and each shard runs ONE divisibility
+scan of its local rows plus every gathered cross row against its own
+query primes.  A cross
 composite's hits in a shard's mask are exactly the member primes that
-shard owns, so no query ever leaves its owner shard.
+shard owns, so no query ever leaves its owner shard.  The host copies
+each shard's hit coordinates, and a shard's mask only when its hits
+overflow their fixed slots.
 
 When the host exposes fewer devices than shards (the common laptop
 case), the same math runs as a per-shard host loop over the identical
@@ -172,13 +175,19 @@ _ROW_BLOCK = 256
 
 
 def _one_shard_scan(lc, qs, gathered, *, interpret: bool):
-    """One shard's kernel work: the ``(C + X, Q)`` divisibility mask of
-    its local rows ``lc`` (C, L), then every shard's gathered cross rows
-    ``gathered`` (X, L), against its owned query primes ``qs`` (Q,).
-    Pad rows encode the value 1 and match nothing."""
+    """One shard's kernel work: the divisibility scan of its local rows
+    ``lc`` (C, L), then every shard's gathered cross rows ``gathered``
+    (X, L), against its owned query primes ``qs`` (Q,), with the hits
+    compacted on the device in the same program
+    (:func:`~repro.kernels.factorize.divisibility_hits_pallas`).
+
+    Returns ``(hits, mask)``: the ``[total, per-query counts (Q), rows
+    (HITS_PER_ROW * Q)]`` vector and the ``(Q, R)`` query-major mask, R
+    being C + X rounded up to the kernel's row tile.  Pad rows encode
+    the value 1 and match nothing."""
     import jax.numpy as jnp
 
-    from repro.kernels.factorize import divisibility_mask_pallas
+    from repro.kernels.factorize import divisibility_hits_pallas
 
     rows = jnp.concatenate([lc, gathered])
     pad = (-rows.shape[0]) % _ROW_BLOCK
@@ -186,14 +195,16 @@ def _one_shard_scan(lc, qs, gathered, *, interpret: bool):
         one = (jnp.arange(rows.shape[1]) == 0).astype(rows.dtype)
         rows = jnp.concatenate(
             [rows, jnp.broadcast_to(one, (pad, rows.shape[1]))])
-    return divisibility_mask_pallas(rows, qs, block_n=_ROW_BLOCK,
+    return divisibility_hits_pallas(rows, qs, block_n=_ROW_BLOCK,
                                     interpret=interpret)
 
 
 @functools.lru_cache(maxsize=16)
 def _shard_map_scan(mesh, interpret: bool):
     """The shard_map scan over ``mesh``, memoized per mesh; ``jit`` keeps
-    one executable per bucketed input shape."""
+    one executable per bucketed input shape.  Each shard's hit vector
+    and mask are laid end to end along the leading axis: ``(S * H,)``
+    and ``(S * Q, R)``."""
     import jax
 
     from repro.sharding.partition import shard_stack_spec
@@ -203,50 +214,67 @@ def _shard_map_scan(mesh, interpret: bool):
 
     def body(lc, qs, xc):
         gathered = jax.lax.all_gather(xc[0], axes, tiled=True)
-        return _one_shard_scan(lc[0], qs[0], gathered,
-                               interpret=interpret)[None]
+        return _one_shard_scan(lc[0], qs[0], gathered, interpret=interpret)
 
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                                 out_specs=spec, check_vma=False))
+                                 out_specs=(spec, spec), check_vma=False))
 
 
 def _scan_sharded(local_c: np.ndarray, queries: np.ndarray,
-                  cross_c: np.ndarray, mesh, **counts) -> np.ndarray:
-    """The per-shard kernel work: one divisibility mask a shard.
+                  cross_c: np.ndarray, mesh, **counts,
+                  ) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """The per-shard kernel work: one compacting divisibility scan a
+    shard.
 
     Inputs are padded stacks: (S, C, L) local digit rows, (S, Q) query
-    primes and (S, X/S, L) cross slices; returns the (S, R, Q) masks, R
-    being C + X rounded up to the kernel's row tile, rows ``[0, C)``
-    local and ``[C, C + X)`` the gathered cross rows in slice order.
-    With a mesh of exactly S devices the work runs under ``shard_map``
-    (one shard per device, the cross slices replicated by
-    ``lax.all_gather`` — the collective exchange); otherwise a host
-    loop runs the identical kernel per shard.  ``counts`` go to the
-    span.
+    primes and (S, X/S, L) cross slices.  Returns, a shard, the hit
+    count of each of its Q queries and the mask row of each hit in
+    (query, row) order, rows ``[0, C)`` local and ``[C, C + X)`` the
+    gathered cross rows in slice order.  With a mesh of exactly S
+    devices the work runs under ``shard_map`` (one shard per device, the
+    cross slices replicated by ``lax.all_gather`` — the collective
+    exchange); otherwise a host loop runs the identical program per
+    shard.
+
+    The host copies each shard's hit vector, not its mask.  A shard
+    whose hits overflow their ``HITS_PER_ROW * Q`` slots copies its own
+    mask as well and compacts it here, so the answer is exact either
+    way.  ``counts`` go to the span, with ``hits`` (the masks' set
+    cells) and ``dense`` (the shards that overflowed).
     """
     import jax.numpy as jnp
 
-    from repro.kernels.ops import check_32bit, interpret_mode
+    from repro.kernels.ops import _scan_hits, check_32bit, interpret_mode
 
     interpret = interpret_mode()
     check_32bit()
     S, C, L = local_c.shape
+    Q = queries.shape[1]
     X = S * cross_c.shape[1]
     with kernel_scope("sharded_exchange", items=S * (C + X), shards=S,
-                      c=C, q=queries.shape[1], **counts):
+                      c=C, q=Q, **counts):
         if mesh is not None and mesh.size == S:
-            mask = _shard_map_scan(mesh, interpret)(
+            hits, mask = _shard_map_scan(mesh, interpret)(
                 jnp.asarray(local_c), jnp.asarray(queries),
                 jnp.asarray(cross_c))
-        else:                           # host loop, same kernel, same math
+            # each device's own (Q, R) mask, copied only on overflow
+            masks = [sh.data for sh in sorted(
+                mask.addressable_shards,
+                key=lambda sh: sh.index[0].start or 0)]
+        else:                           # host loop, same program, same math
             gathered = jnp.asarray(cross_c.reshape(-1, L))
-            mask = jnp.stack([
+            hits, masks = zip(*[
                 _one_shard_scan(jnp.asarray(local_c[s]),
                                 jnp.asarray(queries[s]), gathered,
                                 interpret=interpret)
                 for s in range(S)])
+            hits = jnp.concatenate(hits)
         with profile.span("kernels.sharded_exchange.fetch"):
-            return np.asarray(mask)
+            found = [_scan_hits(h, m, Q) for h, m in
+                     zip(np.asarray(hits).reshape(S, -1), masks)]
+        profile.count(hits=sum(len(rows) for _, rows, _ in found),
+                      dense=sum(dense for *_, dense in found))
+        return [(per_query, rows) for per_query, rows, _ in found]
 
 
 def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
@@ -303,10 +331,10 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
 
     # ---- kernel work (shard_map when the mesh matches) ------------------ #
     n_local = [len(p) for p in local_pos]
-    mask = _scan_sharded(local_c, queries, cross_sh, mesh,
-                         cross=len(cross_pos), gathered=S * per,
-                         local_max=max(n_local), local_min=min(n_local),
-                         q_max=max(len(sh) for sh in by_shard))
+    found = _scan_sharded(local_c, queries, cross_sh, mesh,
+                          cross=len(cross_pos), gathered=S * per,
+                          local_max=max(n_local), local_min=min(n_local),
+                          q_max=max(len(sh) for sh in by_shard))
 
     if report is not None:
         report.n_shards = S
@@ -320,18 +348,19 @@ def sharded_successor_table(registry, assigner, data_ids: Sequence[int],
         # ---- each owned query's hits, as registry positions: local rows
         # first, then the gathered cross rows (cross index == gathered
         # row, the slices being consecutive); padded queries hit nothing
-        C, Q = local_c.shape[1], mask.shape[2]
-        hits_of: List[np.ndarray] = []
-        for s in range(S):
-            row_pos = np.full(mask.shape[1], -1, np.int64)
+        C = local_c.shape[1]
+        pos, per_query = [], []
+        for s, (counts, rows) in enumerate(found):
+            row_pos = np.full(C + len(cross_pos), -1, np.int64)
             row_pos[:n_local[s]] = local_pos[s]
-            row_pos[C:C + len(cross_pos)] = cross_pos
-            row, col = np.divmod(np.flatnonzero(mask[s]), Q)
-            pos = row_pos[row]
-            order = np.lexsort((pos, col))              # by query, then pos
-            n_q = len(by_shard[s])
-            hits_of.extend(np.split(pos[order], np.searchsorted(
-                col[order], np.arange(1, n_q)))[:n_q])
+            row_pos[C:] = cross_pos
+            pos.append(row_pos[rows])
+            per_query.append(counts[:len(by_shard[s])])
+        pos, per_query = np.concatenate(pos), np.concatenate(per_query)
+        # local and cross rows interleave in registry order
+        order = np.lexsort((pos, np.repeat(np.arange(per_query.size),
+                                           per_query)))
+        hits_of = np.split(pos[order], np.cumsum(per_query)[:-1])
 
     with profile.span("tables.rows"):
         return rows_from_hits(registry, assigner,

@@ -273,8 +273,8 @@ def divisibility_mask_pallas(
     interpret: bool = False,
 ):
     """Prefetch candidate scan (§4.2): ``mask[i, j] = primes[j] | c_i``,
-    the dense ``(N, P)`` mask, which the sharded exchange compacts on
-    the host.  ``ops.divisibility_scan`` runs the same kernel through
+    the dense ``(N, P)`` mask.  ``ops.divisibility_scan`` and the
+    sharded exchange run the same kernel through
     :func:`divisibility_hits_pallas` instead, which compacts the mask on
     the device into ``HITS_PER_ROW`` slots a padded query and leaves the
     host the dense mask only when the hits overflow them.  Padding rows

@@ -375,6 +375,30 @@ def test_compacting_wrappers_count_hits_and_dense(over_the_cap):
     assert scan.counts["dense"] == fact.counts["dense"] == int(over_the_cap)
 
 
+def test_sharded_exchange_counts_hits_and_dense():
+    """``hits`` on the sharded exchange is the set count of every
+    shard's dense mask together, a cross composite counted in each shard
+    that owns one of its primes; ``dense`` is 0 while no shard's hits
+    overflow their slots."""
+    from repro.kernels.ref import divisibility_mask_ref
+    from repro.serving.kv_cache_sharded import ShardedPagedKVCache
+
+    kv = ShardedPagedKVCache(hbm_pages=16, page_size=4, n_shards=4,
+                             mesh=None, stripes_per_shard=1000)
+    rng = np.random.default_rng(5)
+    for r in range(6):
+        kv.register_request(r, list(rng.integers(0, 200, size=24)))
+    with profile.recording() as kept:
+        kv.refresh_tables()
+    assert kv.last_scan.cross_composites > 0
+    primes = [p for d in range(kv._next_page)
+              if (p := kv.assigner.prime_of(d)) is not None]
+    ex = next(r for r in kept if r.name == "kernels.sharded_exchange")
+    assert ex.counts["hits"] == \
+        divisibility_mask_ref(kv.registry.limbs_array(), primes).sum() > 0
+    assert ex.counts["dense"] == 0
+
+
 class _StubModel:
     """A model with the decode interface and no weights: logits favour
     token (position + 1)."""

@@ -166,6 +166,18 @@ def test_classify_partitions_registry_in_order():
 # sharded bulk discovery == single-device bulk discovery                      #
 # --------------------------------------------------------------------------- #
 
+def _chain_registry():
+    """Eight requests' chains over 28 pages sharing prefixes."""
+    kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4,
+                                prefetch_budget=3)
+    rng = np.random.default_rng(5)
+    shared = list(rng.integers(0, 200, size=16))
+    for r in range(8):
+        tail = list(rng.integers(0, 200, size=int(rng.integers(4, 16))))
+        kv.register_request(r, shared[:int(rng.integers(0, 16))] + tail)
+    return kv, list(range(kv._next_page))
+
+
 @pytest.mark.parametrize("n_shards,stripes,group", [
     (1, 8, False), (2, 8, False), (3, 8, False), (4, 8, False),
     # one-prime-wide blocks: most chain edges straddle owners
@@ -178,14 +190,7 @@ def test_sharded_successor_table_matches_global(n_shards, stripes, group):
     from repro.core.engine import successor_table
     from repro.core.primes import CacheLevel
 
-    kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4,
-                                prefetch_budget=3)
-    rng = np.random.default_rng(5)
-    shared = list(rng.integers(0, 200, size=16))
-    for r in range(8):
-        tail = list(rng.integers(0, 200, size=int(rng.integers(4, 16))))
-        kv.register_request(r, shared[:int(rng.integers(0, 16))] + tail)
-    pages = range(kv._next_page)
+    kv, pages = _chain_registry()
     part = PrimeSpacePartition(n_shards, stripes)
     if group:
         # three MEM primes in the first block (shard 0), three past the
@@ -221,6 +226,74 @@ def test_sharded_successor_table_matches_global(n_shards, stripes, group):
     sharded = sharded_successor_table(kv.registry, kv.assigner, pages,
                                       sl, mesh=shard_mesh(n_shards))
     assert sharded == host
+
+
+def _hub_registry():
+    """One hub page chained to more pages than the slots a padded query
+    block keeps (``HITS_PER_ROW`` x 512): the hub's prime overflows its
+    shard's hit vector."""
+    from repro.core.primes import CacheLevel
+    from repro.kernels.factorize import HITS_PER_ROW
+
+    kv = VectorizedPagedKVCache(hbm_pages=16, page_size=4)
+    ids = list(range(HITS_PER_ROW * 512 + 8))
+    for d in ids:
+        kv.assigner.assign(d, CacheLevel.L3)
+    hub = kv.assigner.prime_of(0)
+    for d in ids[1:]:
+        kv.registry.register([hub, kv.assigner.prime_of(d)], kind="chain")
+    # the hub and a few spokes, so every shard has queries
+    return kv, ids[:64]
+
+
+@pytest.mark.parametrize("case,n_shards,stripes", [
+    ("chains", 1, 8), ("chains", 2, 1000), ("chains", 4, 1000),
+    ("no-local-rows", 4, 2000), ("no-queries", 4, 1000),
+    ("cross-heavy", 4, 10_000), ("overflow", 2, 100_000)])
+def test_compacting_exchange_matches_global(monkeypatch, case, n_shards,
+                                            stripes):
+    """The host-loop exchange, each shard's hits compacted by the same
+    program the mesh runs, gives the single-device table's rows; a
+    shard copies its dense mask (``dense``) only when its hits overflow
+    their slots."""
+    from repro.core.engine import successor_table
+    from repro.core.engine.shard import ShardScanReport
+    from repro.kernels import ops
+    from repro.obs import profile
+
+    kv, ids = _hub_registry() if case == "overflow" else _chain_registry()
+    part = PrimeSpacePartition(n_shards, stripes)
+    owner = [part.owner(kv.assigner.prime_of(d)) for d in ids]
+    if case == "no-queries":
+        ids = [d for d, s in zip(ids, owner) if s != 3]
+    sl = ShardSlices(part)
+    scan_hits, dense = ops._scan_hits, []
+
+    def recorded(*args):
+        out = scan_hits(*args)
+        dense.append(out[2])
+        return out
+
+    monkeypatch.setattr(ops, "_scan_hits", recorded)
+    report = ShardScanReport()
+    with profile.recording() as kept:
+        sharded = sharded_successor_table(kv.registry, kv.assigner, ids, sl,
+                                          mesh=None, report=report)
+    assert sharded == successor_table(kv.registry, kv.assigner, ids,
+                                      discover="host")
+    local, queries = report.local_composites, report.queries_per_shard
+    if case == "no-local-rows":
+        assert any(n == 0 < q for n, q in zip(local, queries))
+    elif case == "no-queries":
+        assert local[3] > 0 == queries[3]
+    elif case == "cross-heavy":
+        assert report.cross_composites > 4 * sum(local)
+    elif case == "overflow":
+        assert all(queries)             # the other shard scans as well
+    hub_shard = owner[0] if case == "overflow" else None
+    assert dense == [s == hub_shard for s in range(n_shards)]
+    ex = next(r for r in kept if r.name == "kernels.sharded_exchange")
+    assert ex.counts["dense"] == sum(dense)
 
 
 def test_sharded_refresh_crosschecks_against_kernel_backend():

@@ -8,8 +8,9 @@ the chip, so these tests guard the device path on every change:
 * each discovery kernel at 4,096 composites x 1,024 primes, with
   narrow (62-bit) and wide (1,024-bit) digit rows, and the scan and
   the factorization with their masks compacted on the device;
-* the sharded exchange's ``shard_map`` scan over a 2x2 mesh, at the
-  four-chip page-manager cell's buckets;
+* the sharded exchange's ``shard_map`` scan over a 2x2 mesh, each
+  shard's hits compacted on its chip, at the four-chip page-manager
+  cell's buckets;
 * the full-width ``qwen2.5-3b`` decode step and parameter init, which
   must fit one chip.
 
@@ -83,8 +84,10 @@ def test_discovery_kernel_compiles(one_chip, kernel, bits):
 def test_shard_map_scan_compiles_on_four_chips(topo, C):
     """The exchange at the four-chip page-manager cell's buckets: local
     rows C (and the next bucket), 1,024 gathered cross rows, 4,096
-    query primes a shard."""
+    query primes a shard.  Each shard returns its compacted hit vector
+    and its query-major mask, laid end to end over the mesh."""
     from repro.core.engine.shard import _shard_map_scan
+    from repro.kernels.factorize import HITS_PER_ROW
     from repro.sharding.partition import shard_stack_spec
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
@@ -92,9 +95,14 @@ def test_shard_map_scan_compiles_on_four_chips(topo, C):
     S, Q, X = 4, 4096, 1024
     L = n_limbs_for_bits(62)
     fn = _shard_map_scan(mesh, False)
-    compiled = fn.lower(_sds((S, C, L), jnp.int32, stack),
-                        _sds((S, Q), jnp.int32, stack),
-                        _sds((S, X // S, L), jnp.int32, stack)).compile()
+    lowered = fn.lower(_sds((S, C, L), jnp.int32, stack),
+                       _sds((S, Q), jnp.int32, stack),
+                       _sds((S, X // S, L), jnp.int32, stack))
+    hits, mask = lowered.out_info
+    assert (hits.shape, hits.dtype) == ((S * (1 + Q + HITS_PER_ROW * Q),),
+                                        jnp.int32)
+    assert (mask.shape, mask.dtype) == ((S * Q, C + X), jnp.bool_)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "all-gather" in text and "tpu_custom_call" in text
     assert 0 < _device_bytes(compiled) < HBM_BYTES
